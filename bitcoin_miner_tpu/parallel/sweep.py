@@ -38,26 +38,6 @@ from ..ops.sweep import (
 from .mesh import MINER_AXIS, default_mesh
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma):
-    """jax.shard_map across jax versions: the stable API when present,
-    else jax.experimental.shard_map (pre-0.6 images, where the
-    replication-check kwarg is spelled ``check_rep``).  Without this, an
-    old-jax container raises AttributeError inside the miner's daemon
-    dispatcher thread and the fleet hangs instead of failing."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as esm
-
-    return esm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def _collective_min(h0, h1, flat, axis: str):
     """Reduce per-device (h0, h1, flat_idx) scalars to the replicated global
     lexicographic min, lowest-(device, flat) — i.e. lowest-nonce — ties.
@@ -164,7 +144,7 @@ def _shard_and_jit(local, mesh: Mesh, axis_name: str, sieve: bool):
         h0, h1, flat = local(midstate, tail_const, bounds, *th)
         return _collective_min(h0, h1, flat, axis_name)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(axis_name, None), P(axis_name, None))
@@ -276,7 +256,7 @@ def _make_sharded_kernel_dyn(
             h0, h1, flat = pallas_fn(midstate, tailcb, *rest)
         return _collective_min(h0, h1, flat, axis_name)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), P(axis_name, None), P(axis_name, None))

@@ -367,6 +367,8 @@ def format_quantiles(h) -> str:
 #:   miner.nonces              nonces swept by this process's miner loop
 #:   miner.reconnects          successful re-Joins after a lost server conn
 #:   miner.tier_downgrades     kernel tiers abandoned by the sweep watchdog
+#:   sweep.device_lanes        nonces swept by a device kernel dispatch
+#:   sweep.host_fold_lanes     nonces of tiny digit classes min-folded on the host
 #:   sweep.ring_refills        chunk descriptors shipped to the hot plane's device ring
 #:   sweep.donated_dispatches  donated-carry steps enqueued by the always-hot plane
 #:   kernel.thresh_staleness   sieve-threshold lag in dispatches (gauge; 1 = device-resident)

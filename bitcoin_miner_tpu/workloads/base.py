@@ -168,7 +168,7 @@ class Workload:
 
             enable_compile_cache()
             return miner_mod._PipelineSearch(tier, devices=devices, workload=self)
-        return miner_mod._PoolSearch(self.make_search(tier, devices))
+        return miner_mod._PoolSearch(self.make_search(tier, devices), tier)
 
     def _cpu_search(self):
         """The cpu-tier search: the compiled native sweep when this

@@ -9,9 +9,9 @@ The sharded path is validated three ways:
   reproduce on jax 0.9.0 — both a minimal shard_map+pallas+pmin repro and
   the full kernel run clean, so the flagship tier is now oracle-checked
   sharded),
-- AOT: the exact flagship config (Pallas under shard_map + pmin cascade)
-  lowered and Mosaic-compiled against a virtual 8-device v5e:2x4 TPU
-  topology (no chips needed) in test_aot_topology.py.
+- AOT: the production config (Pallas under shard_map + pmin cascade)
+  lowered and Mosaic-compiled against a described 4-device v5e:2x2 TPU
+  topology (no chips needed) in test_chip_compile.py.
 The driver's dryrun_multichip runs the first two.
 """
 
