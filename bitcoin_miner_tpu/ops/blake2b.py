@@ -40,10 +40,15 @@ to BLAKE2b's structure:
   the working set stays cache-resident at ``(B, 10^k_in)``.  Unlike
   SHA-256's message schedule, BLAKE2b's SIGMA permutation feeds raw
   message words to every round, so the unrolled DAG is what makes the
-  zero-word elision reach all 12 rounds; measured on this host the
-  unrolled grouped form is ~4x the rolled fori_loop form, and its
-  XLA:CPU compile is seconds, not the minutes the (wider) SHA-256
-  unrolled DAG costs.
+  zero-word elision reach all 12 rounds.  XLA fuses that DAG whole (three
+  ~4.5k-instruction loop fusions a class on the CPU), and XLA:CPU's
+  default MLIR fusion emitters take minutes and tens of GB to generate
+  code for fusions that size; its legacy emitter compiles a class in
+  ~3 s at 0.4 GB peak RSS (jax 0.9.0, 8-core x86 host), so every jit of
+  this kernel passes :func:`compiler_options`.  A rolled 12-round
+  ``fori_loop`` also compiles in ~1.5 s, but runs 17-45x slower there
+  (0.4-1.1M vs 18.7M nonces/s a k=5 dispatch) because each round splits
+  into ~20-70 fusions.
 
 The kernel keeps the exact operand/result contract of the SHA-256 xla
 tier — ``(midstate, tail_const (B, nw), bounds (B, 2)[, thresh]) ->
@@ -503,6 +508,16 @@ def make_blake2b_kernel_body(
     return kernel
 
 
+def compiler_options() -> Optional[Dict[str, bool]]:
+    """XLA options for every jit of this family's kernel, on the default
+    backend: XLA:CPU's legacy fusion emitter, which compiles the unrolled
+    compression in seconds where its default takes minutes (module
+    docstring); other backends get none."""
+    if jax.default_backend() == "cpu":
+        return {"xla_cpu_use_fusion_emitters": False}
+    return None
+
+
 @lru_cache(maxsize=256)
 def _make_blake2b_kernel(
     msg_len: int,
@@ -521,7 +536,8 @@ def _make_blake2b_kernel(
         make_blake2b_kernel_body(
             msg_len, tail_off, n_tail_blocks, live_words, low_pos, k,
             batch, sieve=sieve, factored=factored,
-        )
+        ),
+        compiler_options=compiler_options(),
     )
 
 

@@ -135,7 +135,9 @@ def _make_sharded_kernel(
     return _shard_and_jit(local, mesh, axis_name, sieve)
 
 
-def _shard_and_jit(local, mesh: Mesh, axis_name: str, sieve: bool):
+def _shard_and_jit(
+    local, mesh: Mesh, axis_name: str, sieve: bool, compiler_options=None
+):
     """shard_map + collective cascade + jit around one local kernel body
     — shared by the sha256 and blake2b sharded factories (the cascade is
     shape-agnostic over the local ``(h0, h1, flat)`` scalars)."""
@@ -155,7 +157,7 @@ def _shard_and_jit(local, mesh: Mesh, axis_name: str, sieve: bool):
         # makes every output genuinely replicated.
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return jax.jit(mapped, compiler_options=compiler_options)
 
 
 @lru_cache(maxsize=256)
@@ -180,13 +182,15 @@ def _make_sharded_blake2b_kernel(
     has no pallas lowering); the shape-class key carries the layout's
     static fields the sha256 key doesn't need (msg_len / tail_off /
     live-word set are compiled into the DAG)."""
-    from ..ops.blake2b import make_blake2b_kernel_body
+    from ..ops.blake2b import compiler_options, make_blake2b_kernel_body
 
     local = make_blake2b_kernel_body(
         msg_len, tail_off, n_tail_blocks, live_words, low_pos, k,
         per_dev_batch, sieve=sieve, factored=factored,
     )
-    return _shard_and_jit(local, mesh, axis_name, sieve)
+    return _shard_and_jit(
+        local, mesh, axis_name, sieve, compiler_options=compiler_options()
+    )
 
 
 @lru_cache(maxsize=8)

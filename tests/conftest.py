@@ -9,7 +9,11 @@ platform override goes through ``jax.config`` (backends initialize lazily,
 so this still wins as long as no computation has run); ``XLA_FLAGS`` is
 read at backend init and can still be set here.  The chip is reached
 through ``python chip_smoke.py`` on a chip host, never from pytest.
+
+Every test runs under a time limit of its own (tests/time_limit.py).
 """
+
+from time_limit import pytest_runtest_protocol  # noqa: F401  (the hook)
 
 from bitcoin_miner_tpu.utils.platform import (
     enable_compile_cache,

@@ -47,6 +47,10 @@ PARAMS = lsp.Params(epoch_limit=5, epoch_millis=100, window_size=5)
 ALL = workloads.names()
 NON_DEFAULT = [n for n in ALL if n != workloads.DEFAULT_WORKLOAD]
 
+#: Workloads whose own device kernel family (not a shared SHA-256 kernel)
+#: must reproduce their golden vectors, and the tier that runs it.
+WORKLOAD_DEVICE_TIERS = {"blake2b64": "xla"}
+
 
 # ------------------------------------------------------------------ registry
 
@@ -167,6 +171,28 @@ class TestTierLadders:
         search = w.make_search("xla")
         assert search("b2dev", 95, 320) == w.min_range("b2dev", 95, 320)
         assert search("b2dev", 95, 320) != min_hash_range("b2dev", 95, 320)
+
+    @pytest.mark.parametrize(
+        "wname,tier,data,nonce,frozen",
+        [
+            (wname, tier, *vec)
+            for wname, tier in WORKLOAD_DEVICE_TIERS.items()
+            for vec in workloads.get(wname).golden
+        ],
+    )
+    def test_device_tier_reproduces_golden(self, wname, tier, data, nonce, frozen):
+        """A from-scratch device kernel can drift while the hashlib oracle
+        stays green, and every sweep would then serve wrong minima
+        whenever that tier wins the ladder.  A single-nonce sweep
+        (``[n, n]``; ``sweep_min_hash`` routes nothing to the host) puts
+        each golden vector through the whole device path: layout build,
+        midstate fold, device compression, min-fold epilogue."""
+        from bitcoin_miner_tpu.ops.sweep import sweep_min_hash
+
+        w = workloads.get(wname)
+        assert tier in w.tiers
+        r = sweep_min_hash(data, nonce, nonce, backend=tier, workload=w)
+        assert (r.hash, r.nonce) == (frozen, nonce)
 
     def test_preimage_xla_tier_bit_exact(self):
         """The tentpole's device half: the separator-parameterized layout

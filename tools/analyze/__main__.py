@@ -41,13 +41,10 @@ DEFAULT_RATCHET = Path(__file__).resolve().parent / "ratchet.json"
 #: contracts/sanitize self-tests only depend on their trigger dirs.
 _PER_FILE_PASSES = frozenset({"lock", "wfq", "trace", "loop", "donate", "thread"})
 _WHOLE_PASS_TRIGGERS = {
-    # contracts: workloads joined the trigger set with the registry's
-    # golden pass (ISSUE 9), ops with the device-tier recompute
-    # (ISSUE 20 — a kernel edit can drift the blake2b64 vectors while
-    # bitcoin/lsp/apps are untouched).
+    # contracts: workloads are in the trigger set for the registry's
+    # golden-vector pass.
     "contracts": ("bitcoin_miner_tpu/bitcoin", "bitcoin_miner_tpu/lsp",
                   "bitcoin_miner_tpu/apps", "bitcoin_miner_tpu/workloads",
-                  "bitcoin_miner_tpu/ops", "bitcoin_miner_tpu/parallel",
                   "tools/analyze"),
     "sanitize": ("bitcoin_miner_tpu/utils", "bitcoin_miner_tpu/apps",
                  "tools/analyze"),
