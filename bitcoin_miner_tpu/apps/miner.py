@@ -219,7 +219,8 @@ class _PipelineSearch:
     def prewarm(self, data: str, upper: int) -> None:
         """Speculatively warm the digit class one past this assignment's
         upper bound so crossing a digit boundary never stalls the sweep
-        (~14 s/class first-in-process, SweepPipeline.prewarm_async)."""
+        on a class's first use in the process (SweepPipeline.prewarm_async
+        has the measured costs)."""
         self._p.prewarm_async(data, len(str(upper)) + 1)
 
     def close(self) -> None:
@@ -1024,7 +1025,10 @@ def main(argv=None) -> int:
         print(
             f"miner: {swept} nonces swept ({swept / dt:,.0f}/s lifetime); "
             f"lanes on device {METRICS.get('sweep.device_lanes')}, "
-            f"host fold {METRICS.get('sweep.host_fold_lanes')}",
+            f"host fold {METRICS.get('sweep.host_fold_lanes')}; kernel "
+            f"export hits {METRICS.get('sweep.kernel_export_hits')}, "
+            f"misses {METRICS.get('sweep.kernel_export_misses')}, build "
+            f"{METRICS.gauge('sweep.kernel_build_s'):.3f} s",
             file=sys.stderr, flush=True,
         )
     return 0

@@ -36,7 +36,7 @@ import numpy as np
 
 from ..utils import trace as _trace
 from ..utils.metrics import METRICS
-from ..utils.platform import is_tpu
+from ..utils.platform import is_tpu, pallas_platform
 from .sha256 import (
     DigitPos,
     MsgLayout,
@@ -501,8 +501,6 @@ def _default_backend() -> str:
     default in :func:`auto_tune` (sieve ON, batch 1024, max_k 6) was
     measured under Mosaic and none transfer sight-unseen to Triton's
     warp-level cost model (ROADMAP follow-on)."""
-    from ..utils.platform import pallas_platform
-
     return "pallas" if pallas_platform() == "mosaic" else "xla"
 
 
@@ -787,10 +785,13 @@ def _build_kernel(
     The pallas tier uses the digit-position-DYNAMIC kernel: one compiled
     executable serves every digit class d in [k+1, 20] of this data length
     (per-class contributions are runtime inputs), so crossing a decimal
-    digit boundary mid-sweep never costs a fresh ~14 s trace+load
-    (BASELINE.md fleet section).  The returned closure carries a stable
-    ``class_key`` (the shared jit fn) so SweepPipeline's single-flight
-    build locks key on the executable, not the per-class wrapper.
+    digit boundary mid-sweep never costs a fresh trace and lower (16.5 s
+    on a v5e host).  Under the Mosaic lowering the kernel is served from
+    its stored export (ops/kernel_store.py), so once the store is warm a
+    fresh process does not trace it either.  The returned closure carries
+    a stable ``class_key`` (the shared kernel) so SweepPipeline's
+    single-flight build locks key on the executable, not the per-class
+    wrapper.
 
     The FACTORED pallas kernel is per-class STATIC, not dynamic — and
     must be: the dyn kernel's word window spans every digit class's
@@ -854,17 +855,23 @@ def _build_kernel(
                 sieve=sieve,
             )
         w_lo, w_hi = window
-        fn, n_pad = make_pallas_minhash_dyn(
-            layout.n_tail_blocks,
-            w_lo,
-            w_hi,
-            group.k,
-            batch,
+        params = dict(
+            n_tail_blocks=layout.n_tail_blocks,
+            w_lo=w_lo,
+            w_hi=w_hi,
+            k=group.k,
+            batch=batch,
             tile=tile if tile is not None else DEFAULT_TILE,
-            interpret=interpret,
             cpb=cpb,
             sieve=sieve,
         )
+        fn, n_pad = make_pallas_minhash_dyn(**params, interpret=interpret)
+        if not interpret and pallas_platform() == "mosaic":
+            # A fresh process loads the kernel's stored export instead of
+            # tracing and lowering it again (ops/kernel_store.py).
+            from .kernel_store import stored_kernel
+
+            fn = stored_kernel(fn, **params)
         contribs = _window_contribs_dev(group.k, low_pos, w_lo, w_hi, n_pad)
 
         # *th is empty (baseline) or the one threshold operand (sieve):
@@ -1309,8 +1316,8 @@ class SweepPipeline:
         self._prewarmed: set = set()
         self._prewarm_lock = threading.Lock()
         # Single-flight warm-up per kernel class (keyed by the lru-cached
-        # kernel object): a class's first invocation traces ~9 s of Python
-        # and loads the executable (~5 s more) — if the prewarm thread and
+        # kernel object): a class's first invocation in a process builds
+        # it (prewarm_async gives the costs) — if the prewarm thread and
         # the dispatcher both hit a cold class, they must share ONE build
         # (measured r5: the unsynchronized race re-traced the full 17 s in
         # the dispatcher even though prewarm was seconds from finishing).
@@ -1353,13 +1360,15 @@ class SweepPipeline:
         """Build + compile + device-load digit class ``d``'s kernel on a
         background thread, overlapping the device's current work.
 
-        Why: each digit class is a distinct kernel shape, and its
-        first-in-process use costs ~9 s of Python tracing plus ~5 s of
-        executable load *even on a persistent-cache hit* (measured before
-        PR 1 on an older remote runtime) — a mid-job stall if paid when the
-        sweep first
-        crosses a digit boundary.  The miner calls this speculatively for
-        the class one past each assignment's upper bound.
+        Why: each kernel shape's first use in a process traces and lowers
+        it *even on a persistent-cache hit* — 13.8 s + 2.7 s for the pallas
+        dyn kernel on a v5e host, which its stored export
+        (ops/kernel_store.py) cuts to ~0.1 s once the store is warm, while
+        a static per-class kernel pays it in full — then compiles it
+        (12.7 s) or loads it from the cache (0.04 s): a mid-job stall if
+        paid when the sweep first crosses a digit boundary.  The miner
+        calls this speculatively for the class one past each assignment's
+        upper bound.
 
         Returns False without spawning when the class is host-routed
         (see :class:`HostFold`), beyond u64's 20 digits, or already

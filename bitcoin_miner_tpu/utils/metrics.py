@@ -371,6 +371,9 @@ def format_quantiles(h) -> str:
 #:   sweep.host_fold_lanes     nonces of tiny digit classes min-folded on the host
 #:   sweep.ring_refills        chunk descriptors shipped to the hot plane's device ring
 #:   sweep.donated_dispatches  donated-carry steps enqueued by the always-hot plane
+#:   sweep.kernel_export_hits  pallas dyn kernels loaded from a stored export (no trace)
+#:   sweep.kernel_export_misses  pallas dyn kernels traced, exported and stored
+#:   sweep.kernel_build_s      seconds of a stored kernel's first call (gauge; the latest)
 #:   kernel.thresh_staleness   sieve-threshold lag in dispatches (gauge; 1 = device-resident)
 #:   client.resubmits          jobs resubmitted after a lost client conn
 #:   chaos.dropped             packets dropped by the network simulator

@@ -103,6 +103,12 @@ def force_virtual_cpu(n_devices: int) -> None:
         pass  # a backend already initialized; leave the caller's setup alone
 
 
+def compile_cache_dir() -> str:
+    """The persistent compile cache's directory: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else :data:`REPO_COMPILE_CACHE`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(REPO_COMPILE_CACHE)
+
+
 def enable_compile_cache() -> str:
     """Turn on the persistent XLA compilation cache and return its directory.
 
@@ -110,8 +116,7 @@ def enable_compile_cache() -> str:
     the CPU); a restarted miner or a repeat run loads them instead.  When
     ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and this
     sets no path; otherwise the cache is :data:`REPO_COMPILE_CACHE`."""
-    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        path = str(REPO_COMPILE_CACHE)
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", path)
     return path

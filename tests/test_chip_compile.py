@@ -11,7 +11,9 @@ one process at a time may load the TPU library, and under pytest-xdist
 every worker imports this file.  Keep these tests in this one file.
 """
 
+import base64
 import os
+import re
 
 import numpy as np
 import pytest
@@ -71,7 +73,8 @@ def _production_shape():
     return batch, sieve, group, layout, w_lo, w_hi
 
 
-def test_single_chip_dyn_kernel_compiles_for_v5e(topo):
+def _single_chip_dyn(topo):
+    """The production dyn kernel and its operands on one described chip."""
     batch, sieve, group, layout, w_lo, w_hi = _production_shape()
     assert sieve, "auto_tune turns the sieve on for pallas"
     fn, n_pad = make_pallas_minhash_dyn(
@@ -83,13 +86,57 @@ def test_single_chip_dyn_kernel_compiles_for_v5e(topo):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    compiled = fn.lower(
+    specs = [
         s((8,), jnp.uint32),
         s((batch, nw + 2), jnp.uint32),
         s((1,), jnp.int32),
         *(s((n_pad // 128, 128), jnp.uint32) for _ in range(w_hi - w_lo + 1)),
-    ).compile()
+    ]
+    return fn, specs
+
+
+def _mosaic_kernels(hlo_text):
+    """The Mosaic module of every ``tpu_custom_call``, as MLIR text with no
+    locations and its serialization version masked: an export writes the
+    forward-compatible version, a kernel traced in the process the newest,
+    and the compiler upgrades the former as it reads it."""
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for body in re.findall(
+        r'custom_call_target="tpu_custom_call".*?"body":"([^"]*)"', hlo_text
+    ):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True  # the serialized form
+            module = ir.Module.parse(base64.b64decode(body))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        out.append(re.sub(r"stable_mosaic\.version = \d+", "", asm))
+    return out
+
+
+def test_single_chip_dyn_kernel_compiles_for_v5e(topo):
+    fn, specs = _single_chip_dyn(topo)
+    compiled = fn.lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stored_dyn_kernel_export_compiles_for_v5e(topo):
+    """What a fresh miner process runs on a store hit: the production dyn
+    kernel exported for the TPU, serialized, deserialized, and its
+    ``call`` compiled for the chip.  It holds the same Mosaic kernel as
+    the kernel traced in the process."""
+    from jax import export
+
+    fn, specs = _single_chip_dyn(topo)
+    plain = [jax.ShapeDtypeStruct(s.shape, s.dtype) for s in specs]
+    blob = export.export(fn, platforms=["tpu"])(*plain).serialize()
+    exp = export.deserialize(blob)
+    assert exp.platforms == ("tpu",)
+    stored = jax.jit(exp.call).lower(*specs).compile().as_text()
+    traced = fn.lower(*specs).compile().as_text()
+    kernels = _mosaic_kernels(stored)
+    assert kernels, "no tpu_custom_call in the stored kernel's executable"
+    assert kernels == _mosaic_kernels(traced)
 
 
 def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):

@@ -1,0 +1,278 @@
+"""The store of exported pallas dyn kernels (ops/kernel_store.py).
+
+On the CPU the pallas kernels run in interpret mode, where the sweep drivers
+never engage the store (it serves the Mosaic lowering only).  So these tests
+call the store directly on an interpret-mode dyn kernel, or steer the
+drivers' pallas dyn branch onto it by patching the platform probe and the
+kernel factory, with every export stored under ``tmp_path``.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import threading
+from pathlib import Path
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bitcoin_miner_tpu.bitcoin import min_hash_range
+from bitcoin_miner_tpu.ops import kernel_store, pallas_sha256
+from bitcoin_miner_tpu.ops.kernel_store import StoredKernel, stored_kernel
+from bitcoin_miner_tpu.ops.pallas_sha256 import (
+    DEFAULT_TILE,
+    dyn_params,
+    make_pallas_minhash_dyn,
+    window_contribs_np,
+)
+from bitcoin_miner_tpu.ops.sha256 import build_layout
+from bitcoin_miner_tpu.ops.sweep import (
+    SweepPipeline,
+    _fill_templates,
+    decompose_range,
+    sweep_min_hash,
+)
+from bitcoin_miner_tpu.utils.metrics import METRICS
+
+REPO = Path(__file__).resolve().parents[1]
+DATA = "cmu440"
+# One d=4 class at k=2: 4 chunks of 100 nonces.
+LO, HI = 1000, 1399
+
+
+def _kernel(batch=2, sieve=True):
+    """A small interpret-mode dyn kernel (d=4, k=2), its factory's
+    parameters, and one dispatch's operands."""
+    group = next(decompose_range(LO, LO + 100 * batch - 1, max_k=2))
+    layout = build_layout(DATA.encode(), group.d)
+    w_lo, w_hi = dyn_params(layout, group.k)
+    params = dict(
+        n_tail_blocks=layout.n_tail_blocks, w_lo=w_lo, w_hi=w_hi, k=group.k,
+        batch=batch, tile=DEFAULT_TILE, cpb=None, sieve=sieve,
+    )
+    fn, n_pad = make_pallas_minhash_dyn(**params, interpret=True)
+    tail_const, bounds = _fill_templates(layout, group, group.chunks, batch)
+    tailcb = np.concatenate([tail_const, bounds.astype(np.uint32)], axis=1)
+    # The sieve threshold U32_MAX, sign-flipped as the kernel wants it.
+    th = (np.array([0x7FFFFFFF], dtype=np.int32),) if sieve else ()
+    low_pos = layout.digit_pos[layout.digit_count - group.k :]
+    args = [
+        np.array(layout.midstate, dtype=np.uint32), tailcb, *th,
+        *window_contribs_np(group.k, low_pos, w_lo, w_hi, n_pad),
+    ]
+    return fn, params, [jnp.asarray(a) for a in args]
+
+
+def _ints(out):
+    return [int(np.asarray(x)) for x in out]
+
+
+def _counts():
+    return (
+        METRICS.get("sweep.kernel_export_hits"),
+        METRICS.get("sweep.kernel_export_misses"),
+    )
+
+
+def _delta(before):
+    now = _counts()
+    return now[0] - before[0], now[1] - before[1]
+
+
+def _exports(directory):
+    return sorted(Path(directory).glob("*.jaxexport"))
+
+
+@pytest.fixture
+def production_store(tmp_path, monkeypatch):
+    """The sweep drivers' pallas dyn branch as it runs under Mosaic, on an
+    interpret-mode kernel, storing under ``tmp_path``."""
+    from bitcoin_miner_tpu.ops import sweep
+
+    real = pallas_sha256.make_pallas_minhash_dyn
+
+    def interpreted(*a, interpret, **kw):
+        return real(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(sweep, "pallas_platform", lambda: "mosaic")
+    monkeypatch.setattr(pallas_sha256, "make_pallas_minhash_dyn", interpreted)
+    monkeypatch.setattr(kernel_store, "store_dir", lambda: tmp_path)
+    stored_kernel.cache_clear()
+    yield tmp_path
+    stored_kernel.cache_clear()
+
+
+def _sweep():
+    return sweep_min_hash(
+        DATA, LO, HI, backend="pallas", interpret=False, batch=2, max_k=2
+    )
+
+
+_CHILD = """
+import json, sys
+sys.path[:0] = [{repo!r}, {tests!r}]
+from bitcoin_miner_tpu.utils.platform import force_virtual_cpu
+force_virtual_cpu(1)
+from test_kernel_export import _counts, _ints, _kernel
+from bitcoin_miner_tpu.ops.kernel_store import StoredKernel
+_fn, params, args = _kernel()
+# No kernel to trace: only the stored export can serve this call.
+out = StoredKernel(None, params, sys.argv[1])(*args)
+print(json.dumps({{"out": _ints(out), "counts": _counts()}}))
+"""
+
+
+def test_export_loads_in_fresh_process_bit_identical(tmp_path):
+    fn, params, args = _kernel()
+    traced = _ints(fn(*args))
+    before = _counts()
+    assert _ints(StoredKernel(fn, params, tmp_path)(*args)) == traced
+    assert _delta(before) == (0, 1)
+    assert len(_exports(tmp_path)) == 1
+    child = subprocess.run(
+        [sys.executable, "-c",
+         _CHILD.format(repo=str(REPO), tests=str(REPO / "tests")),
+         str(tmp_path)],
+        capture_output=True, text=True, timeout=180,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert child.returncode == 0, child.stderr[-3000:]
+    got = json.loads(child.stdout.strip().splitlines()[-1])
+    assert got == {"out": traced, "counts": [1, 0]}
+
+
+@pytest.mark.parametrize(
+    "part", ["same", "batch", "sieve", "source_digest", "jax_version"]
+)
+def test_each_key_part_misses_when_changed(part, tmp_path, monkeypatch):
+    fn, params, args = _kernel()
+    StoredKernel(fn, params, tmp_path)(*args)
+    if part == "batch":
+        fn, params, args = _kernel(batch=4)
+    elif part == "sieve":
+        fn, params, args = _kernel(sieve=False)
+    elif part == "source_digest":
+        digest = kernel_store.source_digest()
+        monkeypatch.setattr(
+            kernel_store, "source_digest", lambda: "0" * len(digest)
+        )
+    elif part == "jax_version":
+        versions = kernel_store.runtime_versions()
+        monkeypatch.setattr(
+            kernel_store, "runtime_versions",
+            lambda: {**versions, "jax": versions["jax"] + ".post1"},
+        )
+    before = _counts()
+    out = _ints(StoredKernel(fn, params, tmp_path)(*args))
+    assert out == _ints(fn(*args))
+    if part == "same":
+        assert _delta(before) == (1, 0)
+        assert len(_exports(tmp_path)) == 1
+    else:
+        assert _delta(before) == (0, 1)
+        assert len(_exports(tmp_path)) == 2
+
+
+@pytest.mark.parametrize("module", ["pallas_sha256.py", "sha256.py"])
+def test_source_digest_follows_each_traced_module(module, tmp_path, monkeypatch):
+    """An edit to either module traced into the kernel changes the
+    digest, and so the key (see the ``source_digest`` case above)."""
+    ops = Path(kernel_store.__file__).parent
+    assert module in kernel_store._SOURCES
+    for name in kernel_store._SOURCES:
+        (tmp_path / name).write_bytes((ops / name).read_bytes())
+    monkeypatch.setattr(kernel_store, "__file__", str(tmp_path / "kernel_store.py"))
+    digest = kernel_store.source_digest.__wrapped__
+    assert digest() == kernel_store.source_digest()
+    with open(tmp_path / module, "a") as f:
+        f.write("\n# an edit\n")
+    assert digest() != kernel_store.source_digest()
+
+
+def test_store_sits_beside_the_compile_cache(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert kernel_store.store_dir() == tmp_path / "kernel_exports"
+
+
+def test_truncated_export_is_a_counted_miss_and_rewritten(production_store):
+    want = min_hash_range(DATA, LO, HI)
+    before = _counts()
+    r = _sweep()
+    assert (r.hash, r.nonce) == want
+    assert _delta(before) == (0, 1)
+    (path,) = _exports(production_store)
+    whole = path.read_bytes()
+    path.write_bytes(whole[: len(whole) // 2])
+
+    stored_kernel.cache_clear()  # what a fresh process starts from
+    before = _counts()
+    r = _sweep()
+    assert (r.hash, r.nonce) == want
+    assert _delta(before) == (0, 1)
+    assert _exports(production_store) == [path]
+    assert path.stat().st_size == len(whole)
+
+    stored_kernel.cache_clear()
+    before = _counts()
+    r = _sweep()
+    assert (r.hash, r.nonce) == want
+    assert _delta(before) == (1, 0)
+
+
+def test_prewarm_racing_dispatch_writes_one_export(production_store, monkeypatch):
+    # Widen the race: the export takes a beat longer than the dispatcher
+    # needs to reach the same cold class.
+    exported = StoredKernel._exported
+
+    def slow(self, args):
+        threading.Event().wait(0.5)
+        return exported(self, args)
+
+    monkeypatch.setattr(StoredKernel, "_exported", slow)
+    p = SweepPipeline(
+        backend="pallas", interpret=False, batch=2, max_k=2, host_lane_budget=0
+    )
+    before = _counts()
+    try:
+        assert p.prewarm_async(DATA, len(str(LO)))
+        r = p.submit(DATA, LO, HI).result(timeout=180)
+    finally:
+        p.close()
+    assert (r.hash, r.nonce) == min_hash_range(DATA, LO, HI)
+    assert _delta(before) == (0, 1)
+    assert len(_exports(production_store)) == 1
+
+
+def test_concurrent_first_calls_export_once(tmp_path):
+    fn, params, args = _kernel()
+    traced = _ints(fn(*args))
+    kern = StoredKernel(fn, params, tmp_path)
+    n = 16
+    start = threading.Barrier(n)
+    outs, errors = [], []
+
+    def first_call():
+        try:
+            start.wait(timeout=60)
+            outs.append(_ints(kern(*args)))
+        except Exception as e:  # reported below
+            errors.append(e)
+
+    before = _counts()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=first_call) for _ in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=180)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
+    assert outs == [traced] * n
+    assert _delta(before) == (0, 1)
+    assert len(_exports(tmp_path)) == 1
