@@ -458,11 +458,55 @@ def _workload_knobs(workload) -> Tuple[bytes, object, bool, str]:
     return workload.sep, workload._cpu_search(), False, family
 
 
+@dataclass(frozen=True)
+class MeshRows:
+    """Where the ``n_rows`` valid rows of one mesh dispatch sit.
+
+    The dispatch has one block of ``per_dev_batch`` slots per device, and
+    the operands are sharded contiguously along the mesh axis, so block
+    ``dev`` runs on device ``dev``.  The rows split as evenly as they go:
+    each device takes ``n_rows // n_devices`` rows and the first
+    ``n_rows % n_devices`` take one more, at the front of their block, in
+    ascending nonce order; padding fills the end of each block.  Blocks
+    stay in device order, so ``(device, slot)`` order is still nonce
+    order and the collective cascade's lowest-(device, flat) tie-break
+    stays lowest-nonce.  The one owner of the slot <-> row map: the
+    template fill places rows with :meth:`slots`, every fold resolves a
+    winning ``(device, local row)`` with :meth:`row`."""
+
+    n_rows: int
+    n_devices: int
+
+    def counts(self) -> Tuple[int, ...]:
+        """Valid rows on each device (no two differ by more than one)."""
+        q, r = divmod(self.n_rows, self.n_devices)
+        return tuple(q + (d < r) for d in range(self.n_devices))
+
+    def row(self, dev: int, local: int) -> int:
+        """The row held by slot ``local`` of device ``dev``'s block."""
+        q, r = divmod(self.n_rows, self.n_devices)
+        return dev * q + min(dev, r) + local
+
+    def slots(self, per_dev_batch: int) -> List[int]:
+        """Each row's slot in the dispatch, in row order."""
+        return [
+            dev * per_dev_batch + i
+            for dev, c in enumerate(self.counts())
+            for i in range(c)
+        ]
+
+
 def _fill_templates(
-    layout: MsgLayout, group: ChunkGroup, chunk_rows: Sequence[Chunk], batch: int
+    layout: MsgLayout,
+    group: ChunkGroup,
+    chunk_rows: Sequence[Chunk],
+    batch: int,
+    slots: Optional[Sequence[int]] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Host-side: fold each chunk's constant high digits into the word
-    template; build the (B, 2) lane-bound array, padding unused rows empty."""
+    template; build the (B, 2) lane-bound array, padding unused rows empty.
+    Row ``r`` goes to slot ``r``, or to ``slots[r]`` when given (a mesh
+    dispatch's placement, :class:`MeshRows`)."""
     tail_const = np.tile(
         np.array(layout.tail_template, dtype=np.uint64), (batch, 1)
     )  # u64 scratch to avoid overflow warnings, cast at the end
@@ -470,13 +514,14 @@ def _fill_templates(
     span = 10**group.k
     n_high = layout.digit_count - group.k
     for r, ch in enumerate(chunk_rows):
+        s = r if slots is None else slots[r]
         if n_high > 0:
             high = str(ch.base // span)
             assert len(high) == n_high, (high, n_high, ch)
             for j, ch_digit in enumerate(high):
                 dp = layout.digit_pos[j]
-                tail_const[r, dp.word] |= ord(ch_digit) << dp.shift
-        bounds[r] = (ch.lo_off, ch.hi_off)
+                tail_const[s, dp.word] |= ord(ch_digit) << dp.shift
+        bounds[s] = (ch.lo_off, ch.hi_off)
     return tail_const.astype(np.uint32), bounds
 
 
@@ -692,9 +737,13 @@ def run_sweep_dispatches(
     sep: bytes = b" ",
     host_min=None,
     family: str = "sha256",
+    n_devices: int = 1,
 ) -> int:
     """The decompose → template-fill → dispatch skeleton shared by the
     single-device (below) and sharded (parallel/sweep.py) drivers.
+    ``n_devices > 1`` is a mesh dispatch: ``batch`` is then ``n_devices``
+    blocks of slots, and each dispatch's rows spread over them
+    (:class:`MeshRows`).
 
     ``sep``/``host_min``/``family`` are the workload knobs
     (``_workload_knobs``): the message-template separator baked into
@@ -737,7 +786,12 @@ def run_sweep_dispatches(
         midstate = np.array(layout.midstate, dtype=np.uint32)
         for s in range(0, len(group.chunks), batch):
             rows = group.chunks[s : s + batch]
-            tail_const, bounds = _fill_templates(layout, group, rows, batch)
+            slots = None
+            if n_devices > 1:
+                place = MeshRows(len(rows), n_devices)
+                slots = place.slots(batch // n_devices)
+                _count_mesh_dispatch(place)
+            tail_const, bounds = _fill_templates(layout, group, rows, batch, slots)
             out = run_kernel(kern, midstate, tail_const, bounds)
             pending.append((out, [c.base for c in rows], 10**group.k))
             n_dev = sum(c.hi_off - c.lo_off for c in rows)
@@ -748,6 +802,20 @@ def run_sweep_dispatches(
     while pending:
         consume(*pending.popleft())
     return lanes
+
+
+def _count_mesh_dispatch(place: MeshRows) -> None:
+    """One mesh dispatch's placement: its valid rows, and the slots the
+    mesh spends on them (every device works as long as the fullest one)."""
+    counts = place.counts()
+    METRICS.inc("sweep.mesh_rows", place.n_rows)
+    METRICS.inc("sweep.mesh_row_slots", place.n_devices * max(counts))
+    METRICS.inc("sweep.mesh_dispatches")
+    if _trace.enabled():
+        _trace.emit(
+            None, "miner", "mesh_dispatch",
+            rows=place.n_rows, per_device=list(counts),
+        )
 
 
 @lru_cache(maxsize=8)
@@ -1056,15 +1124,11 @@ class _HotLoop:
 
     _RING_DEPTH = 8
 
-    def __init__(
-        self, backend, sieve, *, mesh=None, axis_name="miners",
-        per_dev_batch=0,
-    ):
+    def __init__(self, backend, sieve, *, mesh=None, axis_name="miners"):
         self._backend = backend
         self._sieve = sieve
         self._mesh = mesh
         self._axis_name = axis_name
-        self._per_dev_batch = per_dev_batch
         self._carry = None
         self._seq = 0
         self._drained = 0
@@ -1177,7 +1241,6 @@ class _HotLoop:
             bh0, bh1, bseq, bflat = (
                 int(x) for x in self._carry
             )  # donate-ok: THE job-end fetch — the one sanctioned sync
-            bdev = 0
         if bflat == I32_MAX:
             return None
         entry = self._bases.get(bseq)
@@ -1188,7 +1251,9 @@ class _HotLoop:
                 "hot sweep winner's descriptor was never drained"
             )
         bases, n_lanes = entry
-        row = bdev * self._per_dev_batch + bflat // n_lanes
+        row = bflat // n_lanes
+        if self._mesh is not None:
+            row = MeshRows(len(bases), self._mesh.devices.size).row(bdev, row)
         return ((bh0 << 32) | bh1, bases[row] + bflat % n_lanes)
 
 
@@ -1287,11 +1352,13 @@ class SweepPipeline:
         # Mesh mode: the same cross-request pipeline drives the sharded
         # (shard_map + pmin cascade) kernels — a multi-chip miner must not
         # idle its whole mesh between the scheduler's chunks any more than
-        # a single chip may.  ``batch`` stays per-device; dispatch rows
-        # total n_devices * batch, sharded contiguously along axis_name.
+        # a single chip may.  ``batch`` stays per-device; dispatch slots
+        # total n_devices * batch, sharded contiguously along axis_name,
+        # and each dispatch's rows spread evenly over them (MeshRows).
         self._mesh = mesh
         self._axis_name = axis_name
         self._per_dev_batch = self._batch
+        self._n_devices = 1 if mesh is None else mesh.devices.size
         # None = auto: this is the miner's production path, where a tiny
         # digit class must never cost a Mosaic compile (see HostFold).
         self._host_lane_budget = (
@@ -1301,7 +1368,7 @@ class SweepPipeline:
         if mesh is not None:
             from ..utils.platform import is_tpu_device
 
-            self._batch = mesh.devices.size * self._per_dev_batch
+            self._batch = self._n_devices * self._per_dev_batch
             self._rolled = not is_tpu_device(mesh.devices.flat[0])
         else:
             self._rolled = not is_tpu()
@@ -1528,7 +1595,6 @@ class SweepPipeline:
                 state["hot"] = _HotLoop(
                     self._backend, self._sieve, mesh=self._mesh,
                     axis_name=self._axis_name,
-                    per_dev_batch=self._per_dev_batch,
                 )
 
             def run_kernel(kern, midstate, tail_const, bounds):
@@ -1584,6 +1650,7 @@ class SweepPipeline:
                     sep=self._sep,
                     host_min=self._host_min,
                     family=self._family,
+                    n_devices=self._n_devices,
                 )
             except BaseException as e:  # resolve, don't kill the pipeline
                 self._fail(fut, e)
@@ -1655,7 +1722,9 @@ class SweepPipeline:
                 if len(handles) == 4:  # mesh mode: (h0, h1, device, flat)
                     h0, h1, dev, flat_idx = handles
                     fi = int(flat_idx)  # blocks until the dispatch lands
-                    row = int(dev) * self._per_dev_batch + fi // n_lanes
+                    row = MeshRows(len(bases), self._n_devices).row(
+                        int(dev), fi // n_lanes
+                    )
                 else:
                     h0, h1, flat_idx = handles
                     fi = int(flat_idx)

@@ -8,9 +8,11 @@ analogue of the reference's server-side min-fold over miner Results
 (``bitcoin/message.go:38-44``), and the ``lax.pmin`` reduction named in the
 BASELINE north star.
 
-Tie-break: chunk rows are sharded *contiguously* in ascending-nonce order, so
-``(device, flat_idx)`` lexicographic order equals nonce order and the
-collective cascade preserves lowest-nonce-wins.
+Tie-break: a dispatch's rows spread evenly over the devices' blocks of
+slots, each block holding a contiguous run of them in ascending-nonce order
+(``ops.sweep.MeshRows``), and the blocks are sharded *contiguously* along the
+mesh axis, so ``(device, flat_idx)`` lexicographic order equals nonce order
+and the collective cascade preserves lowest-nonce-wins.
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ from ..utils.platform import is_tpu_device
 from ..ops.sweep import (
     I32_MAX,
     U32_MAX,
+    MeshRows,
     SweepResult,
     _workload_knobs,
     auto_tune,
@@ -87,8 +90,8 @@ def _make_sharded_kernel(
 
     Returned jitted fn: ``(midstate (8,), tail_const (B, nw), bounds (B, 2))
     -> (g_h0, g_h1, g_dev, g_flat)`` replicated scalars, where
-    ``B = n_devices * per_dev_batch`` and rows are sharded contiguously
-    along ``axis_name``.
+    ``B = n_devices * per_dev_batch`` and the slot blocks are sharded
+    contiguously along ``axis_name``.
 
     ``factored`` (ISSUE 16 satellite, xla only — the pallas branch
     ignores it, see :func:`sharded_kernel_for`): the inner digit count
@@ -370,7 +373,8 @@ def sharded_kernel_for(
 
 def shard_operands(midstate, tail_const, bounds, mesh: Mesh, axis_name: str):
     """Place one dispatch's chunk descriptor on the mesh, asynchronously:
-    rows sharded contiguously along ``axis_name``, midstate replicated.
+    slot blocks sharded contiguously along ``axis_name`` (block ``d`` on
+    device ``d``, filled by ``ops.sweep.MeshRows``), midstate replicated.
     Shared by :func:`sharded_invoke` and the hot plane's descriptor-ring
     refills (``ops.sweep._HotLoop``), so both dispatch forms ship
     byte-identical operand placements."""
@@ -419,8 +423,9 @@ def sweep_min_hash_sharded(
     """Multi-chip ``(min Hash(data, n), argmin n)`` over inclusive
     ``[lower, upper]``; bit-exact vs the hashlib oracle, lowest-nonce ties.
 
-    Chunk rows pad up to ``n_devices * batch_per_device`` per dispatch
-    (padded rows have empty lane bounds and are masked in-kernel).  Results
+    A dispatch has ``n_devices * batch_per_device`` slots; its rows spread
+    evenly over the devices (``ops.sweep.MeshRows``) and the padding slots
+    have empty lane bounds, masked in-kernel.  Results
     are fetched lazily after all dispatches are queued so the device
     pipeline stays full.
 
@@ -471,10 +476,7 @@ def sweep_min_hash_sharded(
     from ..ops.sweep import _HotLoop, _HotToken
 
     hotloop = (
-        _HotLoop(
-            backend, sieve, mesh=mesh, axis_name=axis_name,
-            per_dev_batch=batch_per_device,
-        )
+        _HotLoop(backend, sieve, mesh=mesh, axis_name=axis_name)
         if hot
         else None
     )
@@ -516,7 +518,7 @@ def sweep_min_hash_sharded(
         fi = int(flat)
         if fi == I32_MAX:
             return
-        row = int(dev) * batch_per_device + fi // n_lanes
+        row = MeshRows(len(bases), n_dev).row(int(dev), fi // n_lanes)
         h = (int(h0) << 32) | int(h1)
         cand = (h, bases[row] + fi % n_lanes)
         if not best or cand < best[0]:
@@ -524,7 +526,7 @@ def sweep_min_hash_sharded(
 
     lanes = run_sweep_dispatches(
         data, lower, upper, max_k, batch, get_kernel, run_kernel, consume,
-        sep=sep, host_min=host_min, family=family,
+        sep=sep, host_min=host_min, family=family, n_devices=n_dev,
     )
     if hotloop is not None:
         cand = hotloop.finish()

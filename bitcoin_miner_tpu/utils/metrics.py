@@ -374,6 +374,9 @@ def format_quantiles(h) -> str:
 #:   sweep.kernel_export_hits  pallas dyn kernels loaded from a stored export (no trace)
 #:   sweep.kernel_export_misses  pallas dyn kernels traced, exported and stored
 #:   sweep.kernel_build_s      seconds of a stored kernel's first call (gauge; the latest)
+#:   sweep.mesh_rows           valid chunk rows placed by mesh dispatches
+#:   sweep.mesh_row_slots      n_devices x the fullest device's rows, per mesh dispatch
+#:   sweep.mesh_dispatches     mesh (sharded) dispatches enqueued
 #:   kernel.thresh_staleness   sieve-threshold lag in dispatches (gauge; 1 = device-resident)
 #:   client.resubmits          jobs resubmitted after a lost client conn
 #:   chaos.dropped             packets dropped by the network simulator

@@ -16,6 +16,7 @@ The driver's dryrun_multichip runs the first two.
 """
 
 import jax
+import pytest
 
 from bitcoin_miner_tpu.bitcoin.hash import min_hash_range
 from bitcoin_miner_tpu.parallel import default_mesh, sweep_min_hash_sharded
@@ -257,3 +258,194 @@ def test_make_async_search_routes_mesh_to_pipeline():
         assert (h, n) == min_hash_range("cmu440", 1000, 1999)
     finally:
         s.close()
+
+
+# -- Even row placement over a 4-device mesh ------------------------------
+#
+# A mesh dispatch of R valid rows spreads them over the devices' blocks of
+# slots (ops.sweep.MeshRows): each device holds a contiguous run at the
+# front of its block, no two devices differ by more than one row, and
+# (device, slot) order stays nonce order.  batch_per_device 5 over 4
+# devices gives 20 slots a dispatch; k=2 rows of 100 nonces from 1000 make
+# a range of R rows one dispatch of R rows.
+
+N_DEV, PER_DEV = 4, 5
+
+
+def _dispatch_rows(lo, hi, max_k):
+    """Valid rows of each dispatch the sharded sweeps make of [lo, hi]."""
+    from bitcoin_miner_tpu.ops.sweep import decompose_range
+
+    batch = N_DEV * PER_DEV
+    return [
+        min(batch, len(g.chunks) - s)
+        for g in decompose_range(lo, hi, max_k=max_k)
+        for s in range(0, len(g.chunks), batch)
+    ]
+
+
+def _mesh_sweep(form, backend, data, lo, hi, max_k, **kw):
+    from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+
+    mesh = default_mesh(N_DEV)
+    interpret = backend == "pallas"
+    if form == "sharded":
+        return sweep_min_hash_sharded(
+            data, lo, hi, mesh=mesh, backend=backend, interpret=interpret,
+            max_k=max_k, batch_per_device=PER_DEV, **kw,
+        )
+    p = SweepPipeline(
+        backend=backend, interpret=interpret, mesh=mesh, max_k=max_k,
+        batch=PER_DEV, host_lane_budget=0, **kw,
+    )
+    try:
+        return p.submit(data, lo, hi).result(timeout=200)
+    finally:
+        p.close()
+
+
+@pytest.mark.parametrize("form,backend,hot", [
+    ("sharded", "xla", False),
+    ("sharded", "pallas", False),
+    ("pipeline", "xla", False),
+    ("pipeline", "xla", True),  # the hot plane's job-end fold
+])
+@pytest.mark.parametrize("data,lo,hi,max_k", [
+    ("cmu440", 1000, 1099, 2),  # R = 1: device 0 alone
+    ("cmu440", 1000, 1299, 2),  # R = 3 = n - 1: one device idle
+    ("cmu440", 1000, 1499, 2),  # R = 5 = n + 1
+    ("cmu440", 1050, 1549, 2),  # R = 6 = one device's batch + 1, runt ends
+    ("cmu440", 1000, 2999, 2),  # R = 20 = n x batch: every slot full
+    ("x", 95, 305, 1),  # crosses d=2 -> d=3: dispatches of 1, 20 and 1 rows
+])
+def test_mesh_even_placement_matches_oracle(
+    form, backend, hot, data, lo, hi, max_k, monkeypatch
+):
+    import numpy as np
+
+    from bitcoin_miner_tpu.ops.sweep import MeshRows
+    from bitcoin_miner_tpu.parallel import sweep as psweep
+    from bitcoin_miner_tpu.utils import trace
+    from bitcoin_miner_tpu.utils.metrics import METRICS
+
+    shipped = []  # each dispatch's bounds, as placed on the mesh
+    place = psweep.shard_operands  # both dispatch forms place through it
+
+    def spy(midstate, tail_const, bounds, *a, **kw):
+        shipped.append(np.array(bounds))
+        return place(midstate, tail_const, bounds, *a, **kw)
+
+    monkeypatch.setattr(psweep, "shard_operands", spy)
+    names = ("sweep.mesh_rows", "sweep.mesh_row_slots", "sweep.mesh_dispatches")
+    before = [METRICS.get(n) for n in names]
+    with trace.tracing() as tr:
+        r = _mesh_sweep(form, backend, data, lo, hi, max_k, hot=hot)
+        events = [e for e in tr.drain() if e["event"] == "mesh_dispatch"]
+    assert (r.hash, r.nonce) == min_hash_range(data, lo, hi)
+    assert r.lanes_swept == hi - lo + 1
+    want = _dispatch_rows(lo, hi, max_k)
+    # Where the rows went: each device's valid rows sit at the front of
+    # its block, and no two devices differ by more than one row.
+    assert len(shipped) == len(want)
+    for bounds, rows in zip(shipped, want):
+        valid = (bounds[:, 1] > bounds[:, 0]).reshape(N_DEV, PER_DEV)
+        per_dev = valid.sum(axis=1).tolist()
+        assert per_dev == list(MeshRows(rows, N_DEV).counts())
+        assert max(per_dev) - min(per_dev) <= 1
+        assert all(valid[d, :c].all() for d, c in enumerate(per_dev))
+    # The counters and the trace event say the same.
+    got_rows, slots, dispatches = (METRICS.get(n) - b for n, b in zip(names, before))
+    assert got_rows == sum(want)
+    assert dispatches == len(want)
+    assert slots == sum(N_DEV * -(-rows // N_DEV) for rows in want)
+    assert [e["attrs"]["rows"] for e in events] == want
+    for e, rows in zip(events, want):
+        assert e["attrs"]["per_device"] == list(MeshRows(rows, N_DEV).counts())
+
+
+def test_mesh_rows_slot_map_is_nonce_ordered():
+    from bitcoin_miner_tpu.ops.sweep import MeshRows
+
+    for r in range(0, 4 * PER_DEV + 1):
+        place = MeshRows(r, N_DEV)
+        slots = place.slots(PER_DEV)
+        assert slots == sorted(slots) and len(set(slots)) == r
+        assert all(s // PER_DEV < N_DEV and s % PER_DEV < PER_DEV for s in slots)
+        # row() inverts slots(): (device, local slot) -> row, in order.
+        assert [place.row(s // PER_DEV, s % PER_DEV) for s in slots] == list(range(r))
+
+
+@pytest.mark.parametrize("form", ["sharded", "pipeline"])
+@pytest.mark.parametrize("hot", [False, True])
+def test_mesh_cross_device_tie_lowest_nonce_wins(form, hot, monkeypatch):
+    # A stand-in kernel hashes nonce n to (7, 3) if n >= 1500, else
+    # (7, 9), reading n from the chunk templates and lanes as the real
+    # kernel sees them.  Over [1050, 1699] (7 rows, 1000..1600, of 100
+    # nonces; 2, 2, 2 and 1 rows per device) the minimum (7, 3) ties on
+    # both digest words across devices 2 (row 1500, local slot 1) and 3
+    # (row 1600, local slot 0, the lower flat index): the cascade must
+    # pick the lower device, and the fold must map it back to nonce 1500.
+    # A placement the fold does not mirror names another nonce.
+    import jax.numpy as jnp
+
+    from bitcoin_miner_tpu.ops.sweep import I32_MAX, U32_MAX
+    from bitcoin_miner_tpu.parallel import sweep as psweep
+
+    def tie_kernel(layout, group, per_dev_batch, mesh, axis_name, *a, **kw):
+        n_lanes = 10**group.k
+        n_high = layout.digit_count - group.k
+
+        def local(midstate, tail_const, bounds):
+            high = jnp.zeros(tail_const.shape[0], jnp.int32)
+            for dp in layout.digit_pos[:n_high]:
+                byte = (tail_const[:, dp.word] >> dp.shift) & 0xFF
+                high = high * 10 + byte.astype(jnp.int32) - 48
+            i = jnp.arange(n_lanes, dtype=jnp.int32)[None, :]
+            nonce = high[:, None] * n_lanes + i
+            valid = (i >= bounds[:, :1]) & (i < bounds[:, 1:2])
+            h1 = jnp.where(nonce >= 1500, jnp.uint32(3), jnp.uint32(9))
+            h1 = jnp.where(valid, h1, jnp.uint32(U32_MAX))
+            min_h1 = jnp.min(h1)
+            flat = jnp.arange(h1.size, dtype=jnp.int32).reshape(h1.shape)
+            first = jnp.min(
+                jnp.where(valid & (h1 == min_h1), flat, jnp.int32(I32_MAX))
+            )
+            h0 = jnp.where(first != I32_MAX, jnp.uint32(7), jnp.uint32(U32_MAX))
+            return h0, min_h1, first
+
+        return psweep._shard_and_jit(local, mesh, axis_name, False)
+
+    monkeypatch.setattr(psweep, "sharded_kernel_for", tie_kernel)
+    r = _mesh_sweep(form, "xla", "cmu440", 1050, 1699, 2, sieve=False, hot=hot)
+    assert (r.hash, r.nonce) == ((7 << 32) | 3, 1500)
+
+
+def test_single_device_templates_unchanged():
+    # The single-device template fill, byte for byte as before mesh
+    # placement existed: a digest of its output over three ranges (one
+    # digit class, a digit boundary, an 11-digit class at k=6).
+    import hashlib
+
+    from bitcoin_miner_tpu.ops.sweep import (
+        _fill_templates,
+        _layout_cache,
+        decompose_range,
+    )
+
+    out = []
+    for data, lo, hi, k, batch in [
+        ("cmu440", 1234567, 1534566, 5, 8),
+        ("x", 95, 305, 1, 32),
+        ("cmu440", 99_000_000_000, 99_004_999_999, 6, 8),
+    ]:
+        for g in decompose_range(lo, hi, max_k=k):
+            layout = _layout_cache(data.encode(), g.d)
+            for s in range(0, len(g.chunks), batch):
+                rows = g.chunks[s : s + batch]
+                t, b = _fill_templates(layout, g, rows, batch)
+                t2, b2 = _fill_templates(layout, g, rows, batch, range(len(rows)))
+                assert t.tobytes() == t2.tobytes() and b.tobytes() == b2.tobytes()
+                out.append(t.tobytes() + b.tobytes())
+    assert hashlib.sha256(b"".join(out)).hexdigest() == (
+        "b861cd02c106261fb58f7e6bd7a148c98768bf294754dd8eb93de2fb9c5ead0c"
+    )

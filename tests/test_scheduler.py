@@ -6,6 +6,8 @@ result folding, adaptive chunking, dead-miner reassignment, dead-client
 cancellation, fairness.
 """
 
+import pytest
+
 from bitcoin_miner_tpu.apps.scheduler import Scheduler
 from bitcoin_miner_tpu.bitcoin.hash import min_hash_range
 from bitcoin_miner_tpu.bitcoin.message import MsgType
@@ -259,6 +261,42 @@ class TestAdaptiveChunking:
         # boundary: lower=20 (after the two cold chunks) runs to 999.
         assert nxt.upper - nxt.lower + 1 <= 1000
         assert (nxt.upper + 1) % 1000 == 0
+
+
+def _settled_chunks(rate, n=80, **kw):
+    """Chunk sizes a scheduler hands one miner sweeping at ``rate``
+    nonces/s, results back to back over one long job (each chunk's
+    Result lands its size / rate after the previous one)."""
+    s = Scheduler(validate_results=False, **kw)
+    s.miner_joined(1, now=0.0)
+    s.client_request(10, "d", 0, 10**14, now=0.0)
+    t, sizes = 0.0, []
+    for _ in range(n):
+        lo, hi = s.miners[1].interval
+        sizes.append(hi - lo + 1)
+        t += (hi - lo + 1) / rate
+        s.result(1, hash_=5, nonce=lo, now=t)
+    return s, sizes
+
+
+class TestChunkCeiling:
+    """Where the default ladder settles a miner: one v5e chip (~1.95e9
+    n/s) and the four-chip mesh miner (~7.8e9 n/s) both reach the 10^9
+    rung, the chunk ceiling: the mesh's ideal 3.9e9 is capped at
+    max_chunk before the ladder rounds it, so it never climbs to 10^10."""
+
+    @pytest.mark.parametrize("rate,kw,size", [
+        (1.95e9, {}, 10**9),  # one chip
+        (7.8e9, {}, 10**9),  # four-chip mesh miner
+        (7.8e9, {"min_chunk": 10**6, "max_chunk": 10**6}, 10**6),  # static leg
+    ])
+    def test_miner_settles_on_its_rung(self, rate, kw, size):
+        s, sizes = _settled_chunks(rate, **kw)
+        assert sizes[-40:] == [size] * 40
+        if kw:
+            assert set(sizes) == {size}  # the static leg never ramps
+        else:
+            assert s.miners[1].rung == 9
 
 
 class TestStealScan:
