@@ -462,9 +462,10 @@ def _workload_knobs(workload) -> Tuple[bytes, object, bool, str]:
 class MeshRows:
     """Where the ``n_rows`` valid rows of one mesh dispatch sit.
 
-    The dispatch has one block of ``per_dev_batch`` slots per device, and
-    the operands are sharded contiguously along the mesh axis, so block
-    ``dev`` runs on device ``dev``.  The rows split as evenly as they go:
+    The dispatch has one block of ``per_dev_batch`` slots per device (by
+    default :func:`auto_tune`'s 1024 slots in all, split over the
+    devices), and the operands are sharded contiguously along the mesh
+    axis, so block ``dev`` runs on device ``dev``.  The rows split as evenly as they go:
     each device takes ``n_rows // n_devices`` rows and the first
     ``n_rows % n_devices`` take one more, at the front of their block, in
     ascending nonce order; padding fills the end of each block.  Blocks
@@ -557,11 +558,22 @@ def auto_tune(
     factored: Optional[bool] = None,
     hot: Optional[bool] = None,
     family: str = "sha256",
+    n_devices: int = 1,
 ) -> Tuple[str, int, int, bool, bool, bool]:
-    """Resolve the (backend, rows-per-dispatch, max_k, sieve, factored,
+    """Resolve the (backend, rows-per-device, max_k, sieve, factored,
     hot) defaults shared by the single-device and sharded sweep drivers.
     max_k=5 bounds the xla tier's compress_rolled schedule buffer
     ((16, B, 10^k) u32) to ~50 MB at B=8.
+
+    ``n_devices`` is the number of devices one dispatch spans.  The pallas
+    default holds ``DEFAULT_BATCH`` slots per dispatch in all, so each
+    device gets ``ceil(1024 / n_devices)`` rounded up to a multiple of
+    ``DEFAULT_CPB``: 1024 on one chip, 256 on four.  A scheduler chunk of
+    ~1000 rows then fills ~98% of a mesh dispatch's row groups instead of
+    ~24%; an empty group still pays the grid's per-step cost (on one v5e,
+    250 rows took 136.6 ms a dispatch at 1024 slots, 128.9 ms at 256).  An
+    explicit ``batch`` is per device and is kept; so are the xla and
+    blake2b defaults.
 
     ``family`` resolves PER-WORKLOAD rung defaults (ISSUE 20) — the
     tuple was sha256-template-only before the BLAKE2b device tier
@@ -659,14 +671,21 @@ def auto_tune(
         # batch 2048 for FULL dispatches (1.907e9 vs 1.899e9 bench), but
         # the fleet's EWMA chunks (~0.95e9 at target_chunk_seconds=0.5)
         # half-fill a 2048-row batch and measured 1.79e9 delivered vs
-        # 1.82e9 at 1024 — the scheduler-matched 1024 wins end-to-end.
+        # 1.82e9 at 1024 — the scheduler-matched 1024 wins end-to-end, so
+        # a mesh dispatch splits those 1024 slots over its devices.
         # xla default measured via bench.py --autotune on XLA:CPU: batch 4
         # beat 8/16/32 by 14-128% (smaller schedule buffer, better cache);
         # RE-MEASURED under the r14 factored default (ROADMAP PR-14
         # follow-on c, BENCH_pr15.json): per-group buffers narrowed the
         # gap but batch 4 still wins — 2.40M vs 2.37M (8), 1.49M (16),
         # 1.21M (32) n/s — so the default stands.
-        batch = 1024 if backend == "pallas" else 4
+        if backend == "pallas":
+            from .pallas_sha256 import DEFAULT_BATCH, DEFAULT_CPB
+
+            per_dev = -(-DEFAULT_BATCH // n_devices)
+            batch = -(-per_dev // DEFAULT_CPB) * DEFAULT_CPB
+        else:
+            batch = 4
     if max_k is None:
         max_k = 6 if backend == "pallas" else 5
     if sieve is None:
@@ -790,7 +809,7 @@ def run_sweep_dispatches(
             if n_devices > 1:
                 place = MeshRows(len(rows), n_devices)
                 slots = place.slots(batch // n_devices)
-                _count_mesh_dispatch(place)
+                _count_mesh_dispatch(place, batch // n_devices)
             tail_const, bounds = _fill_templates(layout, group, rows, batch, slots)
             out = run_kernel(kern, midstate, tail_const, bounds)
             pending.append((out, [c.base for c in rows], 10**group.k))
@@ -804,17 +823,20 @@ def run_sweep_dispatches(
     return lanes
 
 
-def _count_mesh_dispatch(place: MeshRows) -> None:
-    """One mesh dispatch's placement: its valid rows, and the slots the
-    mesh spends on them (every device works as long as the fullest one)."""
+def _count_mesh_dispatch(place: MeshRows, per_dev_batch: int) -> None:
+    """One mesh dispatch's placement: its valid rows, the slots the mesh
+    spends on them (every device works as long as the fullest one), and
+    the row slots the dispatch carries in all."""
     counts = place.counts()
+    slots = place.n_devices * per_dev_batch
     METRICS.inc("sweep.mesh_rows", place.n_rows)
     METRICS.inc("sweep.mesh_row_slots", place.n_devices * max(counts))
+    METRICS.inc("sweep.mesh_dispatch_slots", slots)
     METRICS.inc("sweep.mesh_dispatches")
     if _trace.enabled():
         _trace.emit(
             None, "miner", "mesh_dispatch",
-            rows=place.n_rows, per_device=list(counts),
+            rows=place.n_rows, per_device=list(counts), slots=slots,
         )
 
 
@@ -1328,12 +1350,13 @@ class SweepPipeline:
 
             if not is_tpu_device(mesh.devices.flat[0]):
                 backend = "xla"
+        self._n_devices = 1 if mesh is None else mesh.devices.size
         (
             self._backend, self._batch, self._max_k, self._sieve,
             self._factored, self._hot,
         ) = auto_tune(
             backend, batch, max_k, sieve, factored, hot,
-            family=self._family,
+            family=self._family, n_devices=self._n_devices,
         )
         if mesh is not None and self._backend == "pallas":
             # The sharded tier runs the PER-SHARD sieve (ISSUE 14
@@ -1352,13 +1375,13 @@ class SweepPipeline:
         # Mesh mode: the same cross-request pipeline drives the sharded
         # (shard_map + pmin cascade) kernels — a multi-chip miner must not
         # idle its whole mesh between the scheduler's chunks any more than
-        # a single chip may.  ``batch`` stays per-device; dispatch slots
-        # total n_devices * batch, sharded contiguously along axis_name,
-        # and each dispatch's rows spread evenly over them (MeshRows).
+        # a single chip may.  ``batch`` stays per-device (its default
+        # from auto_tune's n_devices); dispatch slots total n_devices *
+        # batch, sharded contiguously along axis_name, and each
+        # dispatch's rows spread evenly over them (MeshRows).
         self._mesh = mesh
         self._axis_name = axis_name
         self._per_dev_batch = self._batch
-        self._n_devices = 1 if mesh is None else mesh.devices.size
         # None = auto: this is the miner's production path, where a tiny
         # digit class must never cost a Mosaic compile (see HostFold).
         self._host_lane_budget = (

@@ -423,9 +423,10 @@ def sweep_min_hash_sharded(
     """Multi-chip ``(min Hash(data, n), argmin n)`` over inclusive
     ``[lower, upper]``; bit-exact vs the hashlib oracle, lowest-nonce ties.
 
-    A dispatch has ``n_devices * batch_per_device`` slots; its rows spread
-    evenly over the devices (``ops.sweep.MeshRows``) and the padding slots
-    have empty lane bounds, masked in-kernel.  Results
+    A dispatch has ``n_devices * batch_per_device`` slots (by default
+    ``auto_tune``'s 1024 in all on the pallas tier, 256 a device on four);
+    its rows spread evenly over the devices (``ops.sweep.MeshRows``) and
+    the padding slots have empty lane bounds, masked in-kernel.  Results
     are fetched lazily after all dispatches are queued so the device
     pipeline stays full.
 
@@ -459,7 +460,7 @@ def sweep_min_hash_sharded(
     sep, host_min, _native_ok, family = _workload_knobs(workload)
     backend, batch_per_device, max_k, sieve, factored, hot = auto_tune(
         backend, batch_per_device, max_k, sieve, factored, hot,
-        family=family,
+        family=family, n_devices=n_dev,
     )
     rolled = not mesh_on_tpu
     batch = n_dev * batch_per_device

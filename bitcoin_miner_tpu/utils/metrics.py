@@ -376,6 +376,7 @@ def format_quantiles(h) -> str:
 #:   sweep.kernel_build_s      seconds of a stored kernel's first call (gauge; the latest)
 #:   sweep.mesh_rows           valid chunk rows placed by mesh dispatches
 #:   sweep.mesh_row_slots      n_devices x the fullest device's rows, per mesh dispatch
+#:   sweep.mesh_dispatch_slots  n_devices x the per-device batch, per mesh dispatch
 #:   sweep.mesh_dispatches     mesh (sharded) dispatches enqueued
 #:   kernel.thresh_staleness   sieve-threshold lag in dispatches (gauge; 1 = device-resident)
 #:   client.resubmits          jobs resubmitted after a lost client conn

@@ -62,9 +62,12 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def _production_shape():
-    """The pallas tier's kernel as ``auto_tune`` resolves it, for d=10."""
-    backend, batch, max_k, sieve, factored, _hot = auto_tune("pallas", None, None)
+def _production_shape(n_devices=1):
+    """The pallas tier's kernel as ``auto_tune`` resolves it, for d=10, with
+    ``batch`` the rows of each of ``n_devices`` devices."""
+    backend, batch, max_k, sieve, factored, _hot = auto_tune(
+        "pallas", None, None, n_devices=n_devices
+    )
     assert (backend, factored) == ("pallas", False), "the dyn kernel is the default"
     group = next(decompose_range(10**9, 10**9 + 10**8, max_k=max_k))
     assert group.d == 10
@@ -140,9 +143,10 @@ def test_stored_dyn_kernel_export_compiles_for_v5e(topo):
 
 
 def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):
-    batch, sieve, group, layout, w_lo, w_hi = _production_shape()
     n_dev = len(topo.devices)
     assert n_dev == 4
+    batch, sieve, group, layout, w_lo, w_hi = _production_shape(n_dev)
+    assert n_dev * batch == 1024, "a mesh dispatch holds 1024 slots in all"
     mesh = Mesh(np.array(topo.devices).reshape(n_dev), ("miners",))
     kern, n_pad = _make_sharded_kernel_dyn(
         layout.n_tail_blocks, w_lo, w_hi, group.k, batch, mesh, "miners",

@@ -336,7 +336,10 @@ def test_mesh_even_placement_matches_oracle(
         return place(midstate, tail_const, bounds, *a, **kw)
 
     monkeypatch.setattr(psweep, "shard_operands", spy)
-    names = ("sweep.mesh_rows", "sweep.mesh_row_slots", "sweep.mesh_dispatches")
+    names = (
+        "sweep.mesh_rows", "sweep.mesh_row_slots", "sweep.mesh_dispatches",
+        "sweep.mesh_dispatch_slots",
+    )
     before = [METRICS.get(n) for n in names]
     with trace.tracing() as tr:
         r = _mesh_sweep(form, backend, data, lo, hi, max_k, hot=hot)
@@ -354,13 +357,88 @@ def test_mesh_even_placement_matches_oracle(
         assert max(per_dev) - min(per_dev) <= 1
         assert all(valid[d, :c].all() for d, c in enumerate(per_dev))
     # The counters and the trace event say the same.
-    got_rows, slots, dispatches = (METRICS.get(n) - b for n, b in zip(names, before))
+    got_rows, slots, dispatches, carried = (
+        METRICS.get(n) - b for n, b in zip(names, before)
+    )
     assert got_rows == sum(want)
     assert dispatches == len(want)
     assert slots == sum(N_DEV * -(-rows // N_DEV) for rows in want)
+    # Every dispatch carries all N_DEV x PER_DEV slots, however few it fills.
+    assert carried == len(want) * N_DEV * PER_DEV
     assert [e["attrs"]["rows"] for e in events] == want
     for e, rows in zip(events, want):
         assert e["attrs"]["per_device"] == list(MeshRows(rows, N_DEV).counts())
+        assert e["attrs"]["slots"] == N_DEV * PER_DEV
+
+
+# -- The per-device batch a mesh dispatch defaults to ----------------------
+#
+# The pallas tier's default holds DEFAULT_BATCH (1024) slots per dispatch in
+# all, so a scheduler chunk of ~1000 rows fills a mesh dispatch as it fills
+# one chip's: each device gets ceil(1024 / n) rounded up to DEFAULT_CPB (8).
+
+
+@pytest.mark.parametrize("backend,batch,family,n_devices,want", [
+    ("pallas", None, "sha256", 1, 1024),
+    ("pallas", None, "sha256", 2, 512),
+    ("pallas", None, "sha256", 3, 344),
+    ("pallas", None, "sha256", 4, 256),
+    ("pallas", None, "sha256", 8, 128),
+    ("xla", None, "sha256", 1, 4),  # the xla tier's default is per device
+    ("xla", None, "sha256", 4, 4),
+    (None, None, "blake2b", 1, 8),  # so is the blake2b family's
+    (None, None, "blake2b", 4, 8),
+    ("pallas", 5, "sha256", 4, 5),  # an explicit batch is per device
+    ("xla", 2, "sha256", 8, 2),
+])
+def test_auto_tune_per_device_batch(backend, batch, family, n_devices, want):
+    from bitcoin_miner_tpu.ops.pallas_sha256 import DEFAULT_BATCH, DEFAULT_CPB
+    from bitcoin_miner_tpu.ops.sweep import auto_tune
+
+    got = auto_tune(backend, batch, None, family=family, n_devices=n_devices)[1]
+    assert got == want
+    if backend == "pallas" and batch is None:
+        # A multiple of the rows per grid program, and the least one whose
+        # n_devices blocks hold DEFAULT_BATCH slots.
+        assert got % DEFAULT_CPB == 0
+        assert n_devices * got >= DEFAULT_BATCH
+        assert n_devices * (got - DEFAULT_CPB) < DEFAULT_BATCH
+
+
+def test_mesh_pipeline_pallas_default_dispatch_holds_1024_slots(monkeypatch):
+    # A four-device pallas pipeline with no batch given builds its sharded
+    # kernel for 256 rows a device and ships 1024-row operands, however few
+    # rows a dispatch has.  Three rows of 10 nonces (d=3, k=1) keep the
+    # interpret-mode kernel cheap.
+    import numpy as np
+
+    from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+    from bitcoin_miner_tpu.parallel import sweep as psweep
+
+    shipped, built = [], []
+    place, build = psweep.shard_operands, psweep.sharded_kernel_for
+
+    def spy_place(midstate, tail_const, bounds, *a, **kw):
+        shipped.append(np.array(bounds).shape)
+        return place(midstate, tail_const, bounds, *a, **kw)
+
+    def spy_build(layout, group, per_dev_batch, *a, **kw):
+        built.append(per_dev_batch)
+        return build(layout, group, per_dev_batch, *a, **kw)
+
+    monkeypatch.setattr(psweep, "shard_operands", spy_place)
+    monkeypatch.setattr(psweep, "sharded_kernel_for", spy_build)
+    p = SweepPipeline(
+        backend="pallas", interpret=True, mesh=default_mesh(4), max_k=1,
+        host_lane_budget=0,
+    )
+    try:
+        r = p.submit("x", 100, 129).result(timeout=200)
+    finally:
+        p.close()
+    assert (r.hash, r.nonce) == min_hash_range("x", 100, 129)
+    assert built and set(built) == {256}
+    assert shipped == [(1024, 2)]
 
 
 def test_mesh_rows_slot_map_is_nonce_ordered():
