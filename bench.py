@@ -92,6 +92,7 @@ def run_sharded(args, watchdog) -> int:
 
     from bitcoin_miner_tpu.bitcoin.hash import min_hash_range
     from bitcoin_miner_tpu.parallel import default_mesh, sweep_min_hash_sharded
+    from bitcoin_miner_tpu.utils.metrics import METRICS
     from bitcoin_miner_tpu.utils.platform import (
         enable_compile_cache,
         pallas_platform,
@@ -107,10 +108,8 @@ def run_sharded(args, watchdog) -> int:
     mesh = default_mesh(n)
     log(f"sharded bench: mesh of {n} x {platform}")
 
-    def run(lo, hi, stats=None):
-        return sweep_min_hash_sharded(
-            "cmu440", lo, hi, mesh=mesh, stats=stats
-        )
+    def run(lo, hi):
+        return sweep_min_hash_sharded("cmu440", lo, hi, mesh=mesh)
 
     # Correctness gate (digit-boundary-crossing, same as single-chip).
     watchdog.beat("sharded correctness gate (first compile)")
@@ -124,30 +123,30 @@ def run_sharded(args, watchdog) -> int:
     base = 10**9
     run(base, base + 10**5 - 1)  # compile the timed shape class
 
-    def timed(count, stats=None):
+    def timed(count):
+        """Seconds to sweep ``count`` nonces, and the mesh dispatches
+        the sweep made."""
         watchdog.beat(f"sharded sweep of {count} nonces")
+        d0 = METRICS.get("sweep.mesh_dispatches")
         t0 = time.perf_counter()
-        r = run(base, base + count - 1, stats)
+        r = run(base, base + count - 1)
         dt = time.perf_counter() - t0
         assert r.lanes_swept == count
         watchdog.beat()
-        return dt
+        return dt, METRICS.get("sweep.mesh_dispatches") - d0
 
-    # stats resets on every sweep entry, so the last iteration's numbers
-    # are the ones reported — no extra stats-only sweep needed.
-    stats: dict = {}
+    # The last iteration's numbers are the ones reported.
     count = 10**6 if platform == "cpu" else 10**8
-    dt = timed(count, stats)
+    dt, dispatches = timed(count)
     while dt < 4.0 and count < 4 * 10**9:
         count = min(count * max(2, int(4.0 / max(dt, 1e-3))), 4 * 10**9)
-        dt = timed(count, stats)
+        dt, dispatches = timed(count)
     watchdog.disarm()
     rate = count / dt
     log(
         f"swept {count} nonces on {n} devices in {dt:.3f}s -> "
         f"{rate:,.0f} nonces/s total, {rate / n:,.0f}/device; "
-        f"{stats['dispatches']} dispatches, "
-        f"fetch wait {stats['fetch_wait_seconds']:.3f}s"
+        f"{dispatches} dispatches"
     )
     emit(
         {
@@ -158,8 +157,7 @@ def run_sharded(args, watchdog) -> int:
             "platform": platform,
             "devices": n,
             "per_device": round(rate / n),
-            "dispatches": stats["dispatches"],
-            "fetch_wait_seconds": round(stats["fetch_wait_seconds"], 3),
+            "dispatches": dispatches,
             "backend": "pallas" if platform == "tpu" else "xla",
             "pallas_platform": pallas_platform(),
         }
@@ -279,7 +277,7 @@ def run_sieve_compare(args, watchdog) -> int:
     watchdog.disarm()
     r_base = n / dt_base
     r_sieve = n / dt_sieve
-    _, _, _, tuned_sieve, _, _ = auto_tune(backend, None, None)
+    _, _, _, tuned_sieve, _ = auto_tune(backend, None, None)
     log(
         f"swept {n} nonces twice: baseline {r_base:,.0f} n/s, sieve "
         f"{r_sieve:,.0f} n/s (ratio {r_sieve / r_base:.3f}); auto_tune "
@@ -426,7 +424,7 @@ def run_factor_compare(args, watchdog) -> int:
     watchdog.disarm()
     r_base = n / dt_base
     r_fact = n / dt_fact
-    _, _, _, _, tuned_factored, _ = auto_tune(backend, None, None)
+    _, _, _, _, tuned_factored = auto_tune(backend, None, None)
     log(
         f"swept {n} nonces twice: baseline {r_base:,.0f} n/s, factored "
         f"{r_fact:,.0f} n/s (ratio {r_fact / r_base:.3f}); auto_tune "
@@ -619,12 +617,10 @@ def run_tier_compare(args, watchdog) -> int:
     watchdog.disarm()
     (r_dev, r_cpu), (rs_dev, rs_cpu) = rates["long"], rates["short"]
     tuned = auto_tune(backend, None, None, family=wl.kernel_family)
-    t_backend, t_batch, _t_max_k, t_sieve, t_factored, t_hot = tuned
+    t_backend, t_batch, _t_max_k, t_sieve, t_factored = tuned
     kept = "factored" if t_factored else "baseline"
     if t_sieve:
         kept += "+sieve"
-    if t_hot:
-        kept += "+hot"
     log(
         f"workload={wl.name} data_len={len(data_long)}: {backend} "
         f"{r_dev:,.0f} n/s vs cpu {r_cpu:,.0f} n/s (ratio "
@@ -651,7 +647,6 @@ def run_tier_compare(args, watchdog) -> int:
             "auto_tune_batch": t_batch,
             "auto_tune_sieve": bool(t_sieve),
             "auto_tune_factored": bool(t_factored),
-            "auto_tune_hot": bool(t_hot),
             "kept_kernel": kept,
             "platform": platform,
             "pallas_platform": pallas_platform(),
@@ -660,151 +655,6 @@ def run_tier_compare(args, watchdog) -> int:
             "fast": bool(args.fast),
         }
     )
-    return 0
-
-
-def run_hot_compare(args, watchdog) -> int:
-    """--hot-compare: same-seed persistent-vs-per-chunk dispatch legs
-    (ISSUE 16).
-
-    Runs the SAME data + nonce range through the per-chunk dispatch path
-    and the always-hot plane (donated running-min carry + device
-    descriptor ring) of the resolved jax tier — both legs at the
-    backend's default sieve/factored rungs, so the pair isolates the
-    dispatch discipline — and emits one JSON line with both rates (the
-    BENCH_pr16 artifact).  Both legs are bit-exactness-gated against the
-    hashlib oracle first on a digit-boundary-crossing range; ``--fast``
-    swaps the timed windows for tiny tier-1-sized ones and adds
-    interpret-mode pallas hot gates (plain AND composed with the sieve's
-    device-carried threshold), so the correctness half runs on every PR.
-
-    Honesty contract: ``auto_tune_hot`` records which dispatch
-    discipline :func:`bitcoin_miner_tpu.ops.sweep.auto_tune` actually
-    picks for this backend — if the hot leg loses here, the default
-    demonstrably keeps the per-chunk path and both numbers still land.
-    """
-    import jax
-
-    from bitcoin_miner_tpu.bitcoin.hash import min_hash_range
-    from bitcoin_miner_tpu.ops.sweep import auto_tune, sweep_min_hash
-    from bitcoin_miner_tpu.utils.platform import (
-        enable_compile_cache,
-        is_tpu,
-        pallas_platform,
-    )
-
-    enable_compile_cache()
-    for flag, val in (("--autotune", args.autotune), ("--profile", args.profile)):
-        if val:
-            log(f"WARNING: {flag} is ignored in --hot-compare mode")
-    watchdog.beat("device init (jax.devices)")
-    dev = jax.devices()[0]
-    platform = dev.platform
-    if args.backend in ("pallas", "xla"):
-        backend = args.backend
-    elif args.backend == "native":
-        emit({"error": "--hot-compare applies to the jax tiers only"})
-        return 1
-    else:
-        backend = "pallas" if is_tpu() else "xla"
-    data = "cmu440"  # the flagship BASELINE shape
-
-    # -- correctness gates: both disciplines, digit-boundary range -----------
-    lo, hi = 95, 1205
-    expect = min_hash_range(data, lo, hi)
-    watchdog.beat("hot-compare correctness gates (first compiles)")
-    for hot in (False, True):
-        r = sweep_min_hash(data, lo, hi, backend=backend, max_k=2, hot=hot)
-        if (r.hash, r.nonce) != expect:
-            emit(
-                {
-                    "error": "hot-compare correctness gate failed",
-                    "hot": hot,
-                    "kernel": [r.hash, r.nonce],
-                    "oracle": list(expect),
-                    "backend": backend,
-                }
-            )
-            return 1
-    interp_ok = None
-    if args.fast:
-        # Tier-1 also covers the REAL prize path in interpreter mode: the
-        # pallas hot plane (donated carry threaded through the flipped
-        # scalar-prefetch threshold) bit-exact across a digit boundary —
-        # plain and composed with the PR-13 sieve, whose threshold is now
-        # the device-carried running min.
-        watchdog.beat("interpret-mode pallas hot gates")
-        expect_i = min_hash_range(data, 985, 1040)
-        interp_ok = True
-        for sieve in (False, True):
-            ri = sweep_min_hash(
-                data, 985, 1040, backend="pallas", interpret=True,
-                batch=2, max_k=2, hot=True, sieve=sieve,
-            )
-            interp_ok = interp_ok and (ri.hash, ri.nonce) == expect_i
-        if not interp_ok:
-            emit({"error": "interpret-mode pallas hot gate failed"})
-            return 1
-    log("correctness OK: per-chunk and hot dispatch match the oracle")
-
-    # -- same-seed timed legs ------------------------------------------------
-    base = 10**9
-
-    def timed(n: int, hot: bool) -> float:
-        watchdog.beat(
-            f"timed {'hot' if hot else 'per-chunk'} sweep of {n}"
-        )
-        t0 = time.perf_counter()
-        r = sweep_min_hash(data, base, base + n - 1, backend=backend, hot=hot)
-        dt = time.perf_counter() - t0
-        assert r.lanes_swept == n
-        watchdog.beat()
-        return dt
-
-    warm = 10**5 if args.fast else 10**6
-    timed(warm, False)  # compile both dispatch disciplines
-    timed(warm, True)
-    if args.fast:
-        n = 2 * 10**5
-    else:
-        n = 4 * 10**6
-        dt = timed(n, False)
-        while dt < 4.0 and n < 16 * 10**9:
-            n = min(n * max(2, int(4.0 / max(dt, 1e-3))), 16 * 10**9)
-            dt = timed(n, False)
-    # Interleaved best-of-2 per leg: same-seed PAIR, not single numbers
-    # (this box's wall clock swings run-to-run — ROADMAP).
-    dt_chunk = min(timed(n, False), timed(n, False))
-    dt_hot = min(timed(n, True), timed(n, True))
-    watchdog.disarm()
-    r_chunk = n / dt_chunk
-    r_hot = n / dt_hot
-    _, _, _, _, _, tuned_hot = auto_tune(backend, None, None)
-    log(
-        f"swept {n} nonces twice: per-chunk {r_chunk:,.0f} n/s, hot "
-        f"{r_hot:,.0f} n/s (ratio {r_hot / r_chunk:.3f}); auto_tune "
-        f"keeps the {'hot' if tuned_hot else 'per-chunk'} dispatch "
-        f"for backend={backend}"
-    )
-    out = {
-        "metric": "hot_compare",
-        "unit": "nonces/s",
-        "data": data,
-        "count": n,
-        "perchunk_nps": round(r_chunk),
-        "hot_nps": round(r_hot),
-        "ratio": round(r_hot / r_chunk, 4),
-        "auto_tune_hot": bool(tuned_hot),
-        "kept_kernel": "hot" if tuned_hot else "per-chunk",
-        "platform": platform,
-        "pallas_platform": pallas_platform(),
-        "backend": backend,
-        "bitexact": True,
-        "fast": bool(args.fast),
-    }
-    if interp_ok is not None:
-        out["interpret_pallas_hot_bitexact"] = bool(interp_ok)
-    emit(out)
     return 0
 
 
@@ -850,13 +700,6 @@ def main() -> int:
         "jax tier (ISSUE 14); emits the BENCH_pr14 factor_compare JSON line",
     )
     ap.add_argument(
-        "--hot-compare",
-        action="store_true",
-        help="same-seed persistent-vs-per-chunk dispatch legs on the "
-        "resolved jax tier (ISSUE 16); emits the BENCH_pr16 hot_compare "
-        "JSON line",
-    )
-    ap.add_argument(
         "--tier-compare",
         action="store_true",
         help="same-seed device-tier-vs-cpu-tier legs for --workload "
@@ -872,8 +715,8 @@ def main() -> int:
     ap.add_argument(
         "--fast",
         action="store_true",
-        help="with --sieve-compare / --factor-compare / --hot-compare / "
-        "--tier-compare: tiny tier-1-sized timed windows plus "
+        help="with --sieve-compare / --factor-compare / --tier-compare: "
+        "tiny tier-1-sized timed windows plus "
         "interpret-mode pallas correctness legs",
     )
     ap.add_argument(
@@ -929,7 +772,6 @@ def main() -> int:
             ("--profile", args.profile),
             ("--sieve-compare", args.sieve_compare),
             ("--factor-compare", args.factor_compare),
-            ("--hot-compare", args.hot_compare),
             ("--tier-compare", args.tier_compare),
             ("--fast", args.fast),
         ):
@@ -941,18 +783,11 @@ def main() -> int:
 
     enable_compile_cache()
 
-    if sum(
-        (
-            args.sieve_compare,
-            args.factor_compare,
-            args.hot_compare,
-            args.tier_compare,
-        )
-    ) > 1:
+    if sum((args.sieve_compare, args.factor_compare, args.tier_compare)) > 1:
         emit(
             {
-                "error": "--sieve-compare, --factor-compare, --hot-compare "
-                "and --tier-compare are exclusive"
+                "error": "--sieve-compare, --factor-compare and "
+                "--tier-compare are exclusive"
             }
         )
         return 1
@@ -963,14 +798,12 @@ def main() -> int:
         return run_sieve_compare(args, watchdog)
     if args.factor_compare:
         return run_factor_compare(args, watchdog)
-    if args.hot_compare:
-        return run_hot_compare(args, watchdog)
     if args.tier_compare:
         return run_tier_compare(args, watchdog)
     if args.fast:
         log(
             "WARNING: --fast only applies to --sieve-compare/"
-            "--factor-compare/--hot-compare/--tier-compare; ignored"
+            "--factor-compare/--tier-compare; ignored"
         )
 
     from bitcoin_miner_tpu import native

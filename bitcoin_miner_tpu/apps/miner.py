@@ -184,7 +184,6 @@ class _PipelineSearch:
         backend: Optional[str],
         devices: Optional[int] = None,
         workload=None,
-        hot: Optional[bool] = None,
     ) -> None:
         from concurrent.futures import Future
 
@@ -196,9 +195,7 @@ class _PipelineSearch:
 
             mesh = default_mesh(devices)
         self._Future = Future
-        self._p = SweepPipeline(
-            backend=backend, mesh=mesh, workload=workload, hot=hot
-        )
+        self._p = SweepPipeline(backend=backend, mesh=mesh, workload=workload)
         self.tier = self._p.backend
         self.devices = self._p.devices
 
@@ -229,16 +226,13 @@ class _PipelineSearch:
 
 def make_async_search(
     backend: str = "auto", devices: Optional[int] = None, workload=None,
-    hot: Optional[bool] = None,
 ):
     """Build the async (submit -> Future of (hash, nonce)) search the miner
     serves Requests with.  JAX tiers get the cross-request SweepPipeline —
     single-device or mesh-sharded (a multi-chip miner must not idle its
     whole mesh between chunks); only the cpu tier runs behind a
     single-worker pool (FIFO, compute-bound anyway).  ``workload``: see
-    :func:`make_search`.  ``hot`` (ISSUE 16): the pipeline's always-hot
-    device plane; None = the ``auto_tune`` rung, False forces the
-    per-chunk fallback (the watchdog ladder's same-backend rung)."""
+    :func:`make_search`."""
     if workload is not None and not _is_default(workload):
         tier = _resolve_tier(backend, workload, devices)
         return workload.make_async_search(tier, devices)
@@ -260,7 +254,7 @@ def make_async_search(
     from ..utils.platform import enable_compile_cache
 
     enable_compile_cache()
-    return _PipelineSearch(backend, devices=devices, hot=hot)
+    return _PipelineSearch(backend, devices=devices)
 
 
 def run_miner(
@@ -679,31 +673,11 @@ def make_tiered_search(
         from ..utils.platform import is_tpu
 
         backend = "pallas" if is_tpu() else "cpu"
-    from ..ops.sweep import auto_tune as _auto_tune
-
-    def _hot_rung(b: str) -> bool:
-        # ISSUE 16: when auto_tune turns the always-hot plane ON for a
-        # backend, the ladder grows a same-backend PER-CHUNK rung before
-        # the backend downgrade — a wedged persistent dispatch loop
-        # shouldn't cost the whole device tier when the per-chunk form
-        # of the same kernel is still healthy.
-        return _auto_tune(b, None, None)[5]
-
     chain = []
     if backend == "pallas":
         chain.append(("pallas", lambda: make_async_search("pallas", devices)))
-        if _hot_rung("pallas"):
-            chain.append((
-                "pallas-perchunk",
-                lambda: make_async_search("pallas", devices, hot=False),
-            ))
     if backend in ("pallas", "xla"):
         chain.append(("xla", lambda: make_async_search("xla", devices)))
-        if _hot_rung("xla"):
-            chain.append((
-                "xla-perchunk",
-                lambda: make_async_search("xla", devices, hot=False),
-            ))
     chain.append(("cpu", lambda: _PoolSearch(make_search("cpu"))))
     chain.append(("hashlib", lambda: _PoolSearch(_oracle, "hashlib")))
     return _TieredSearch(chain, wedge_seconds=wedge_seconds)

@@ -2,7 +2,7 @@
 
 The registry's BLAKE2b workload (workloads/blake2b.py) was the only
 workload with no device tier: every nonce ran on the host interpreter
-while the SHA-256 stack enjoyed factored/sieve/hot XLA+Pallas kernels.
+while the SHA-256 stack enjoyed factored/sieve XLA+Pallas kernels.
 This module closes that gap with a jnp kernel computing BLAKE2b with an
 8-byte digest over ``"<data> <nonce>"`` message lanes — the same
 message-template decomposition as :mod:`ops.sha256` (constant prefix
@@ -54,8 +54,8 @@ The kernel keeps the exact operand/result contract of the SHA-256 xla
 tier — ``(midstate, tail_const (B, nw), bounds (B, 2)[, thresh]) ->
 (min_h0, min_h1, flat_idx)`` with the lexicographic big-endian
 ``(h0, h1)`` min-fold and lowest-nonce ties — so ``ops.sweep``'s
-drivers, the hot plane's donated steps, and ``parallel/sweep.py``'s
-collective cascade all serve the family unchanged; only the layout
+pipeline and ``parallel/sweep.py``'s collective cascade both serve the
+family unchanged; only the layout
 builder and kernel factory differ (dispatched on ``layout.family``).
 """
 
@@ -389,8 +389,7 @@ def make_blake2b_kernel_body(
     bounds (B, 2)[, thresh]) -> (min_h0, min_h1, flat_idx)`` — the same
     contract as the SHA-256 xla kernels (big-endian lexicographic min,
     lowest flat-lane ties, I32_MAX when every lane is masked), so the
-    per-chunk drivers, the hot plane's donated steps, and the sharded
-    collective cascade work unchanged.
+    sweep pipeline and the sharded collective cascade work unchanged.
 
     ``factored = k_in > 0`` runs the grouped form: an outer ``fori_loop``
     over ``10^(k - k_in)`` digit groups (template patched per group from
@@ -405,8 +404,7 @@ def make_blake2b_kernel_body(
     across groups with the carried best (the sequential-dimension
     tightening of the factored SHA-256 sieve).  BLAKE2b's h0 and h1 fall
     out of one compression output word, so there is no cheaper h0-only
-    pass to stage — the operand exists for the hot plane's carried
-    threshold, not as a two-pass win.
+    pass to stage and no two-pass win; ``auto_tune`` leaves it off.
     """
     n_lanes = 10**k
     live = frozenset(live_words)

@@ -556,12 +556,11 @@ def auto_tune(
     max_k: Optional[int],
     sieve: Optional[bool] = None,
     factored: Optional[bool] = None,
-    hot: Optional[bool] = None,
     family: str = "sha256",
     n_devices: int = 1,
-) -> Tuple[str, int, int, bool, bool, bool]:
-    """Resolve the (backend, rows-per-device, max_k, sieve, factored,
-    hot) defaults shared by the single-device and sharded sweep drivers.
+) -> Tuple[str, int, int, bool, bool]:
+    """Resolve the (backend, rows-per-device, max_k, sieve, factored)
+    defaults of :class:`SweepPipeline`, on one device or a mesh.
     max_k=5 bounds the xla tier's compress_rolled schedule buffer
     ((16, B, 10^k) u32) to ~50 MB at B=8.
 
@@ -588,10 +587,7 @@ def auto_tune(
     ON (the grouped form IS the kernel's production shape — the
     full-lane form exists for tiny classes and tests); ``sieve``
     defaults OFF (h0 and h1 fall out of one compression word, so there
-    is no cheaper pass 1 — the threshold operand exists for the hot
-    plane's carried bound, not as a two-stage win); ``hot`` defaults
-    OFF like the sha256 xla tier (same per-dispatch-cost argument,
-    BENCH_pr16.json).
+    is no cheaper pass 1 and no two-stage win).
 
     The **sieve rung** (ISSUE 13, ``sieve=None`` = auto): the two-stage
     sieve kernel is ON for the pallas tier — pass 1's predicate epilogue
@@ -626,25 +622,7 @@ def auto_tune(
     programs ~4× (1024-lane inner tiles vs 4096), neither of which this
     host can price; ``bench.py --factor-compare`` on real TPU is the
     arbiter (ROADMAP follow-on), and a shape where factoring loses keeps
-    the current kernel by default.
-
-    The **hot rung** (ISSUE 16, ``hot=None`` = auto): the always-hot
-    device plane (donated carried best/threshold buffers + the async
-    chunk-descriptor ring, :class:`_HotLoop`) wraps whichever kernel
-    variant the other rungs resolved.  OFF by default on BOTH tiers on
-    this host: the same-seed pair (``bench.py --hot-compare``,
-    BENCH_pr16.json) measured the donated/ring path at parity with the
-    per-chunk path on XLA:CPU (ratio 1.02: hot 2.31M vs per-chunk 2.26M
-    n/s, inside this host's run-to-run swing and under the 1.15×
-    promotion bar) — per-dispatch cost here is kernel compute
-    (~0.16 s at batch 4), so eliding the output allocation and the
-    host-side fold is below noise — and the rung's real target, the TPU's
-    per-dispatch dispatch+fetch latency and the per-dispatch host sync the
-    per-chunk fold forces, cannot be priced off-TPU
-    (real-TPU arbitration is the ROADMAP follow-on, same pattern as the
-    factored pallas rung).  A shape where the hot plane does not
-    demonstrably win keeps the per-chunk kernel by default; the plane
-    stays available behind ``hot=True`` and is bit-exact either way."""
+    the current kernel by default."""
     if family == "blake2b":
         if backend is None:
             backend = "xla"
@@ -661,9 +639,7 @@ def auto_tune(
             sieve = False
         if factored is None:
             factored = True
-        if hot is None:
-            hot = False
-        return backend, batch, max_k, sieve, factored, hot
+        return backend, batch, max_k, sieve, factored
     if backend is None:
         backend = _default_backend()
     if batch is None:
@@ -692,9 +668,7 @@ def auto_tune(
         sieve = backend == "pallas"
     if factored is None:
         factored = backend == "xla"
-    if hot is None:
-        hot = False
-    return backend, batch, max_k, sieve, factored, hot
+    return backend, batch, max_k, sieve, factored
 
 
 @dataclass(frozen=True)
@@ -758,11 +732,11 @@ def run_sweep_dispatches(
     family: str = "sha256",
     n_devices: int = 1,
 ) -> int:
-    """The decompose → template-fill → dispatch skeleton shared by the
-    single-device (below) and sharded (parallel/sweep.py) drivers.
-    ``n_devices > 1`` is a mesh dispatch: ``batch`` is then ``n_devices``
-    blocks of slots, and each dispatch's rows spread over them
-    (:class:`MeshRows`).
+    """The decompose → template-fill → dispatch skeleton that
+    :class:`SweepPipeline`'s dispatcher runs for each job, on one device
+    or a mesh.  ``n_devices > 1`` is a mesh dispatch: ``batch`` is then
+    ``n_devices`` blocks of slots, and each dispatch's rows spread over
+    them (:class:`MeshRows`).
 
     ``sep``/``host_min``/``family`` are the workload knobs
     (``_workload_knobs``): the message-template separator baked into
@@ -865,8 +839,8 @@ def _build_kernel(
     backend, batch, tile, cpb, interpret, rolled, layout, group, sieve=False,
     factored=False,
 ):
-    """One place for the backend-specific kernel construction (shared by
-    the synchronous driver and SweepPipeline; the underlying factories are
+    """One place for the backend-specific kernel construction of
+    SweepPipeline's single-device mode (the underlying factories are
     lru_cached).  ``sieve`` picks the two-stage variant of whichever
     backend kernel applies (ISSUE 13); ``factored`` the outer/inner
     digit-factored variant (ISSUE 14, classes with ``k >= 2`` — a 1-digit
@@ -1006,279 +980,6 @@ def _invoke_kernel(backend, kern, midstate, tail_const, bounds, thresh=None):
     )
 
 
-# --------------------------------------------------------------------------
-# Always-hot device plane (ISSUE 16)
-# --------------------------------------------------------------------------
-
-
-def _flip_thresh_traced(th):
-    """A TRACED uint32 threshold -> the pallas sieve kernel's pre-sign-
-    flipped ``(1,)`` int32 operand (its comparisons live in that domain).
-    The per-chunk path does this flip on the host (:func:`_invoke_kernel`);
-    the hot step must do it on device because the threshold is the carried
-    ``best_h0`` and never visits the host."""
-    return jax.lax.bitcast_convert_type(
-        th ^ jnp.uint32(0x80000000), jnp.int32
-    ).reshape(1)
-
-
-def make_hot_step(backend, kern, sieve, mesh=False):
-    """Build the donated-buffer dispatch step wrapping one sweep kernel.
-
-    Carried-state contract (the hot plane's analogue of ops/sha256.py's
-    midstate contract):
-
-    - The carry is ``(best_h0, best_h1, best_seq, [best_dev,] best_flat)``
-      — u32/u32/i32/[i32/]i32 scalars.  ``best_flat == I32_MAX`` marks a
-      vacant carry; ``best_seq`` is the dispatch sequence number whose
-      ``(bases, 10^k)`` descriptor resolves the winning flat lane to a
-      nonce on the host (``best_dev`` additionally scales the row in mesh
-      mode, exactly like the per-chunk sharded fold).
-    - The carry is **donated** (``donate_argnums=(0,)``): XLA aliases the
-      input buffers into the output, so a steady-state dispatch allocates
-      no fresh device memory for the accumulator and the caller's old
-      carry handle is dead the moment the step is enqueued.
-    - ``carry[0]`` IS the sieve threshold.  It always equals the min h0
-      seen over dispatches ``< seq``, and the kernels' pass-1 predicate is
-      ``h0 <= thresh``, so an exact tie still survives to pass 2 — the
-      same conservative contract as the operand-shipped threshold, but
-      with zero staleness: dispatch N+1 reads the min through dispatch N
-      regardless of how deep the pipeline runs.
-    - Ties across dispatches keep the CARRIED candidate.  Dispatches are
-      enqueued in ascending nonce order (:func:`decompose_range`), so the
-      carried winner of an exact ``(h0, h1)`` tie is the lower nonce, and
-      within a dispatch the kernel already resolves ties to the lowest
-      flat lane.
-    - Each step also returns a tiny PROBE copy ``[best_h0, best_seq]``
-      (a fresh ``(2,)`` buffer, never aliased to the donated carry): the
-      host blocks on probes — not the carry — for backpressure, the
-      per-dispatch latency histogram, and pruning the seq->descriptor
-      map; the carry itself is only fetched once, at job end.  This is a
-      hard rule, not a style choice: materialising a carry element
-      host-side pins its buffer (jax caches the host view), and the next
-      step's donation silently falls back to a fresh-buffer copy.
-    """
-    sentinel = jnp.int32(I32_MAX)
-
-    def _merge(carry, seq, h0, h1, extra):
-        # extra = (flat,) single-device, (dev, flat) mesh.
-        bh0, bh1, bseq = carry[0], carry[1], carry[2]
-        bflat = carry[-1]
-        flat = extra[-1]
-        valid = flat != sentinel
-        vacant = bflat == sentinel
-        # Strict compare + vacant clause: an exact (h0, h1) tie keeps the
-        # carried (earlier-dispatch -> lower-nonce) candidate; the vacant
-        # clause admits a first candidate even at h0 == U32_MAX.
-        better = valid & (vacant | (h0 < bh0) | ((h0 == bh0) & (h1 < bh1)))
-        new_vals = (h0, h1, seq) + extra
-        new = tuple(
-            jnp.where(better, n, b) for n, b in zip(new_vals, carry)
-        )
-        probe = jnp.stack([new[0], new[2].astype(jnp.uint32)])
-        return new, probe
-
-    if backend == "pallas" and not mesh:
-        def step(carry, seq, midstate, tailcb):
-            th = (_flip_thresh_traced(carry[0]),) if sieve else ()
-            h0, h1, flat = kern(midstate, tailcb, *th)
-            return _merge(carry, seq, h0, h1, (flat,))
-    elif mesh:
-        def step(carry, seq, midstate, tail_const, bounds):
-            th = (carry[0],) if sieve else ()
-            h0, h1, dev, flat = kern(midstate, tail_const, bounds, *th)
-            return _merge(carry, seq, h0, h1, (dev, flat))
-    else:
-        def step(carry, seq, midstate, tail_const, bounds):
-            th = (carry[0],) if sieve else ()
-            h0, h1, flat = kern(midstate, tail_const, bounds, *th)
-            return _merge(carry, seq, h0, h1, (flat,))
-
-    return jax.jit(step, donate_argnums=(0,))
-
-
-#: Hot steps are cached per wrapped kernel OBJECT (not per class_key: the
-#: dyn pallas wrapper closes over per-class contribution tiles, so two
-#: classes sharing one executable still need distinct steps).  Kernel
-#: objects are themselves lru_cached, so this stays bounded by the same
-#: cache budget.
-_HOT_STEPS: dict = {}
-
-
-def _hot_step_for(backend, kern, sieve, mesh):
-    key = (kern, backend, bool(sieve), mesh is not None)
-    step = _HOT_STEPS.get(key)
-    if step is None:
-        step = _HOT_STEPS[key] = make_hot_step(
-            backend, kern, sieve, mesh=mesh is not None
-        )
-    return step
-
-
-@dataclass(frozen=True)
-class _HotToken:
-    """One hot dispatch's handle through a driver's ``consume``: the
-    sequence number, the probe array to block on, and the enqueue stamp."""
-
-    seq: int
-    probe: object
-    t_enq: float
-
-
-class _HotLoop:
-    """Job-lifetime always-hot dispatch plane (ISSUE 16).
-
-    One instance per job.  The host refills a small descriptor ring —
-    asynchronous device transfers of each dispatch's ``(midstate row,
-    tail templates, bounds)`` — ahead of the device consuming them, and
-    every dispatch is one donated step (:func:`make_hot_step`) carrying
-    the ``(best, threshold)`` state in place on device.  The per-chunk
-    drivers' backpressure (``max_inflight`` / the fetch queue) bounds the
-    live ring window; :data:`_RING_DEPTH` bounds the refill lookahead the
-    host keeps strong references to.
-
-    Zero-staleness sieving falls out of the carry: ``carry[0]`` is the
-    running-min h0 through the previous dispatch, so the threshold a
-    dispatch sieves against lags by exactly one dispatch (the per-chunk
-    operand-shipped threshold lags by the whole in-flight window) —
-    ``kernel.thresh_staleness`` records the contrast.
-    """
-
-    _RING_DEPTH = 8
-
-    def __init__(self, backend, sieve, *, mesh=None, axis_name="miners"):
-        self._backend = backend
-        self._sieve = sieve
-        self._mesh = mesh
-        self._axis_name = axis_name
-        self._carry = None
-        self._seq = 0
-        self._drained = 0
-        #: seq -> (bases, 10^k): resolves the carried winner's flat lane
-        #: to a nonce at job end; pruned by probe drains to O(in-flight).
-        self._bases: dict = {}
-        #: The refill lookahead: strong refs to the last few descriptor
-        #: slots shipped to the device (the transfers themselves are
-        #: async; execution keeps them alive once enqueued).
-        self._ring: collections.deque = collections.deque(
-            maxlen=self._RING_DEPTH
-        )
-
-    @property
-    def carry(self):
-        return self._carry
-
-    def _fresh_carry(self):
-        vals = (
-            np.uint32(U32_MAX), np.uint32(U32_MAX), np.int32(-1),
-        ) + ((np.int32(0),) if self._mesh is not None else ()) + (
-            np.int32(I32_MAX),
-        )
-        if self._mesh is None:
-            return tuple(jnp.asarray(v) for v in vals)
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        rep = NamedSharding(self._mesh, PartitionSpec())
-        return tuple(jax.device_put(v, rep) for v in vals)
-
-    def _refill(self, midstate, tail_const, bounds):
-        """Ship one chunk descriptor to the device, asynchronously: the
-        ring-slot transfer starts now and overlaps the dispatches already
-        in the device queue."""
-        if self._mesh is not None:
-            from ..parallel.sweep import shard_operands
-
-            slot = shard_operands(
-                midstate, tail_const, bounds, self._mesh, self._axis_name
-            )
-        elif self._backend == "pallas":
-            tailcb = np.concatenate(
-                [tail_const, bounds.astype(np.uint32)], axis=1
-            )
-            slot = (jnp.asarray(midstate), jnp.asarray(tailcb))
-        else:
-            slot = (
-                jnp.asarray(midstate),
-                jnp.asarray(tail_const),
-                jnp.asarray(bounds),
-            )
-        self._ring.append(slot)
-        METRICS.inc("sweep.ring_refills")
-        return slot
-
-    def dispatch(self, kern, midstate, tail_const, bounds) -> _HotToken:
-        """Enqueue one donated step; returns the token ``consume`` later
-        drains.  Called from the (single) dispatcher thread only — the
-        carry handle swap is not locked."""
-        step = _hot_step_for(self._backend, kern, self._sieve, self._mesh)
-        if self._carry is None:
-            self._carry = self._fresh_carry()
-        slot = self._refill(midstate, tail_const, bounds)
-        seq = self._seq
-        self._seq = seq + 1
-        self._carry, probe = step(self._carry, jnp.int32(seq), *slot)
-        METRICS.inc("sweep.donated_dispatches")
-        if self._sieve:
-            # By construction: the threshold this step sieved against is
-            # the running min through dispatch seq-1.
-            METRICS.set_gauge("kernel.thresh_staleness", 1.0)
-        return _HotToken(seq=seq, probe=probe, t_enq=_time.monotonic())
-
-    def drain(self, token: _HotToken, bases, n_lanes) -> float:
-        """Block on one dispatch's probe: registers its descriptor,
-        prunes every descriptor the carry can no longer reference, and
-        reports the per-dispatch latency.  Tokens drain in FIFO dispatch
-        order (both drivers guarantee it)."""
-        self._bases[token.seq] = (bases, n_lanes)
-        vals = np.asarray(token.probe)  # blocks until the step lands
-        self._drained += 1
-        best_seq = int(vals[1])
-        # The final winner is either this probe's best_seq or a dispatch
-        # AFTER token.seq (the carry only moves to strictly better, later
-        # candidates) — every other descriptor at or below token.seq is
-        # dead.  Keeps host state O(in-flight) over 10^6-dispatch jobs.
-        for s in [s for s in self._bases if s <= token.seq and s != best_seq]:
-            del self._bases[s]
-        dt = _time.monotonic() - token.t_enq
-        METRICS.observe("hist.device_dispatch_s", dt)
-        if _trace.enabled():
-            _trace.emit(
-                None, "kernel", "dispatch_done",
-                rows=len(bases), lanes=n_lanes, dt=round(dt, 6),
-                ring=self._seq - self._drained, donated=True,
-            )
-        return dt
-
-    def finish(self):
-        """Fetch the carry ONCE (the only full sync of the job) and
-        resolve it to a ``(hash, nonce)`` candidate, or None if no device
-        dispatch produced a valid lane."""
-        if self._carry is None:
-            return None
-        if self._mesh is not None:
-            bh0, bh1, bseq, bdev, bflat = (
-                int(x) for x in self._carry
-            )  # donate-ok: THE job-end fetch — the one sanctioned sync
-        else:
-            bh0, bh1, bseq, bflat = (
-                int(x) for x in self._carry
-            )  # donate-ok: THE job-end fetch — the one sanctioned sync
-        if bflat == I32_MAX:
-            return None
-        entry = self._bases.get(bseq)
-        if entry is None:
-            # Only reachable when a fetch was dropped (injected wedge /
-            # close mid-job): the winning dispatch's descriptor is gone.
-            raise RuntimeError(
-                "hot sweep winner's descriptor was never drained"
-            )
-        bases, n_lanes = entry
-        row = bflat // n_lanes
-        if self._mesh is not None:
-            row = MeshRows(len(bases), self._mesh.devices.size).row(bdev, row)
-        return ((bh0 << 32) | bh1, bases[row] + bflat % n_lanes)
-
-
 #: TPU-runtime fault injection (ISSUE 10 satellite, carry-over from PR 2):
 #: ``BMT_WEDGE_DISPATCH=N`` makes the N-th result fetched by the FIRST
 #: armed pipeline in this process hang until that pipeline is closed —
@@ -1294,20 +995,22 @@ _WEDGE_STATE = {"fired": False}
 class SweepPipeline:
     """Cross-request sweep pipeline: the device never idles between jobs.
 
-    A synchronous :func:`sweep_min_hash` call pays the dispatch+fetch
-    latency once per call, and concurrent calls from separate threads race
-    their dispatch enqueues so the device interleaves both jobs and both
-    finish late (an older remote-runtime run, before PR 1, saw a pipelined
-    fleet stuck at ~38% of kernel rate).
-    This pipeline serializes *enqueue* order in one dispatcher thread —
-    jobs' dispatches land on the device queue back-to-back, FIFO — while a
-    fetcher thread blocks on results in the same order and resolves each
-    job's future the moment its last dispatch lands.  Submitting job N+1
+    The one sweep driver, on one device or a mesh.  Concurrent
+    synchronous sweeps from separate threads would race their dispatch
+    enqueues so the device interleaves both jobs and both finish late (an
+    older remote-runtime run, before PR 1, saw a pipelined fleet stuck at
+    ~38% of kernel rate).  This pipeline serializes *enqueue* order in
+    one dispatcher thread — jobs' dispatches land on the device queue
+    back-to-back, FIFO — while a fetcher thread blocks on results in the
+    same order and resolves each job's future the moment its last
+    dispatch lands.  Submitting job N+1
     while job N computes therefore costs zero device idle, and results
     stream back with per-job latency, not per-job-pair bursts.
 
     Used by the miner worker (apps/miner.py) to serve the scheduler's
     pipelined 2-deep assignment window; ``submit`` is thread-safe.
+    :func:`sweep_min_hash` and ``parallel.sweep_min_hash_sharded`` are
+    its synchronous form: one job through a pipeline of their own.
     """
 
     _DONE = object()
@@ -1328,7 +1031,6 @@ class SweepPipeline:
         workload=None,
         sieve: Optional[bool] = None,
         factored: Optional[bool] = None,
-        hot: Optional[bool] = None,
     ) -> None:
         import queue as _queue
         import threading
@@ -1344,8 +1046,8 @@ class SweepPipeline:
         ) = _workload_knobs(workload)
         if mesh is not None and backend is None:
             # Resolve the backend from the MESH devices, not the process
-            # default (same guard as sweep_min_hash_sharded: a CPU mesh in
-            # a TPU-default process must get xla, not a Mosaic kernel).
+            # default: a CPU mesh in a TPU-default process must get xla,
+            # not a Mosaic kernel.
             from ..utils.platform import is_tpu_device
 
             if not is_tpu_device(mesh.devices.flat[0]):
@@ -1353,9 +1055,9 @@ class SweepPipeline:
         self._n_devices = 1 if mesh is None else mesh.devices.size
         (
             self._backend, self._batch, self._max_k, self._sieve,
-            self._factored, self._hot,
+            self._factored,
         ) = auto_tune(
-            backend, batch, max_k, sieve, factored, hot,
+            backend, batch, max_k, sieve, factored,
             family=self._family, n_devices=self._n_devices,
         )
         if mesh is not None and self._backend == "pallas":
@@ -1610,15 +1312,6 @@ class SweepPipeline:
                 return
             data, lower, upper, fut = item
             state = {"best": [], "lanes": 0, "fut": fut}
-            if self._hot:
-                # One hot loop per job: the donated carry is the job's
-                # running (best, threshold) state; its tokens flow through
-                # the same fetch queue as per-chunk handles, so the wedge
-                # drill and the backpressure window are unchanged.
-                state["hot"] = _HotLoop(
-                    self._backend, self._sieve, mesh=self._mesh,
-                    axis_name=self._axis_name,
-                )
 
             def run_kernel(kern, midstate, tail_const, bounds):
                 # Class lock: a cold class traces inside this call; holding
@@ -1627,12 +1320,6 @@ class SweepPipeline:
                 # lock is uncontended in steady state.  The enqueue stamp
                 # rides with the handle so the fetcher can report each
                 # dispatch's enqueue→fetch time (hist.device_dispatch_s).
-                hot = state.get("hot")
-                if hot is not None:
-                    with self._class_lock(kern):
-                        tok = hot.dispatch(kern, midstate, tail_const, bounds)
-                        self._warm_keys.add(getattr(kern, "class_key", kern))
-                        return tok
                 th = None
                 if self._sieve:
                     # Sieve threshold: the running-min h0 known at ENQUEUE
@@ -1640,13 +1327,6 @@ class SweepPipeline:
                     # looser — read is conservative-correct, so no lock).
                     b = state["best"]
                     th = (b[0][0] >> 32) if b else U32_MAX
-                    # The contrast number for the hot plane's zero-lag
-                    # carry: an operand-shipped threshold is as stale as
-                    # the whole in-flight window.
-                    METRICS.set_gauge(
-                        "kernel.thresh_staleness",
-                        float(self._fetches.qsize() + 1),
-                    )
                 with self._class_lock(kern):
                     out = self._invoke(
                         kern, midstate, tail_const, bounds, thresh=th
@@ -1704,15 +1384,6 @@ class SweepPipeline:
             if out is self._DONE:
                 if not fut.done():  # not already failed by the dispatcher
                     best = state["best"]
-                    hot = state.get("hot")
-                    if hot is not None:
-                        try:
-                            cand = hot.finish()
-                        except BaseException as e:
-                            self._fail(fut, e)
-                            continue
-                        if cand is not None and (not best or cand < best[0]):
-                            best[:] = [cand]
                     if not best:
                         self._fail(
                             fut, RuntimeError("sweep produced no candidates")
@@ -1734,12 +1405,6 @@ class SweepPipeline:
                 if not best or cand < best[0]:
                     best[:] = [cand]
                 continue
-            if isinstance(out, _HotToken):
-                try:
-                    state["hot"].drain(out, bases, n_lanes)
-                except BaseException as e:
-                    self._fail(fut, e)
-                continue
             try:
                 handles, t_enq = out  # run_kernel stamped the enqueue
                 if len(handles) == 4:  # mesh mode: (h0, h1, device, flat)
@@ -1759,14 +1424,9 @@ class SweepPipeline:
                 dt = _time.monotonic() - t_enq
                 METRICS.observe("hist.device_dispatch_s", dt)
                 if _trace.enabled():
-                    # ring/donated attrs (ISSUE 16): the per-chunk path
-                    # allocates fresh buffers per dispatch and has no
-                    # descriptor ring — the hot plane's emits say the
-                    # opposite (_HotLoop.drain).
                     _trace.emit(
                         None, "kernel", "dispatch_done",
                         rows=len(bases), lanes=n_lanes, dt=round(dt, 6),
-                        ring=0, donated=False,
                     )
                 if fi != I32_MAX:
                     h = (int(h0) << 32) | int(h1)
@@ -1793,7 +1453,6 @@ def sweep_min_hash(
     workload=None,
     sieve: Optional[bool] = None,
     factored: Optional[bool] = None,
-    hot: Optional[bool] = None,
 ) -> SweepResult:
     """Find ``(min Hash(data, n), argmin n)`` over inclusive ``[lower,
     upper]`` on the default JAX device.  Bit-exact vs the hashlib oracle
@@ -1820,70 +1479,20 @@ def sweep_min_hash(
     = the :func:`auto_tune` rung): the lane axis splits into outer digit
     groups whose invariant round prefix is computed once per group on
     the scalar unit — composable with ``sieve``, bit-exact either way.
-    ``hot`` = the always-hot device plane (ISSUE 16; None = the
-    :func:`auto_tune` rung): dispatches become donated steps over a
-    device-carried ``(best, threshold)`` buffer fed by an async chunk-
-    descriptor ring (:class:`_HotLoop`) — composable with both other
-    rungs, bit-exact either way.
+    ``host_lane_budget`` = the largest digit class min-folded on the host
+    (:class:`HostFold`); 0 keeps every class on the device.
+
+    The synchronous form of :class:`SweepPipeline`: one job through a
+    pipeline of its own, closed on return.  A failure raises here with
+    the dispatcher's own exception (``ValueError`` for a bad ``cpb``,
+    ``RuntimeError`` when the sweep found no candidate).
     """
-    sep, host_min, _native_ok, family = _workload_knobs(workload)
-    backend, batch, max_k, sieve, factored, hot = auto_tune(
-        backend, batch, max_k, sieve, factored, hot, family=family
+    p = SweepPipeline(
+        max_k=max_k, batch=batch, tile=tile, cpb=cpb, backend=backend,
+        interpret=interpret, host_lane_budget=host_lane_budget,
+        workload=workload, sieve=sieve, factored=factored,
     )
-    rolled = not is_tpu()
-
-    best: List[Tuple[int, int]] = []  # [(hash, nonce)] — current minimum
-    hotloop = _HotLoop(backend, sieve) if hot else None
-
-    def get_kernel(layout, group):
-        return _build_kernel(
-            backend, batch, tile, cpb, interpret, rolled, layout, group,
-            sieve=sieve, factored=factored,
-        )
-
-    def run_kernel(kern, midstate, tail_const, bounds):
-        if hotloop is not None:
-            return hotloop.dispatch(kern, midstate, tail_const, bounds)
-        th = None
-        if sieve:
-            # The running-min h0 at enqueue time; pipelined dispatches may
-            # carry a stale (looser) bound — conservative-correct.
-            th = (best[0][0] >> 32) if best else U32_MAX
-        return _invoke_kernel(
-            backend, kern, midstate, tail_const, bounds, thresh=th
-        )
-
-    def consume(out, bases, n_lanes):
-        if isinstance(out, HostFold):
-            cand = (out.hash, out.nonce)
-            if not best or cand < best[0]:
-                best[:] = [cand]
-            return
-        if isinstance(out, _HotToken):
-            hotloop.drain(out, bases, n_lanes)
-            return
-        h0, h1, flat_idx = out
-        fi = int(flat_idx)
-        if fi == I32_MAX:
-            # Fully-masked call, or (sieve) no lane beat the threshold —
-            # the running minimum stands.
-            return
-        h = (int(h0) << 32) | int(h1)
-        cand = (h, bases[fi // n_lanes] + fi % n_lanes)
-        if not best or cand < best[0]:
-            best[:] = [cand]
-
-    lanes = run_sweep_dispatches(
-        data, lower, upper, max_k, batch, get_kernel, run_kernel, consume,
-        host_lane_budget=host_lane_budget, sep=sep, host_min=host_min,
-        family=family,
-    )
-    if hotloop is not None:
-        # The job's ONE carry fetch: every device dispatch folded on
-        # device; merge with any host-routed candidates.
-        cand = hotloop.finish()
-        if cand is not None and (not best or cand < best[0]):
-            best[:] = [cand]
-    if not best:
-        raise RuntimeError("sweep produced no candidates")
-    return SweepResult(hash=best[0][0], nonce=best[0][1], lanes_swept=lanes)
+    try:
+        return p.submit(data, lower, upper).result()
+    finally:
+        p.close()

@@ -26,17 +26,13 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.sha256 import DigitPos
-from ..utils.platform import is_tpu_device
 from ..ops.sweep import (
     I32_MAX,
     U32_MAX,
-    MeshRows,
+    SweepPipeline,
     SweepResult,
-    _workload_knobs,
-    auto_tune,
     default_factor_k_in,
     make_kernel_body,
-    run_sweep_dispatches,
 )
 from .mesh import MINER_AXIS, default_mesh
 
@@ -290,8 +286,7 @@ def sharded_kernel_for(
     """Build (or fetch cached) the sharded kernel closure for one digit
     class: ``kern(midstate, tail_const, bounds, *th) -> (g_h0, g_h1,
     g_dev, g_flat)`` (``*th`` is the one replicated uint32 threshold
-    operand when ``sieve=True``, empty otherwise).  Shared by the
-    synchronous sharded driver below and the mesh mode of
+    operand when ``sieve=True``, empty otherwise), for the mesh mode of
     ``ops.sweep.SweepPipeline``; dyn-kernel closures carry ``class_key``
     for the pipeline's single-flight build locks.
 
@@ -374,10 +369,7 @@ def sharded_kernel_for(
 def shard_operands(midstate, tail_const, bounds, mesh: Mesh, axis_name: str):
     """Place one dispatch's chunk descriptor on the mesh, asynchronously:
     slot blocks sharded contiguously along ``axis_name`` (block ``d`` on
-    device ``d``, filled by ``ops.sweep.MeshRows``), midstate replicated.
-    Shared by :func:`sharded_invoke` and the hot plane's descriptor-ring
-    refills (``ops.sweep._HotLoop``), so both dispatch forms ship
-    byte-identical operand placements."""
+    device ``d``, filled by ``ops.sweep.MeshRows``), midstate replicated."""
     row = NamedSharding(mesh, P(axis_name, None))
     rep = NamedSharding(mesh, P())
     return (
@@ -414,11 +406,9 @@ def sweep_min_hash_sharded(
     batch_per_device: Optional[int] = None,
     backend: Optional[str] = None,
     interpret: bool = False,
-    stats: Optional[dict] = None,
     workload=None,
     sieve: Optional[bool] = None,
     factored: Optional[bool] = None,
-    hot: Optional[bool] = None,
 ) -> SweepResult:
     """Multi-chip ``(min Hash(data, n), argmin n)`` over inclusive
     ``[lower, upper]``; bit-exact vs the hashlib oracle, lowest-nonce ties.
@@ -426,113 +416,36 @@ def sweep_min_hash_sharded(
     A dispatch has ``n_devices * batch_per_device`` slots (by default
     ``auto_tune``'s 1024 in all on the pallas tier, 256 a device on four);
     its rows spread evenly over the devices (``ops.sweep.MeshRows``) and
-    the padding slots have empty lane bounds, masked in-kernel.  Results
-    are fetched lazily after all dispatches are queued so the device
-    pipeline stays full.
+    the padding slots have empty lane bounds, masked in-kernel.
 
-    ``sieve`` (ISSUE 14 satellite, None = the :func:`auto_tune` rung for
+    ``sieve`` (ISSUE 14 satellite, None = the ``auto_tune`` rung for
     this backend): the PER-SHARD two-stage sieve — each dispatch carries
     the host's running-min h0 replicated to every shard, each shard's
     pass 1 seeds from it (and, on pallas, tightens its own local running
     min in SMEM scratch) ahead of the collective argmin cascade, and a
     survivor-less shard contributes the sentinel the cascade orders
-    last.  Bit-exact either way; the sharded tier no longer forces the
-    baseline kernel.
+    last.  Bit-exact either way.
 
-    ``factored`` (ISSUE 16 satellite, None = the :func:`auto_tune` rung):
+    ``factored`` (ISSUE 16 satellite, None = the ``auto_tune`` rung):
     the outer/inner digit split, threaded per-shard through the xla
     sharded kernels — a mesh miner gets the single-device tier's 2.76×
     win.  Ignored by the sharded pallas branch (dyn kernels; real-TPU
-    arbitration follow-on).  ``hot`` (ISSUE 16, None = the rung): the
-    always-hot device plane — donated replicated carry + descriptor-ring
-    refills via :func:`shard_operands` — wrapping the sharded kernels.
+    arbitration follow-on).
 
-    ``stats``, if given, is filled with dispatch-overlap accounting:
-    ``dispatches`` (count), ``fetch_wait_seconds`` (host time blocked on
-    result fetches — near zero means enqueue fully overlapped compute).
+    The synchronous form of ``ops.sweep.SweepPipeline`` in mesh mode: one
+    job through a pipeline of its own, closed on return.  Its one
+    dispatcher thread enqueues every collective in job order, which the
+    multi-host miner needs: each process must enqueue the same
+    collectives in the same order.
     """
     if mesh is None:
         mesh = default_mesh(axis_name=axis_name)
-    n_dev = mesh.devices.size
-    mesh_on_tpu = is_tpu_device(mesh.devices.flat[0])
-    if backend is None and not mesh_on_tpu:
-        backend = "xla"
-    sep, host_min, _native_ok, family = _workload_knobs(workload)
-    backend, batch_per_device, max_k, sieve, factored, hot = auto_tune(
-        backend, batch_per_device, max_k, sieve, factored, hot,
-        family=family, n_devices=n_dev,
+    p = SweepPipeline(
+        mesh=mesh, axis_name=axis_name, max_k=max_k, batch=batch_per_device,
+        backend=backend, interpret=interpret, host_lane_budget=0,
+        workload=workload, sieve=sieve, factored=factored,
     )
-    rolled = not mesh_on_tpu
-    batch = n_dev * batch_per_device
-
-    def get_kernel(layout, group):
-        return sharded_kernel_for(
-            layout, group, batch_per_device, mesh, axis_name, backend,
-            interpret, rolled, sieve=sieve, factored=factored,
-        )
-
-    if stats is not None:
-        stats.update(dispatches=0, fetch_wait_seconds=0.0)
-
-    from ..ops.sweep import _HotLoop, _HotToken
-
-    hotloop = (
-        _HotLoop(backend, sieve, mesh=mesh, axis_name=axis_name)
-        if hot
-        else None
-    )
-
-    def run_kernel(kern, midstate, tail_const, bounds):
-        if stats is not None:
-            stats["dispatches"] += 1
-        if hotloop is not None:
-            return hotloop.dispatch(kern, midstate, tail_const, bounds)
-        th = None
-        if sieve:
-            # Enqueue-time running-min h0; a stale (looser) read is
-            # conservative-correct, same as the single-device driver.
-            th = (best[0][0] >> 32) if best else U32_MAX
-        return sharded_invoke(
-            kern, midstate, tail_const, bounds, mesh, axis_name, thresh=th
-        )
-
-    best: list = []
-
-    def consume(out, bases, n_lanes):
-        from ..ops.sweep import HostFold
-
-        if isinstance(out, HostFold):
-            cand = (out.hash, out.nonce)
-            if not best or cand < best[0]:
-                best[:] = [cand]
-            return
-        if isinstance(out, _HotToken):
-            hotloop.drain(out, bases, n_lanes)
-            return
-        h0, h1, dev, flat = out
-        if stats is not None:
-            import time
-
-            t0 = time.perf_counter()
-            jax.block_until_ready(flat)
-            stats["fetch_wait_seconds"] += time.perf_counter() - t0
-        fi = int(flat)
-        if fi == I32_MAX:
-            return
-        row = MeshRows(len(bases), n_dev).row(int(dev), fi // n_lanes)
-        h = (int(h0) << 32) | int(h1)
-        cand = (h, bases[row] + fi % n_lanes)
-        if not best or cand < best[0]:
-            best[:] = [cand]
-
-    lanes = run_sweep_dispatches(
-        data, lower, upper, max_k, batch, get_kernel, run_kernel, consume,
-        sep=sep, host_min=host_min, family=family, n_devices=n_dev,
-    )
-    if hotloop is not None:
-        cand = hotloop.finish()
-        if cand is not None and (not best or cand < best[0]):
-            best[:] = [cand]
-    if not best:
-        raise RuntimeError("sharded sweep produced no candidates")
-    return SweepResult(hash=best[0][0], nonce=best[0][1], lanes_swept=lanes)
+    try:
+        return p.submit(data, lower, upper).result()
+    finally:
+        p.close()

@@ -369,8 +369,6 @@ def format_quantiles(h) -> str:
 #:   miner.tier_downgrades     kernel tiers abandoned by the sweep watchdog
 #:   sweep.device_lanes        nonces swept by a device kernel dispatch
 #:   sweep.host_fold_lanes     nonces of tiny digit classes min-folded on the host
-#:   sweep.ring_refills        chunk descriptors shipped to the hot plane's device ring
-#:   sweep.donated_dispatches  donated-carry steps enqueued by the always-hot plane
 #:   sweep.kernel_export_hits  pallas dyn kernels loaded from a stored export (no trace)
 #:   sweep.kernel_export_misses  pallas dyn kernels traced, exported and stored
 #:   sweep.kernel_build_s      seconds of a stored kernel's first call (gauge; the latest)
@@ -378,7 +376,6 @@ def format_quantiles(h) -> str:
 #:   sweep.mesh_row_slots      n_devices x the fullest device's rows, per mesh dispatch
 #:   sweep.mesh_dispatch_slots  n_devices x the per-device batch, per mesh dispatch
 #:   sweep.mesh_dispatches     mesh (sharded) dispatches enqueued
-#:   kernel.thresh_staleness   sieve-threshold lag in dispatches (gauge; 1 = device-resident)
 #:   client.resubmits          jobs resubmitted after a lost client conn
 #:   chaos.dropped             packets dropped by the network simulator
 #:   chaos.partitioned         packets blackholed by a directional partition

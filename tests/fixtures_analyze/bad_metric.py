@@ -19,12 +19,9 @@
 - ``ingress.fixture_events`` is documented below but never emitted
   (``metric-unused`` — pins the new ``ingress.*`` counter family in the
   registry cross-check);
-- ``kernel.thresh_staleness`` is the hot plane's sieve-threshold lag
-  gauge (the one gauge-kind name under ``kernel.*``, ISSUE 16) but
-  emitted via ``inc`` (``metric-kind-mismatch``);
 - ``sweep.fixture_refills`` is documented below but never emitted
-  (``metric-unused`` — pins the ``sweep.*`` hot-plane counter family,
-  which stays inc-kind, in the registry cross-check);
+  (``metric-unused`` — pins the ``sweep.*`` counter family, which
+  stays inc-kind, in the registry cross-check);
 - ``autoscale.target_workers`` is the capacity plane's fleet-size gauge
   (the one gauge-kind name under ``autoscale.*``, ISSUE 18) but emitted
   via ``inc`` (``metric-kind-mismatch``);
@@ -62,8 +59,7 @@ class Metrics:  # stand-in so the fixture never imports the real package
 #:   fed.peer_state.fixture    a membership gauge (set_gauge-only kind)
 #:   gw.conns_live             the ingress live-conn gauge (set_gauge-only kind)
 #:   ingress.fixture_events    an ingress counter, documented but never emitted
-#:   kernel.thresh_staleness   the hot plane's threshold-lag gauge (set_gauge-only kind)
-#:   sweep.fixture_refills     a hot-plane counter, documented but never emitted
+#:   sweep.fixture_refills     a sweep counter, documented but never emitted
 #:   autoscale.target_workers  the capacity plane's fleet-size gauge (set_gauge-only kind)
 #:   fed.conns_live            the federation shared-loop conn gauge (set_gauge-only kind)
 #:   autoscale.fixture_actions an autoscale action counter, documented but never emitted
@@ -77,7 +73,6 @@ def provoke_metric_drift(suffix: str) -> None:
     METRICS.inc("fleet.fixture_sources")  # wrong emitter for a fleet.* gauge
     METRICS.inc("fed.peer_state.fixture")  # wrong emitter for a membership gauge
     METRICS.inc("gw.conns_live")  # wrong emitter for the ingress conn gauge
-    METRICS.inc("kernel.thresh_staleness")  # wrong emitter for the lag gauge
     METRICS.inc("autoscale.target_workers")  # wrong emitter for the fleet-size gauge
     METRICS.inc("fed.conns_live")  # wrong emitter for the fed conn gauge
     METRICS.inc("fixture." + suffix)  # dynamic name: unverifiable

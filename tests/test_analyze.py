@@ -35,7 +35,6 @@ if str(REPO) not in sys.path:  # make `tools.analyze` importable in-process
 from tools.analyze import PASSES, apply_ratchet, load_ratchet, save_ratchet
 from tools.analyze import contracts as contracts_pass
 from tools.analyze.common import DEFAULT_SCAN_DIRS, Finding
-from tools.analyze.donatecheck import DONATE_SCAN_DIRS
 from tools.analyze.tracecheck import TRACE_SCAN_DIRS
 
 from bitcoin_miner_tpu.utils import sanitize
@@ -54,14 +53,11 @@ def _pass_findings(name, root, scan=None):
     "name",
     [
         "lock", "wfq", "trace", "contracts", "sanitize", "metrics",
-        "loop", "donate", "thread",
+        "loop", "thread",
     ],
 )
 def test_repo_is_clean(name):
-    scan = {
-        "trace": TRACE_SCAN_DIRS,
-        "donate": DONATE_SCAN_DIRS,
-    }.get(name, DEFAULT_SCAN_DIRS)
+    scan = {"trace": TRACE_SCAN_DIRS}.get(name, DEFAULT_SCAN_DIRS)
     findings = _pass_findings(name, REPO, scan)
     ratchet = load_ratchet(REPO / "tools" / "analyze" / "ratchet.json")
     new, stale = apply_ratchet(findings, ratchet)
@@ -92,7 +88,7 @@ def test_cli_fixture_mode_exits_nonzero():
     assert res.returncode == 1, res.stdout + res.stderr
     # Every pass contributed at least one finding to the output.
     for tag in ("[lock/", "[wfq/", "[contracts/", "[trace/", "[sanitize/",
-                "[metrics/", "[loop/", "[donate/", "[thread/"):
+                "[metrics/", "[loop/", "[thread/"):
         assert tag in res.stdout, f"{tag} never fired:\n{res.stdout}"
 
 
@@ -212,13 +208,8 @@ def test_metrics_rules_fire_on_fixture():
     assert ("metric-unused", "ingress.fixture_events") in {
         (f.rule, f.symbol) for f in findings
     }
-    # kernel.thresh_staleness is the hot plane's threshold-lag gauge
-    # (ISSUE 16) — the one gauge-kind name under kernel.* — and the
-    # sweep.* hot-plane counter family rides the same registry
-    # cross-check (inc-kind).
-    assert ("metric-kind-mismatch", "kernel.thresh_staleness") in {
-        (f.rule, f.symbol) for f in findings
-    }
+    # The sweep.* counter family rides the same registry cross-check
+    # (inc-kind).
     assert ("metric-unused", "sweep.fixture_refills") in {
         (f.rule, f.symbol) for f in findings
     }
@@ -269,37 +260,6 @@ def test_loop_rules_fire_on_fixture():
         "clean_handler",           # awaited read / async with
         "suppressed_handler",      # trailing # loop-ok:
         "BadBridge.__init__",      # the annotation site itself
-    ):
-        assert clean not in symbols, (clean, symbols)
-
-
-def test_donate_rules_fire_on_fixture():
-    """Every donation-safety rule fires on bad_donate.py — via both the
-    explicit ``jax.jit(..., donate_argnums=...)`` spelling and the
-    hot-step factory convention — while the hot-carry rebind idiom
-    (the exact ``_HotLoop.dispatch`` shape), the ``carry is None``
-    refresh test, and ``# donate-ok:`` suppressions stay clean."""
-    findings = _pass_findings("donate", FIXTURES)
-    assert {
-        "donate-no-rebind",
-        "donate-read-after-call",
-        "donate-materialize",
-    } <= _rules(findings)
-    rules_syms = {(f.rule, f.symbol) for f in findings}
-    assert ("donate-no-rebind", "drops_result") in rules_syms
-    assert ("donate-no-rebind", "reads_dead_handle") in rules_syms
-    assert ("donate-read-after-call", "reads_dead_handle") in rules_syms
-    # The factory route: callee named like *hot_step* donates arg 0.
-    assert ("donate-no-rebind", "factory_route") in rules_syms
-    # Mid-job materialization of the donated carry, both spellings.
-    assert ("donate-materialize", "HotThing.peek") in rules_syms
-    assert ("donate-materialize", "HotThing.finish") in rules_syms
-    symbols = {f.symbol for f in findings}
-    for clean in (
-        "clean_rebind",              # the donated call rebinds
-        "sanctioned_drop",           # trailing # donate-ok:
-        "HotThing.dispatch",         # hot-carry rebind + None test
-        "HotThing.finish_sanctioned",  # the annotated job-end fetch
     ):
         assert clean not in symbols, (clean, symbols)
 
@@ -451,32 +411,6 @@ def test_trace_pass_collects_factored_kernel_bodies():
     # factored jit wrapper join the static + dyn ones.
     assert collected["ops/pallas_sha256.py"].count("kernel") >= 2
     assert collected["ops/pallas_sha256.py"].count("minhash") >= 3
-
-
-def test_trace_pass_collects_hot_step_bodies():
-    """ISSUE 16 coverage meta-test: the trace-safety lint must SEE the
-    always-hot plane's donated ring-loop step bodies.  ``make_hot_step``
-    builds one jitted ``step`` per backend variant (xla / pallas / mesh)
-    plus the shared ``_merge`` carry combine — all of them trace with a
-    carried device threshold, so the concretize/branch/wallclock rules
-    must gate them exactly like the kernels they wrap.  If a refactor
-    renames the factory outside the ``|hot`` convention, this test (not
-    silence) fails."""
-    import ast
-
-    from tools.analyze.common import file_comments
-    from tools.analyze.tracecheck import FACTORY_RE, _collect_kernel_bodies
-
-    # The hot factory naming is part of the convention now.
-    assert FACTORY_RE.search("make_hot_step")
-    src = (REPO / "bitcoin_miner_tpu" / "ops" / "sweep.py").read_text()
-    names = [
-        fn.name
-        for fn in _collect_kernel_bodies(ast.parse(src), file_comments(src))
-    ]
-    # All three backend-variant step bodies and the carry combine.
-    assert names.count("step") >= 3
-    assert "_merge" in names
 
 
 def test_trace_pass_collects_blake2b_kernel_bodies():

@@ -32,7 +32,8 @@ def run_bench(*flags, env=None, timeout=560):
 
 def test_sharded_devices_mode_on_virtual_mesh():
     """--devices N must run the sharded sweep on a virtual CPU mesh when
-    there aren't N real chips, and report per-device + overlap stats."""
+    there aren't N real chips, and report per-device stats and the mesh
+    dispatches the timed sweep made."""
     p = run_bench("--devices", "2", "--cpu")
     assert p.returncode == 0, p.stderr[-2000:]
     lines = p.stdout.strip().splitlines()
@@ -44,7 +45,6 @@ def test_sharded_devices_mode_on_virtual_mesh():
     # value and per_device are rounded independently from the raw rate.
     assert abs(out["per_device"] - out["value"] / 2) <= 1
     assert out["dispatches"] >= 1
-    assert "fetch_wait_seconds" in out
 
 
 def test_hung_backend_init_still_emits_json():
@@ -114,33 +114,6 @@ def test_factor_compare_fast_leg():
     assert out["kept_kernel"] in ("baseline", "factored")
 
 
-def test_hot_compare_fast_leg():
-    """``--hot-compare --fast`` (ISSUE 16): the tier-1 correctness leg
-    of the persistent-vs-per-chunk dispatch comparison — both disciplines
-    oracle-gated on a digit-boundary range, the interpret-mode pallas hot
-    plane (plain and sieve-composed, threshold device-carried) included,
-    and the JSON honest about which dispatch auto_tune keeps
-    (BENCH_pr16.json is the full-speed artifact)."""
-    p = run_bench("--hot-compare", "--fast", "--cpu")
-    assert p.returncode == 0, p.stderr[-2000:]
-    lines = p.stdout.strip().splitlines()
-    assert len(lines) == 1, lines
-    out = json.loads(lines[0])
-    assert out["metric"] == "hot_compare"
-    assert out["bitexact"] is True
-    assert out["interpret_pallas_hot_bitexact"] is True
-    assert out["perchunk_nps"] > 0 and out["hot_nps"] > 0
-    assert out["fast"] is True
-    # The honesty contract here is SELF-consistency: the JSON must record
-    # exactly what auto_tune picks for this backend.  (No ratio→kept
-    # coupling: the hot rung is calibrated on the FULL-SPEED same-seed
-    # pair — BENCH_pr16.json — and the --fast leg's tiny window under
-    # tier-1 load is a correctness gate, not a measurement; asserting on
-    # its noisy ratio would flake.)
-    assert out["auto_tune_hot"] == (out["kept_kernel"] == "hot")
-    assert out["kept_kernel"] in ("per-chunk", "hot")
-
-
 def test_tier_compare_fast_leg():
     """``--tier-compare --fast`` (ISSUE 20): the tier-1 correctness leg
     of the heterogeneous-plane comparison — the blake2b64 device tier and
@@ -167,7 +140,6 @@ def test_tier_compare_fast_leg():
     # exactly what auto_tune picks for the blake2b family on this host.
     assert "pallas_platform" in out
     assert out["auto_tune_factored"] == ("factored" in out["kept_kernel"])
-    assert out["auto_tune_hot"] == ("hot" in out["kept_kernel"])
 
 
 @pytest.mark.parametrize("flags", [(), ("--devices", "2")])

@@ -65,7 +65,7 @@ def no_compile_cache():
 def _production_shape(n_devices=1):
     """The pallas tier's kernel as ``auto_tune`` resolves it, for d=10, with
     ``batch`` the rows of each of ``n_devices`` devices."""
-    backend, batch, max_k, sieve, factored, _hot = auto_tune(
+    backend, batch, max_k, sieve, factored = auto_tune(
         "pallas", None, None, n_devices=n_devices
     )
     assert (backend, factored) == ("pallas", False), "the dyn kernel is the default"

@@ -791,216 +791,6 @@ class TestFactored:
         assert (int(h0), int(h1), int(idx)) == (h >> 32, h & 0xFFFFFFFF, n - 100)
 
 
-class TestHotPlane:
-    """The always-hot device plane (ISSUE 16): donated-carry dispatch
-    steps with the device-resident running-min threshold, on both
-    backends, plain and composed with the sieve and the factored tier.
-    The adversarial matrix extends TestSieve's: digit-class boundaries,
-    the u64 upper edge, exact (h0, h1) ties that must keep the CARRIED
-    lower-nonce candidate through the device-side merge, donation
-    correctness (no fresh allocations, no donation warnings), the
-    one-dispatch threshold lag, and the injected-wedge drill through the
-    hot fetch path — every case bit-exact vs the hashlib oracle."""
-
-    BACKENDS = [
-        ("xla", dict(backend="xla")),
-        ("pallas", dict(backend="pallas", interpret=True, batch=2)),
-    ]
-
-    @pytest.mark.parametrize("name,kw", BACKENDS, ids=[b[0] for b in BACKENDS])
-    @pytest.mark.parametrize(
-        "lo,hi",
-        [
-            (5, 15),       # 9→10: d=1 (host/static fallback) + d=2
-            (93, 107),     # 99→100 digit-class boundary
-            (985, 1040),   # 999→1000 (the dyn-kernel window shift)
-        ],
-    )
-    @pytest.mark.parametrize("sieve", [False, True], ids=["plain", "sieve"])
-    def test_digit_class_boundaries(self, name, kw, lo, hi, sieve):
-        r = sweep_min_hash(
-            "cmu440", lo, hi, max_k=2, hot=True, sieve=sieve, **kw
-        )
-        assert (r.hash, r.nonce) == min_hash_range("cmu440", lo, hi)
-        assert r.lanes_swept == hi - lo + 1
-
-    @pytest.mark.parametrize("name,kw", BACKENDS, ids=[b[0] for b in BACKENDS])
-    def test_u64_upper_edge(self, name, kw):
-        top = (1 << 64) - 1
-        r = sweep_min_hash(
-            "big", top - 50, top, max_k=1, hot=True, sieve=True, **kw
-        )
-        assert (r.hash, r.nonce) == min_hash_range("big", top - 50, top)
-
-    def test_multi_dispatch_hot_matches_per_chunk_and_oracle(self):
-        # batch=2 at k=2 → many donated steps re-using ONE carry buffer;
-        # the device-side merge must agree with the per-chunk host fold
-        # AND the per-nonce oracle (layout machinery in the loop), with
-        # the factored xla default riding along under the hot plane.
-        lo, hi = 100, 2099
-        r_hot = sweep_min_hash(
-            "cmu440", lo, hi, backend="xla", max_k=2, batch=2,
-            sieve=True, hot=True,
-        )
-        r_chunk = sweep_min_hash(
-            "cmu440", lo, hi, backend="xla", max_k=2, batch=2,
-            sieve=True, hot=False,
-        )
-        assert (r_hot.hash, r_hot.nonce) == (r_chunk.hash, r_chunk.nonce)
-        best = None
-        for n in range(lo, hi + 1):
-            digits = str(n)
-            layout = build_layout(b"cmu440", len(digits))
-            cand = (digest_u64_py(layout, digits), n)
-            if best is None or cand < best:
-                best = cand
-        assert (r_hot.hash, r_hot.nonce) == best
-
-    def test_no_donation_warnings(self):
-        # donate_argnums only elides the allocation when XLA actually
-        # aliases the buffer; a layout mismatch falls back to a copy and
-        # WARNS.  The zero-alloc claim requires silence on both backends.
-        import warnings
-
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", UserWarning)
-            for _name, kw in self.BACKENDS:
-                r = sweep_min_hash(
-                    "cmu440", 95, 1205, max_k=2, hot=True, sieve=True, **kw
-                )
-                assert (r.hash, r.nonce) == min_hash_range("cmu440", 95, 1205)
-
-    # ---------------------------------------------------- direct loop drive
-
-    def _tie_setup(self):
-        """Same fixture as TestSieve: one chunk row of [100, 199] for
-        'tie' (d=3, k=2) plus the oracle triple over it."""
-        import numpy as np
-
-        layout = build_layout(b"tie", 3)
-        h, n = min_hash_range("tie", 100, 199)
-        row = np.array(layout.tail_template, dtype=np.uint64)
-        dp = layout.digit_pos[0]
-        row[dp.word] |= np.uint64(ord("1") << dp.shift)
-        midstate = np.array(layout.midstate, dtype=np.uint32)
-        return layout, midstate, row, (h >> 32, h & 0xFFFFFFFF, n - 100)
-
-    def test_hot_loop_donation_tie_and_pruning(self):
-        """Drive :class:`_HotLoop` directly through two dispatches of the
-        SAME descriptor: (a) the donated carry re-uses ONE device buffer
-        (zero fresh accumulator allocations); (b) after dispatch 1 drains,
-        ``carry[0]`` already equals that dispatch's min h0 — the
-        one-dispatch threshold lag the staleness gauge records; (c)
-        dispatch 2 produces an exact (h0, h1) tie which must keep the
-        CARRIED ``best_seq == 0`` candidate; (d) probe drains prune the
-        seq->descriptor map to O(in-flight); (e) ``finish()`` resolves
-        the carry to the oracle's (hash, nonce)."""
-        import numpy as np
-
-        from bitcoin_miner_tpu.ops.sweep import (
-            _HotLoop, make_kernel_body,
-        )
-        from bitcoin_miner_tpu.utils.metrics import METRICS
-
-        layout, midstate, row, (eh0, eh1, elane) = self._tie_setup()
-        kern = make_kernel_body(
-            layout.n_tail_blocks, layout.digit_pos[1:], 2, batch=1,
-            rolled=True, sieve=True,
-        )
-        tail_const = row.astype(np.uint32)[None, :]
-        bounds = np.array([[0, 100]], dtype=np.int32)
-        refills0 = METRICS.get("sweep.ring_refills")
-        donated0 = METRICS.get("sweep.donated_dispatches")
-        loop = _HotLoop("xla", True)
-        tok1 = loop.dispatch(kern, midstate, tail_const, bounds)
-        loop.drain(tok1, [100], 100)
-        ptrs1 = tuple(c.unsafe_buffer_pointer() for c in loop.carry)
-        # (b) zero-staleness: the carried threshold is already this
-        # dispatch's min h0 — no host round-trip, no in-flight lag.
-        # Read it through the PROBE, never the carry: materialising a
-        # carry element host-side pins its buffer (jax caches the numpy
-        # view) and the next donation would silently fall back to a copy
-        # — the exact failure mode the probe protocol exists to prevent.
-        assert int(np.asarray(tok1.probe)[0]) == eh0
-        assert METRICS.gauge("kernel.thresh_staleness") == 1.0
-        tok2 = loop.dispatch(kern, midstate, tail_const, bounds)
-        loop.drain(tok2, [100], 100)
-        ptrs2 = tuple(c.unsafe_buffer_pointer() for c in loop.carry)
-        # (a) donation: the steady-state step wrote the carry IN PLACE —
-        # every accumulator buffer of dispatch 2 is a dispatch-1 buffer.
-        assert ptrs2 == ptrs1
-        # (c) the exact tie kept the carried dispatch-0 candidate.
-        assert int(np.asarray(tok2.probe)[1]) == 0
-        # (d) drain pruned the duplicate descriptor (seq 1 lost the tie).
-        assert set(loop._bases) == {0}
-        # (e) one carry fetch resolves to the oracle candidate.
-        assert loop.finish() == ((eh0 << 32) | eh1, 100 + elane)
-        assert METRICS.get("sweep.ring_refills") - refills0 == 2
-        assert METRICS.get("sweep.donated_dispatches") - donated0 == 2
-
-    def test_hot_loop_pallas_tie_survives_carried_threshold(self):
-        """Same tie contract through the REAL prize path: the carried
-        ``best_h0`` is sign-flipped ON DEVICE (:func:`_flip_thresh_traced`)
-        into the pallas sieve kernel's SMEM threshold, and the exact-tie
-        lane must survive pass 1 and keep the carried candidate."""
-        import numpy as np
-
-        from bitcoin_miner_tpu.ops.pallas_sha256 import make_pallas_minhash
-        from bitcoin_miner_tpu.ops.sweep import _HotLoop
-
-        layout, midstate, row, (eh0, eh1, elane) = self._tie_setup()
-        fn = make_pallas_minhash(
-            layout.n_tail_blocks, layout.digit_pos[1:], 2,
-            batch=1, interpret=True, sieve=True,
-        )
-        tail_const = row.astype(np.uint32)[None, :]
-        bounds = np.array([[0, 100]], dtype=np.int32)
-        loop = _HotLoop("pallas", True)
-        for _ in range(2):
-            tok = loop.dispatch(fn, midstate, tail_const, bounds)
-            loop.drain(tok, [100], 100)
-        # Dispatch 2 sieved against thresh == eh0 exactly: the tie lane
-        # survived pass 1 and the merge kept the dispatch-0 candidate.
-        assert int(np.asarray(tok.probe)[1]) == 0
-        assert loop.finish() == ((eh0 << 32) | eh1, 100 + elane)
-
-    def test_hot_loop_all_pruned_returns_none(self):
-        """A job whose every dispatch returns the sentinel (possible
-        when the host fold owns every candidate) must finish() to None,
-        not a bogus lane."""
-        from bitcoin_miner_tpu.ops.sweep import _HotLoop
-
-        loop = _HotLoop("xla", True)
-        assert loop.finish() is None  # no dispatch at all
-
-    def test_wedge_dispatch_fires_through_hot_path(self, monkeypatch):
-        """``BMT_WEDGE_DISPATCH=1`` must hang the first fetch of a HOT
-        pipeline exactly like the per-chunk drill (tokens flow through
-        the same fetch queue), and the watchdog budget must abandon the
-        tier and complete on the next rung."""
-        from bitcoin_miner_tpu.apps import miner as miner_mod
-        from bitcoin_miner_tpu.ops import sweep as sweep_mod
-
-        monkeypatch.setenv("BMT_WEDGE_DISPATCH", "1")
-        monkeypatch.setitem(sweep_mod._WEDGE_STATE, "fired", False)
-        ts = miner_mod._TieredSearch(
-            [
-                ("xla-hot", lambda: miner_mod._PipelineSearch(
-                    "xla", hot=True
-                )),
-                ("oracle", lambda: min_hash_range),
-            ],
-            wedge_seconds=4.0,
-        )
-        try:
-            fut = ts.submit("wedgehot", 0, 80)
-            assert fut.result(timeout=120) == min_hash_range("wedgehot", 0, 80)
-            assert ts.active_tier == "oracle"
-            assert sweep_mod._WEDGE_STATE["fired"]  # the hang was real
-        finally:
-            ts.close()
-
-
 class TestPipelineLifecycle:
     """SweepPipeline edge behavior: close/submit ordering and concurrent
     submitters — the states a miner hits at shutdown and under the
@@ -1095,8 +885,8 @@ class TestBlake2bDeviceTier:
         assert (r.hash, r.nonce) == w.min_range("big", top - 50, top)
 
     def test_sieve_threshold_operand_bit_exact(self):
-        # The kernel's carried-threshold mask (built for the hot plane's
-        # operand) must stay bit-exact when forced on.
+        # The kernel's threshold mask (off by default for this family)
+        # must stay bit-exact when forced on.
         w = self._wl()
         r = sweep_min_hash(
             "cmu440", 93, 320, backend="xla", max_k=2, sieve=True, workload=w

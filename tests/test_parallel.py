@@ -168,48 +168,6 @@ def test_sharded_factored_sieve_composition():
     assert (r.hash, r.nonce) == min_hash_range("cmu440", 1000, 2234)
 
 
-def test_sharded_hot_matches_oracle():
-    # The always-hot plane over the mesh (ISSUE 16): donated replicated
-    # carry merged AFTER the collective cascade, the carried best_dev
-    # scaling the row exactly like the per-chunk sharded fold.  The xla
-    # leg rides the factored sharded default.
-    r = sweep_min_hash_sharded(
-        "cmu440", 1000, 2234, backend="xla", max_k=2, batch_per_device=2,
-        sieve=True, hot=True,
-    )
-    assert (r.hash, r.nonce) == min_hash_range("cmu440", 1000, 2234)
-    assert r.lanes_swept == 2234 - 1000 + 1
-
-
-def test_sharded_hot_digit_boundary():
-    r = sweep_min_hash_sharded(
-        "x", 95, 305, backend="xla", max_k=1, batch_per_device=2, hot=True
-    )
-    assert (r.hash, r.nonce) == min_hash_range("x", 95, 305)
-
-
-def test_mesh_pipeline_hot_matches_oracle():
-    # SweepPipeline mesh mode with the hot plane on: back-to-back jobs,
-    # one donated carry per job, tokens through the same fetch queue.
-    from bitcoin_miner_tpu.ops.sweep import SweepPipeline
-
-    p = SweepPipeline(
-        backend="xla", mesh=default_mesh(8), max_k=2, batch=2,
-        host_lane_budget=0, sieve=True, hot=True,
-    )
-    try:
-        futs = [
-            p.submit("cmu440", 1000, 2234),
-            p.submit("cmu440", 2235, 3499),
-        ]
-        wants = [("cmu440", 1000, 2234), ("cmu440", 2235, 3499)]
-        for f, (d, lo, hi) in zip(futs, wants):
-            r = f.result(timeout=300)
-            assert (r.hash, r.nonce) == min_hash_range(d, lo, hi), (d, lo, hi)
-    finally:
-        p.close()
-
-
 def test_sharded_matches_single_device_tier():
     from bitcoin_miner_tpu.ops.sweep import sweep_min_hash
 
@@ -284,10 +242,10 @@ def _dispatch_rows(lo, hi, max_k):
     ]
 
 
-def _mesh_sweep(form, backend, data, lo, hi, max_k, **kw):
+def _mesh_sweep(form, backend, data, lo, hi, max_k, n_dev=N_DEV, **kw):
     from bitcoin_miner_tpu.ops.sweep import SweepPipeline
 
-    mesh = default_mesh(N_DEV)
+    mesh = default_mesh(n_dev)
     interpret = backend == "pallas"
     if form == "sharded":
         return sweep_min_hash_sharded(
@@ -304,11 +262,11 @@ def _mesh_sweep(form, backend, data, lo, hi, max_k, **kw):
         p.close()
 
 
-@pytest.mark.parametrize("form,backend,hot", [
-    ("sharded", "xla", False),
-    ("sharded", "pallas", False),
-    ("pipeline", "xla", False),
-    ("pipeline", "xla", True),  # the hot plane's job-end fold
+@pytest.mark.parametrize("form,backend", [
+    ("sharded", "xla"),
+    ("sharded", "pallas"),
+    ("pipeline", "xla"),
+    ("pipeline", "pallas"),  # the form and tier of the four-chip cell
 ])
 @pytest.mark.parametrize("data,lo,hi,max_k", [
     ("cmu440", 1000, 1099, 2),  # R = 1: device 0 alone
@@ -319,7 +277,7 @@ def _mesh_sweep(form, backend, data, lo, hi, max_k, **kw):
     ("x", 95, 305, 1),  # crosses d=2 -> d=3: dispatches of 1, 20 and 1 rows
 ])
 def test_mesh_even_placement_matches_oracle(
-    form, backend, hot, data, lo, hi, max_k, monkeypatch
+    form, backend, data, lo, hi, max_k, monkeypatch
 ):
     import numpy as np
 
@@ -329,7 +287,7 @@ def test_mesh_even_placement_matches_oracle(
     from bitcoin_miner_tpu.utils.metrics import METRICS
 
     shipped = []  # each dispatch's bounds, as placed on the mesh
-    place = psweep.shard_operands  # both dispatch forms place through it
+    place = psweep.shard_operands  # every mesh dispatch places through it
 
     def spy(midstate, tail_const, bounds, *a, **kw):
         shipped.append(np.array(bounds))
@@ -342,7 +300,7 @@ def test_mesh_even_placement_matches_oracle(
     )
     before = [METRICS.get(n) for n in names]
     with trace.tracing() as tr:
-        r = _mesh_sweep(form, backend, data, lo, hi, max_k, hot=hot)
+        r = _mesh_sweep(form, backend, data, lo, hi, max_k)
         events = [e for e in tr.drain() if e["event"] == "mesh_dispatch"]
     assert (r.hash, r.nonce) == min_hash_range(data, lo, hi)
     assert r.lanes_swept == hi - lo + 1
@@ -454,20 +412,24 @@ def test_mesh_rows_slot_map_is_nonce_ordered():
 
 
 @pytest.mark.parametrize("form", ["sharded", "pipeline"])
-@pytest.mark.parametrize("hot", [False, True])
-def test_mesh_cross_device_tie_lowest_nonce_wins(form, hot, monkeypatch):
-    # A stand-in kernel hashes nonce n to (7, 3) if n >= 1500, else
+@pytest.mark.parametrize("n_dev", [2, N_DEV])
+def test_mesh_cross_device_tie_lowest_nonce_wins(form, n_dev, monkeypatch):
+    # A stand-in kernel hashes nonce n to (7, 3) if n >= tie, else
     # (7, 9), reading n from the chunk templates and lanes as the real
-    # kernel sees them.  Over [1050, 1699] (7 rows, 1000..1600, of 100
-    # nonces; 2, 2, 2 and 1 rows per device) the minimum (7, 3) ties on
-    # both digest words across devices 2 (row 1500, local slot 1) and 3
-    # (row 1600, local slot 0, the lower flat index): the cascade must
-    # pick the lower device, and the fold must map it back to nonce 1500.
-    # A placement the fold does not mirror names another nonce.
+    # kernel sees them.  [1050, 1699] is 7 rows, 1000..1600, of 100
+    # nonces: 2, 2, 2 and 1 rows per device on 4 devices, 4 and 3 on 2.
+    # The tie starts at the last row of the next-to-last device (1500 on
+    # 4 devices, 1300 on 2), so the minimum (7, 3) ties on both digest
+    # words across that device and the last one, whose local slot 0 is
+    # the lower flat index: the cascade must pick the lower device, and
+    # the fold must map it back to the tie's first nonce.  A placement
+    # the fold does not mirror names another nonce.
     import jax.numpy as jnp
 
-    from bitcoin_miner_tpu.ops.sweep import I32_MAX, U32_MAX
+    from bitcoin_miner_tpu.ops.sweep import I32_MAX, U32_MAX, MeshRows
     from bitcoin_miner_tpu.parallel import sweep as psweep
+
+    tie = 1000 + 100 * (sum(MeshRows(7, n_dev).counts()[:-1]) - 1)
 
     def tie_kernel(layout, group, per_dev_batch, mesh, axis_name, *a, **kw):
         n_lanes = 10**group.k
@@ -481,7 +443,7 @@ def test_mesh_cross_device_tie_lowest_nonce_wins(form, hot, monkeypatch):
             i = jnp.arange(n_lanes, dtype=jnp.int32)[None, :]
             nonce = high[:, None] * n_lanes + i
             valid = (i >= bounds[:, :1]) & (i < bounds[:, 1:2])
-            h1 = jnp.where(nonce >= 1500, jnp.uint32(3), jnp.uint32(9))
+            h1 = jnp.where(nonce >= tie, jnp.uint32(3), jnp.uint32(9))
             h1 = jnp.where(valid, h1, jnp.uint32(U32_MAX))
             min_h1 = jnp.min(h1)
             flat = jnp.arange(h1.size, dtype=jnp.int32).reshape(h1.shape)
@@ -494,8 +456,10 @@ def test_mesh_cross_device_tie_lowest_nonce_wins(form, hot, monkeypatch):
         return psweep._shard_and_jit(local, mesh, axis_name, False)
 
     monkeypatch.setattr(psweep, "sharded_kernel_for", tie_kernel)
-    r = _mesh_sweep(form, "xla", "cmu440", 1050, 1699, 2, sieve=False, hot=hot)
-    assert (r.hash, r.nonce) == ((7 << 32) | 3, 1500)
+    r = _mesh_sweep(
+        form, "xla", "cmu440", 1050, 1699, 2, n_dev=n_dev, sieve=False
+    )
+    assert (r.hash, r.nonce) == ((7 << 32) | 3, tie)
 
 
 def test_single_device_templates_unchanged():
@@ -527,3 +491,214 @@ def test_single_device_templates_unchanged():
     assert hashlib.sha256(b"".join(out)).hexdigest() == (
         "b861cd02c106261fb58f7e6bd7a148c98768bf294754dd8eb93de2fb9c5ead0c"
     )
+
+
+# -- The mesh pipeline's adversarial matrix --------------------------------
+#
+# SweepPipeline over the 4-device mesh is the path the four-chip miner
+# runs; each case is bit-exact against the hashlib oracle.
+
+
+class TestMeshPipeline:
+    BACKENDS = ["xla", "pallas"]  # pallas in interpret mode (_mesh_sweep)
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    @pytest.mark.parametrize(
+        "lo,hi",
+        [
+            (5, 15),       # 9→10: d=1 (static pallas kernel) + d=2
+            (93, 107),     # 99→100 digit-class boundary
+            (985, 1040),   # 999→1000 (the dyn-kernel window shift)
+        ],
+    )
+    @pytest.mark.parametrize("sieve", [False, True], ids=["plain", "sieve"])
+    def test_digit_class_boundaries(self, backend, lo, hi, sieve):
+        r = _mesh_sweep("pipeline", backend, "cmu440", lo, hi, 2, sieve=sieve)
+        assert (r.hash, r.nonce) == min_hash_range("cmu440", lo, hi)
+        assert r.lanes_swept == hi - lo + 1
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    @pytest.mark.parametrize("sieve", [False, True], ids=["plain", "sieve"])
+    def test_u64_upper_edge(self, backend, sieve):
+        top = (1 << 64) - 1
+        r = _mesh_sweep("pipeline", backend, "big", top - 50, top, 1, sieve=sieve)
+        assert (r.hash, r.nonce) == min_hash_range("big", top - 50, top)
+        assert r.lanes_swept == 51
+
+    @staticmethod
+    def _fold_before_enqueue(monkeypatch):
+        """Hold each mesh dispatch until every earlier one has been fetched
+        and folded, so the threshold it carries is the running minimum
+        through the dispatch before it (the pipeline otherwise enqueues
+        ahead of its fetches).  Returns the ``(thresh, outputs)`` of each
+        dispatch, in order."""
+        import functools
+        import time
+
+        from bitcoin_miner_tpu.ops import sweep as sweep_mod
+        from bitcoin_miner_tpu.ops.sweep import MeshRows
+        from bitcoin_miner_tpu.parallel import sweep as psweep
+
+        folds, shipped = [], []
+        row, count = MeshRows.row, sweep_mod._count_mesh_dispatch
+        invoke = psweep.sharded_invoke
+
+        def counting_row(self, dev, local):  # the fetcher maps every result
+            folds.append(dev)
+            return row(self, dev, local)
+
+        def held_count(place, per_dev_batch):
+            deadline = time.monotonic() + 120
+            while len(folds) < len(shipped) and time.monotonic() < deadline:
+                time.sleep(0.005)
+            time.sleep(0.05)  # the fetcher's fold follows its row lookup
+            return count(place, per_dev_batch)
+
+        def spy_invoke(kern, *a, thresh=None, **kw):
+            out = invoke(kern, *a, thresh=thresh, **kw)
+            shipped.append((thresh, out))
+            return out
+
+        # Each dispatch goes to the fetcher as soon as it is enqueued.
+        monkeypatch.setattr(
+            sweep_mod, "run_sweep_dispatches",
+            functools.partial(sweep_mod.run_sweep_dispatches, max_inflight=0),
+        )
+        monkeypatch.setattr(MeshRows, "row", counting_row)
+        monkeypatch.setattr(sweep_mod, "_count_mesh_dispatch", held_count)
+        monkeypatch.setattr(psweep, "sharded_invoke", spy_invoke)
+        return shipped
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_threshold_tie_across_shards_survives(self, backend, monkeypatch):
+        # A stand-in kernel hashes every nonce of [1000, 3699] to (T, 9),
+        # but those >= 3500 to (T, 3), honouring the sieve threshold as the
+        # real kernels do (``h0 <= thresh``; in the sign-flipped int32
+        # domain on pallas, through the real _flip_thresh).  Dispatch 1
+        # (rows 1000..2999) sets the running minimum's h0 to T; dispatch 2
+        # (rows 3000..3600: 2, 2, 2 and 1 per device) carries threshold T
+        # exactly, and its (T, 3) ties across device 2 (row 3500, local
+        # slot 1) and device 3 (row 3600, slot 0, the lower flat index).
+        # The tie must survive the threshold, and the lower nonce must win.
+        import jax.numpy as jnp
+        from jax import lax
+
+        from bitcoin_miner_tpu.ops.sweep import I32_MAX, U32_MAX
+        from bitcoin_miner_tpu.parallel import sweep as psweep
+
+        T = 0x80000007  # past 2^31: the flipped domain orders it
+
+        def tie_kernel(
+            layout, group, per_dev_batch, mesh, axis_name, *a, sieve=False, **kw
+        ):
+            n_lanes = 10**group.k
+            n_high = layout.digit_count - group.k
+
+            def flip(x):
+                return lax.bitcast_convert_type(x ^ jnp.uint32(0x80000000), jnp.int32)
+
+            def local(midstate, tail_const, bounds, *th):
+                high = jnp.zeros(tail_const.shape[0], jnp.int32)
+                for dp in layout.digit_pos[:n_high]:
+                    byte = (tail_const[:, dp.word] >> dp.shift) & 0xFF
+                    high = high * 10 + byte.astype(jnp.int32) - 48
+                i = jnp.arange(n_lanes, dtype=jnp.int32)[None, :]
+                nonce = high[:, None] * n_lanes + i
+                valid = (i >= bounds[:, :1]) & (i < bounds[:, 1:2])
+                h0 = jnp.full(nonce.shape, T, jnp.uint32)
+                if sieve and backend == "pallas":
+                    valid = valid & (flip(h0) <= psweep._flip_thresh(th[0])[0])
+                elif sieve:
+                    valid = valid & (h0 <= th[0])
+                h1 = jnp.where(nonce >= 3500, jnp.uint32(3), jnp.uint32(9))
+                h1 = jnp.where(valid, h1, jnp.uint32(U32_MAX))
+                min_h1 = jnp.min(h1)
+                flat = jnp.arange(h1.size, dtype=jnp.int32).reshape(h1.shape)
+                first = jnp.min(
+                    jnp.where(valid & (h1 == min_h1), flat, jnp.int32(I32_MAX))
+                )
+                h0 = jnp.where(first != I32_MAX, jnp.uint32(T), jnp.uint32(U32_MAX))
+                return h0, min_h1, first
+
+            return psweep._shard_and_jit(local, mesh, axis_name, sieve)
+
+        monkeypatch.setattr(psweep, "sharded_kernel_for", tie_kernel)
+        shipped = self._fold_before_enqueue(monkeypatch)
+        r = _mesh_sweep("pipeline", backend, "cmu440", 1000, 3699, 2, sieve=True)
+        assert [th for th, _ in shipped] == [U32_MAX, T]
+        h0, h1, dev, flat = (int(x) for x in shipped[1][1])
+        assert (h0, h1, dev, flat) == (T, 3, 2, 100)
+        assert (r.hash, r.nonce) == ((T << 32) | 3, 3500)
+
+    @pytest.mark.parametrize("backend", BACKENDS)
+    def test_threshold_below_every_shard_keeps_running_min(
+        self, backend, monkeypatch
+    ):
+        # [1000, 4999] is two dispatches of 20 rows, and the job's minimum
+        # (nonce 1081) lies in the first.  The second carries that minimum's
+        # h0 as its threshold, below every lane of every shard: each shard
+        # contributes the sentinel, the cascade returns it, and the running
+        # minimum stands.
+        from bitcoin_miner_tpu.ops.sweep import I32_MAX, U32_MAX
+
+        want = min_hash_range("cmu440", 1000, 4999)
+        assert want[1] < 3000 < min_hash_range("cmu440", 3000, 4999)[1]
+        shipped = self._fold_before_enqueue(monkeypatch)
+        r = _mesh_sweep("pipeline", backend, "cmu440", 1000, 4999, 2, sieve=True)
+        assert [th for th, _ in shipped] == [U32_MAX, want[0] >> 32]
+        assert int(shipped[1][1][3]) == I32_MAX
+        assert (r.hash, r.nonce) == want
+
+    def test_wedge_dispatch_hangs_until_close(self, monkeypatch):
+        # BMT_WEDGE_DISPATCH=1 hangs the mesh pipeline's first fetch, as a
+        # stuck device future would: the job's future stays open until
+        # close() releases the fetch loop.
+        import time
+
+        from bitcoin_miner_tpu.ops import sweep as sweep_mod
+        from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+
+        monkeypatch.setenv("BMT_WEDGE_DISPATCH", "1")
+        monkeypatch.setitem(sweep_mod._WEDGE_STATE, "fired", False)
+        p = SweepPipeline(
+            backend="xla", mesh=default_mesh(N_DEV), max_k=2, batch=PER_DEV,
+            host_lane_budget=0,
+        )
+        try:
+            fut = p.submit("wedgemesh", 1000, 1299)
+            deadline = time.monotonic() + 120
+            while not sweep_mod._WEDGE_STATE["fired"] and time.monotonic() < deadline:
+                time.sleep(0.02)
+            assert sweep_mod._WEDGE_STATE["fired"]  # the hang was real
+            time.sleep(0.5)
+            assert not fut.done()
+        finally:
+            p.close()
+        assert not p._fetcher.is_alive()
+        # The dropped fetch never yields a result.
+        assert fut.exception(timeout=10) is not None
+
+
+@pytest.mark.parametrize("form", ["single", "sharded"])
+def test_sync_sweep_leaves_no_pipeline_thread(form):
+    # The synchronous sweeps run one job through a pipeline of their own
+    # and close it before they return.
+    import threading
+
+    from bitcoin_miner_tpu.ops.sweep import sweep_min_hash
+
+    names = ("sweep-dispatch", "sweep-fetch")
+    before = {t for t in threading.enumerate() if t.name in names}
+    if form == "single":
+        r = sweep_min_hash("cmu440", 1000, 1299, backend="xla", max_k=2)
+    else:
+        r = sweep_min_hash_sharded(
+            "cmu440", 1000, 1299, mesh=default_mesh(N_DEV), backend="xla",
+            max_k=2, batch_per_device=PER_DEV,
+        )
+    assert (r.hash, r.nonce) == min_hash_range("cmu440", 1000, 1299)
+    left = [
+        t for t in threading.enumerate()
+        if t.name in names and t not in before and t.is_alive()
+    ]
+    assert not left, left

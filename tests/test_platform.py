@@ -59,8 +59,10 @@ def test_device_desc():
     assert device_desc(dev("cpu", None)) == "cpu:?"
 
 
-# A fresh process turns the cache on, compiles one program with a constant
-# of its own (a key no other test writes), and lists the entries of that key.
+# A fresh process turns the cache on and compiles one program named for its
+# tag, with the tag as a constant: a key, and entry names, that no other
+# test or case writes, so cases running side by side cannot see each
+# other's entries in the shared in-checkout cache.
 _PROBE = """
 import sys
 import jax, jax.numpy as jnp
@@ -74,22 +76,26 @@ def cache_probe(x):
     return x * tag + 7
 
 
+cache_probe.__name__ = cache_probe.__qualname__ = f"cache_probe_{tag}"
 jax.jit(cache_probe).lower(jnp.zeros(3, jnp.int32)).compile()
 """
 
 
-def _entries(d: Path, before=frozenset()):
+def _tag(tmp_path) -> str:
+    return str(abs(hash(tmp_path)) % 10**9)
+
+
+def _entries(d: Path, tag: str, before=frozenset()):
     if not d.is_dir():
         return set()
-    return {p.name for p in d.glob("jit_cache_probe*")} - set(before)
+    return {p.name for p in d.glob(f"jit_cache_probe_{tag}-*")} - set(before)
 
 
-def _probe(tmp_path, env_dir):
+def _probe(tag, env_dir):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     if env_dir is not None:
         env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
-    tag = str(abs(hash(tmp_path)) % 10**9)
     p = subprocess.run(
         [sys.executable, "-c", _PROBE, tag],
         capture_output=True, text=True, timeout=120, cwd=REPO, env=env,
@@ -104,12 +110,13 @@ def test_compile_cache_dir(tmp_path, env_set):
     in the checkout; unset, they land in the fixed in-checkout directory,
     which git ignores."""
     outside = tmp_path / "cache"
-    in_repo_before = _entries(REPO_COMPILE_CACHE)
-    said = _probe(tmp_path, outside if env_set else None)
-    new_in_repo = _entries(REPO_COMPILE_CACHE, in_repo_before)
+    tag = _tag(tmp_path)
+    in_repo_before = _entries(REPO_COMPILE_CACHE, tag)
+    said = _probe(tag, outside if env_set else None)
+    new_in_repo = _entries(REPO_COMPILE_CACHE, tag, in_repo_before)
     if env_set:
         assert said == str(outside)
-        new = _entries(outside)
+        new = _entries(outside, tag)
         assert new, "nothing was cached in JAX_COMPILATION_CACHE_DIR"
         assert not new_in_repo, "an entry landed in the checkout too"
     else:

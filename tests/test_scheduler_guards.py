@@ -413,7 +413,7 @@ def test_max_chunk_cannot_outgrow_pallas_argmin_guard():
         decompose_range,
     )
 
-    backend, batch, max_k, _sieve, _factored, _hot = auto_tune(
+    backend, batch, max_k, _sieve, _factored = auto_tune(
         "pallas", None, None
     )
     assert batch * 10**max_k <= 2**31 - 1, "pallas defaults overflow argmin"

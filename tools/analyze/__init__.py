@@ -1,6 +1,6 @@
 """Repo-native static-analysis & sanitizer suite (``python -m tools.analyze``).
 
-Nine passes, one exit code:
+Eight passes, one exit code:
 
 - ``lock`` — AST lock-discipline checker (``# guarded-by:`` annotations,
   the ``with``-block rule, the ``_locked``/def-line helper conventions,
@@ -22,9 +22,6 @@ Nine passes, one exit code:
   coroutines / ``# on-loop:`` code, sync locks on the loop, off-thread
   writes bypassing the ``call_soon_threadsafe`` hop.
   tools/analyze/loopcheck.py
-- ``donate`` — JAX donation-safety pass over ops/ + parallel/:
-  use-after-donate, donated calls that don't rebind the carry, mid-job
-  carry materialisation.  tools/analyze/donatecheck.py
 - ``thread`` — thread-lifecycle sanitizer: every ``threading.Thread``
   construction joined on its class's close()/stop()/shutdown() path or
   annotated ``# thread-owner:``.  tools/analyze/threadcheck.py
@@ -38,7 +35,6 @@ from __future__ import annotations
 from .common import Finding, apply_ratchet, load_ratchet, save_ratchet  # noqa: F401
 from . import (  # noqa: F401
     contracts,
-    donatecheck,
     lockcheck,
     loopcheck,
     metriccheck,
@@ -56,6 +52,5 @@ PASSES = {
     "sanitize": sanitcheck.run,
     "metrics": metriccheck.run,
     "loop": loopcheck.run,
-    "donate": donatecheck.run,
     "thread": threadcheck.run,
 }

@@ -30,7 +30,6 @@ from .common import (
     load_ratchet,
     save_ratchet,
 )
-from .donatecheck import DONATE_SCAN_DIRS
 from .tracecheck import TRACE_SCAN_DIRS
 
 DEFAULT_RATCHET = Path(__file__).resolve().parent / "ratchet.json"
@@ -39,7 +38,7 @@ DEFAULT_RATCHET = Path(__file__).resolve().parent / "ratchet.json"
 #: (contracts, sanitize, metrics) are whole-repo cross-checks: metrics
 #: must re-balance emitters against the registry after ANY change, while
 #: contracts/sanitize self-tests only depend on their trigger dirs.
-_PER_FILE_PASSES = frozenset({"lock", "wfq", "trace", "loop", "donate", "thread"})
+_PER_FILE_PASSES = frozenset({"lock", "wfq", "trace", "loop", "thread"})
 _WHOLE_PASS_TRIGGERS = {
     # contracts: workloads are in the trigger set for the registry's
     # golden-vector pass.
@@ -55,8 +54,6 @@ _WHOLE_PASS_TRIGGERS = {
 def _scan_dirs_for(name: str) -> Tuple[str, ...]:
     if name == "trace":
         return TRACE_SCAN_DIRS
-    if name == "donate":
-        return DONATE_SCAN_DIRS
     return DEFAULT_SCAN_DIRS
 
 

@@ -102,7 +102,6 @@ JIT_KERNEL_RE = re.compile(r"jit-kernel\b")
 #: (``# on-loop: _loop`` -> ``self._loop.call_soon_threadsafe``).
 ON_LOOP_RE = re.compile(r"(?<![\w-])on-loop:?\s*([A-Za-z_][A-Za-z0-9_]*)?")
 LOOP_OK_RE = re.compile(r"loop-ok:")
-DONATE_OK_RE = re.compile(r"donate-ok:")
 THREAD_OWNER_RE = re.compile(r"thread-owner:\s*(\S+)")
 
 

@@ -65,19 +65,17 @@ def _name_kind(name: str) -> str:
     if name.startswith(
         (
             "gauge.", "fleet.", "fed.peer_state", "fed.conns_live",
-            "gw.conns_live", "kernel.thresh_staleness",
-            "autoscale.target_workers", "sweep.kernel_build_s",
+            "gw.conns_live", "autoscale.target_workers",
+            "sweep.kernel_build_s",
         )
     ):
         # fed.peer_state[.<peer>] is the per-peer membership gauge family
         # (ISSUE 12) and fed.conns_live the federation transport's
         # live-conn level (ISSUE 18); the rest of fed.* stays
         # counter-kind.  gw.conns_live is the ingress live-conn gauge
-        # (ISSUE 15) — the only gauge-kind name under gw.*.
-        # kernel.thresh_staleness is the hot plane's sieve-threshold lag
-        # level (ISSUE 16) — the one gauge-kind name under kernel.*,
-        # while sweep.* stays counter-kind but for sweep.kernel_build_s,
-        # a stored kernel's first-call seconds.  autoscale.target_workers is
+        # (ISSUE 15) — the only gauge-kind name under gw.*.  sweep.*
+        # stays counter-kind but for sweep.kernel_build_s, a stored
+        # kernel's first-call seconds.  autoscale.target_workers is
         # the controller's worker-target level (ISSUE 18); the other
         # autoscale.* names count actions and stay counters.
         return "gauge"

@@ -10,13 +10,11 @@ inside **kernel bodies** — functions it identifies as jit-traced:
 - passed by name to ``jax.jit(...)`` in the same module,
 - defined (at any nesting depth) inside a kernel factory — a function
   whose name matches ``(make|build).*(kernel|minhash|sieve|factored|
-  hot|call)``, the repo's factory convention (``make_kernel_body``,
+  call|blake2b)``, the repo's factory convention (``make_kernel_body``,
   ``_build_call``, ``_make_sharded_kernel``, the ISSUE 13 sieve
   factories — both of the two-stage sieve's passes live inside these
   bodies on both backends, so the race/contract checks gate them like
-  the old code — the ISSUE 14 factored factories, and the ISSUE 16 hot
-  plane's ``make_hot_step``, whose donated ring-loop step bodies trace
-  like any kernel body),
+  the old code — and the ISSUE 14 factored factories),
 - or explicitly marked with ``# jit-kernel`` on its def line.
 
 Rules (suppress a deliberate line with ``# trace-ok: <reason>``):
@@ -64,9 +62,7 @@ from .common import (
 
 PASS = "trace"
 
-#: Kernel-factory naming convention the lint keys on; ``hot`` (ISSUE 16)
-#: admits the always-hot plane's donated-step factories (make_hot_step),
-#: whose ring-loop step bodies trace like any kernel body; ``blake2b``
+#: Kernel-factory naming convention the lint keys on; ``blake2b``
 #: (ISSUE 20) admits the second kernel family's factories
 #: (``make_blake2b_kernel_body`` / ``_make_blake2b_kernel`` /
 #: ``build_kernel_for`` in ops/blake2b.py and the sharded wrapper in
@@ -75,7 +71,7 @@ PASS = "trace"
 #: ``_compress_pairs``, ...) carry explicit ``# jit-kernel`` marks since
 #: they sit outside any factory.
 FACTORY_RE = re.compile(
-    r"(make|build).*(kernel|minhash|sieve|factored|hot|call|blake2b)"
+    r"(make|build).*(kernel|minhash|sieve|factored|call|blake2b)"
 )
 
 #: Default scan scope in repo mode: the accelerator layers.
