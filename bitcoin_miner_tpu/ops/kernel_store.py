@@ -11,11 +11,20 @@ later process in milliseconds; ``jax.jit(exported.call)`` then lowers in
 holds the same Mosaic kernel (tests/test_chip_compile.py), serialized at the
 forward-compatible Mosaic version that the compiler upgrades as it reads it.
 
+The store serves the single-device dyn kernel (``ops/sweep.py``) and the
+sharded one (``parallel/sweep.py``: the same kernel under ``shard_map``
+and the pmin cascade), the latter only in a single-process mesh: every
+process of a multi-host mesh must enqueue identical collectives, and the
+export is not checked across processes.  An operand whose sharding spans
+several devices is exported with that sharding, so the export records the
+mesh's in-shardings.
+
 The store is the ``kernel_exports/`` subdirectory of the compile cache's
 directory (jax's cache reads and evicts only the ``*-cache`` files at its
 top level), so it is warm exactly when the compile cache is.  A file is
 named by :func:`export_key`, a digest of everything that fixes the lowered
-module: the kernel's parameters, its operands, the jax and jaxlib versions,
+module: the kernel's parameters, its operands (with the mesh shape, axis
+names and partition spec of a sharded one), the jax and jaxlib versions,
 the platform and device, and the source of the modules traced into the
 kernel, so a changed kernel never loads a stale export.  Its content is the
 payload's sha256 followed by the payload: a torn or truncated file reads as
@@ -32,7 +41,7 @@ import threading
 import time
 from functools import lru_cache
 from pathlib import Path
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 from jax import export as jax_export
@@ -42,8 +51,12 @@ from ..utils.platform import compile_cache_dir
 
 SUBDIR = "kernel_exports"
 
-#: The modules whose code is traced into the pallas kernels.
+#: The modules whose code is traced into the pallas kernels, in ``ops/``.
 _SOURCES = ("pallas_sha256.py", "sha256.py")
+
+#: What the sharded kernel traces besides, from the package root: the
+#: ``shard_map`` and the collective cascade around the kernel.
+MESH_SOURCES = ("parallel/sweep.py",)
 
 
 def store_dir() -> Path:
@@ -51,14 +64,18 @@ def store_dir() -> Path:
     return Path(compile_cache_dir()) / SUBDIR
 
 
-@lru_cache(maxsize=1)
-def source_digest() -> str:
-    """sha256 over the source of the modules traced into the kernel."""
+@lru_cache(maxsize=4)
+def source_digest(extra: Tuple[str, ...] = ()) -> str:
+    """sha256 over the source of the modules traced into the kernel:
+    :data:`_SOURCES`, then ``extra`` (paths from the package root)."""
     h = hashlib.sha256()
     here = Path(__file__).resolve().parent
     for name in _SOURCES:
         h.update(name.encode())
         h.update((here / name).read_bytes())
+    for name in extra:
+        h.update(name.encode())
+        h.update((here.parent / name).read_bytes())
     return h.hexdigest()
 
 
@@ -68,17 +85,39 @@ def runtime_versions() -> dict:
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
 
 
-def export_key(params: dict, specs: Sequence) -> str:
+def _spans_devices(sharding) -> bool:
+    return sharding is not None and len(sharding.device_set) > 1
+
+
+def _operand(spec) -> list:
+    """One operand's part of the key: shape and dtype, and for an operand
+    sharded over a mesh (a ``NamedSharding``) the mesh's shape, its axis
+    names and the operand's partition spec."""
+    desc = [list(spec.shape), str(spec.dtype)]
+    sharding = spec.sharding
+    if _spans_devices(sharding):
+        desc.append({
+            "mesh": list(sharding.mesh.devices.shape),
+            "axes": list(sharding.mesh.axis_names),
+            "spec": str(sharding.spec),
+        })
+    return desc
+
+
+def export_key(
+    params: dict, specs: Sequence, sources: Tuple[str, ...] = ()
+) -> str:
     """The store's name for one kernel: a digest of the factory's
-    parameters, the operand shapes and dtypes, the runtime versions, the
-    platform and device kind, and :func:`source_digest`."""
+    parameters, the operands (:func:`_operand`), the runtime versions, the
+    platform and device kind, and :func:`source_digest` over ``sources``
+    besides the kernel's own modules."""
     desc = {
         "params": params,
-        "operands": [[list(s.shape), str(s.dtype)] for s in specs],
+        "operands": [_operand(s) for s in specs],
         "versions": runtime_versions(),
         "platform": jax.default_backend(),
         "device": jax.devices()[0].device_kind,
-        "sources": source_digest(),
+        "sources": source_digest(sources),
     }
     return hashlib.sha256(
         json.dumps(desc, sort_keys=True).encode()
@@ -134,13 +173,17 @@ class StoredKernel:
     ``sweep.kernel_build_s`` is the first call's seconds: the export
     loaded or made, lowered, compiled (or loaded from the persistent
     cache) and enqueued.  One kernel serves one operand signature, which
-    is what the dyn kernel's callers pass.
+    is what the dyn kernel's callers pass.  ``sources`` are the modules
+    traced into ``fn`` besides the kernel's own (:func:`source_digest`).
     """
 
-    def __init__(self, fn, params: dict, directory: Path) -> None:
+    def __init__(
+        self, fn, params: dict, directory: Path, sources: Tuple[str, ...] = ()
+    ) -> None:
         self._fn = fn
         self._params = params
         self._dir = Path(directory)
+        self._sources = sources
         self._lock = threading.Lock()
         self._call = None  # guarded-by: _lock
 
@@ -159,8 +202,17 @@ class StoredKernel:
             return out
 
     def _exported(self, args) -> jax_export.Exported:  # guarded-by: _lock
-        specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
-        path = self._dir / f"{export_key(self._params, specs)}.jaxexport"
+        # A sharded operand is exported with its sharding, so the export
+        # records the mesh's in-shardings; a single-device one without.
+        specs = [
+            jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if _spans_devices(a.sharding) else None,
+            )
+            for a in args
+        ]
+        key = export_key(self._params, specs, self._sources)
+        path = self._dir / f"{key}.jaxexport"
         exp = _read(path)
         if exp is not None:
             METRICS.inc("sweep.kernel_export_hits")
@@ -172,9 +224,10 @@ class StoredKernel:
 
 
 @lru_cache(maxsize=64)
-def stored_kernel(fn, **params) -> StoredKernel:
+def stored_kernel(fn, sources: Tuple[str, ...] = (), **params) -> StoredKernel:
     """The one :class:`StoredKernel` per jitted kernel ``fn`` (itself
     lru_cached by its factory), so it stays the stable class key of the
     sweep drivers' single-flight build locks.  ``params`` are the
-    factory's arguments, for the key."""
-    return StoredKernel(fn, params, store_dir())
+    factory's arguments, for the key; ``sources`` as for
+    :class:`StoredKernel`."""
+    return StoredKernel(fn, params, store_dir(), sources)

@@ -34,6 +34,7 @@ from ..ops.sweep import (
     default_factor_k_in,
     make_kernel_body,
 )
+from ..utils.platform import pallas_platform
 from .mesh import MINER_AXIS, default_mesh
 
 
@@ -288,7 +289,9 @@ def sharded_kernel_for(
     g_dev, g_flat)`` (``*th`` is the one replicated uint32 threshold
     operand when ``sieve=True``, empty otherwise), for the mesh mode of
     ``ops.sweep.SweepPipeline``; dyn-kernel closures carry ``class_key``
-    for the pipeline's single-flight build locks.
+    for the pipeline's single-flight build locks.  Under Mosaic, in a
+    single-process mesh, the dyn kernel is served from its stored export
+    (``ops/kernel_store.py``), as on one device.
 
     ``factored`` threads the outer/inner digit split into the xla
     branch (classes with ``k >= 2``; a 1-digit lane axis has nothing to
@@ -336,6 +339,31 @@ def sharded_kernel_for(
                 interpret,
                 sieve=sieve,
             )
+            if (
+                not interpret
+                and pallas_platform() == "mosaic"
+                and jax.process_count() == 1
+            ):
+                # A fresh process loads the kernel's stored export instead
+                # of tracing and lowering it again (ops/kernel_store.py).
+                # Not across processes: each must enqueue the collectives
+                # of one program, and that is unchecked for an export.
+                from ..ops.kernel_store import MESH_SOURCES, stored_kernel
+
+                fn = stored_kernel(
+                    fn,
+                    sources=MESH_SOURCES,
+                    n_tail_blocks=layout.n_tail_blocks,
+                    w_lo=w_lo,
+                    w_hi=w_hi,
+                    k=group.k,
+                    per_dev_batch=batch_per_device,
+                    sieve=sieve,
+                    n_devices=mesh.size,
+                    mesh_shape=tuple(mesh.devices.shape),
+                    axis_names=tuple(mesh.axis_names),
+                    axis_name=axis_name,
+                )
             contribs = _mesh_contribs(
                 group.k, low_pos, w_lo, w_hi, n_pad, mesh
             )

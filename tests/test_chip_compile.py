@@ -142,7 +142,9 @@ def test_stored_dyn_kernel_export_compiles_for_v5e(topo):
     assert kernels == _mosaic_kernels(traced)
 
 
-def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):
+def _sharded_dyn(topo):
+    """The production sharded dyn kernel and its operands, placed as
+    ``parallel.sweep.shard_operands`` places them, on the described mesh."""
     n_dev = len(topo.devices)
     assert n_dev == 4
     batch, sieve, group, layout, w_lo, w_hi = _production_shape(n_dev)
@@ -157,7 +159,7 @@ def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):
     rep2 = NamedSharding(mesh, P(None, None))
     nw = len(layout.tail_template)
     rows = n_dev * batch
-    compiled = kern.lower(
+    specs = [
         jax.ShapeDtypeStruct((8,), jnp.uint32, sharding=rep),
         jax.ShapeDtypeStruct((rows, nw), jnp.uint32, sharding=row),
         jax.ShapeDtypeStruct((rows, 2), jnp.int32, sharding=row),
@@ -166,7 +168,33 @@ def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):
             jax.ShapeDtypeStruct((n_pad // 128, 128), jnp.uint32, sharding=rep2)
             for _ in range(w_hi - w_lo + 1)
         ),
-    ).compile()
-    txt = compiled.as_text()
+    ]
+    return kern, specs
+
+
+def test_sharded_dyn_kernel_compiles_for_v5e_mesh(topo):
+    kern, specs = _sharded_dyn(topo)
+    txt = kern.lower(*specs).compile().as_text()
     assert "all-reduce" in txt, "the collective min did not become a collective"
     assert "tpu_custom_call" in txt
+
+
+def test_stored_sharded_dyn_kernel_export_compiles_for_v5e_mesh(topo):
+    """What a fresh four-chip miner runs on a store hit: the production
+    sharded dyn kernel exported for the TPU with its operands' shardings,
+    serialized, deserialized, and its ``call`` compiled for the mesh.  It
+    keeps the collective cascade and holds the same Mosaic kernel as the
+    sharded kernel traced in the process."""
+    from jax import export
+
+    kern, specs = _sharded_dyn(topo)
+    blob = export.export(kern, platforms=["tpu"])(*specs).serialize()
+    exp = export.deserialize(blob)
+    assert exp.platforms == ("tpu",)
+    assert exp.nr_devices == 4
+    stored = jax.jit(exp.call).lower(*specs).compile().as_text()
+    traced = kern.lower(*specs).compile().as_text()
+    assert "all-reduce" in stored, "the stored kernel lost the collective min"
+    kernels = _mosaic_kernels(stored)
+    assert kernels, "no tpu_custom_call in the stored kernel's executable"
+    assert kernels == _mosaic_kernels(traced)
