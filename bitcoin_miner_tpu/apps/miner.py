@@ -999,7 +999,8 @@ def main(argv=None) -> int:
         print(
             f"miner: {swept} nonces swept ({swept / dt:,.0f}/s lifetime); "
             f"lanes on device {METRICS.get('sweep.device_lanes')}, "
-            f"host fold {METRICS.get('sweep.host_fold_lanes')}; kernel "
+            f"host fold {METRICS.get('sweep.host_fold_lanes')} in "
+            f"{METRICS.get('sweep.host_fold_s'):.3f} s; kernel "
             f"export hits {METRICS.get('sweep.kernel_export_hits')}, "
             f"misses {METRICS.get('sweep.kernel_export_misses')}, build "
             f"{METRICS.gauge('sweep.kernel_build_s'):.3f} s",

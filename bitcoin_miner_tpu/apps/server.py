@@ -1021,6 +1021,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             if server is not None:
                 server.close()
             return 0
+    _arm_exit_line()
     # Self-scaling capacity plane (ISSUE 18): the controller reads the
     # hub's burn verdicts and the fleet.utilization gauge each beat and
     # actuates miner worker subprocesses against the live serving port
@@ -1102,6 +1103,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 telemetry=hub, lock=ev_lock,
             )
     finally:
+        _print_exit_line()
         if pump is not None:
             pump.stop()
         if workers is not None:
@@ -1111,6 +1113,36 @@ def main(argv: Optional[List[str]] = None) -> int:
         if server is not None:
             server.close()
     return 0
+
+
+def _print_exit_line() -> None:
+    """The server's last stderr line: the scheduler's and the LSP
+    transport's lifetime counters."""
+    print(
+        f"server: jobs completed {METRICS.get('sched.jobs_completed')}; lsp "
+        f"connects rebound {METRICS.get('lsp.connects_rebound')}, deduped "
+        f"{METRICS.get('lsp.connects_deduped')}, retransmits "
+        f"{METRICS.get('lsp.retransmits')}",
+        file=sys.stderr, flush=True,
+    )
+
+
+def _arm_exit_line() -> None:
+    """SIGTERM prints the exit line, then ends the process as SIGTERM
+    always has: at once, with no drain."""
+    import signal
+
+    def on_term(_sig, _frame) -> None:
+        try:
+            _print_exit_line()
+        finally:
+            signal.signal(signal.SIGTERM, signal.SIG_DFL)
+            os.kill(os.getpid(), signal.SIGTERM)
+
+    try:
+        signal.signal(signal.SIGTERM, on_term)
+    except ValueError:
+        pass  # not the main thread (tests drive main() elsewhere)
 
 
 if __name__ == "__main__":

@@ -7,6 +7,26 @@ the reference quirks (SURVEY §8): per-conn epoch timers instead of one
 shared ticker, a complete close/drain path, duplicate-Connect dedupe by
 remote address, and loss errors that carry the dead conn_id.
 
+Connection ids keep two connections from one address apart.  LSP has no
+close message, so a finished client's connection lingers on the server
+for EpochLimit epochs, and a new client may be handed the same UDP port
+meanwhile.  The server tells the two Connects apart by what it has heard:
+a Connect from an address whose connection has already heard a message
+carrying its id (the client learned its id and went) is a new client and
+gets a new connection; one whose connection has heard nothing with its id
+is the retry of a lost connect-ack and is re-acked.  On a network that
+keeps one sender's datagrams in order, a retry never follows a message
+with the id, so the rule cannot split a live client.  Where the network
+reorders, it can: a connect-ack late by more than an epoch draws a
+retried Connect, and if that retry arrives after the client's first
+Data, the live connection is lost on both ends, as a partition would
+lose it (``tests/test_lsp6_rebind.py`` shows it).  A Connect carries
+nothing that would tell the two apart, and deduping by address alone
+hands a returning port a dead connection and drops its Request, which
+one-connection-per-job clients meet often; the rule trades that for the
+rarer reordered retry.  The client, in turn, reads only messages that
+carry its own id.
+
 Sync facades with the frozen Go-style blocking API live in sync.py.
 """
 
@@ -17,6 +37,8 @@ import socket
 from typing import Dict, Optional, Tuple
 
 from .. import lspnet
+from ..utils import trace
+from ..utils.metrics import METRICS
 from .conn import ConnCore
 from .errors import (
     CannotEstablishConnectionError,
@@ -76,10 +98,20 @@ class AsyncClient:
         connect_wire = Message.connect()
         self._endpoint.send(connect_wire.marshal())
         epochs = 0
+        # Ids of connections this port had before: until the handshake the
+        # server holds no data for this client, so a Data, or an Ack of a
+        # data seq, names an older connection from the same address.  Ids
+        # are minted from a counter and never reused, so the true
+        # connect-ack never names one of them.
+        stale = set()
+        loop = asyncio.get_running_loop()
+        # Epochs tick on the clock, not on silence: a stream of ignored
+        # datagrams must not hold back the Connect's resend.
+        epoch_end = loop.time() + params.epoch_seconds
         while True:
             try:
                 data, addr = await asyncio.wait_for(
-                    endpoint.recv(), timeout=params.epoch_seconds
+                    endpoint.recv(), timeout=max(0.0, epoch_end - loop.time())
                 )
             except asyncio.TimeoutError:
                 epochs += 1
@@ -87,17 +119,21 @@ class AsyncClient:
                     endpoint.close()
                     raise CannotEstablishConnectionError()
                 self._endpoint.send(connect_wire.marshal())
+                epoch_end = loop.time() + params.epoch_seconds
                 continue
             if addr[:2] != self._peer:
                 continue
             msg = _decode(data)
-            if msg is not None and msg.type == MsgType.ACK and msg.seq_num == 0:
+            if msg is None or msg.type == MsgType.CONNECT:
+                continue
+            if msg.type == MsgType.DATA or msg.seq_num != 0:
+                stale.add(msg.conn_id)
+            elif msg.conn_id not in stale:
                 conn = ConnCore(
                     msg.conn_id, params, self._send_msg, self._read_q.put_nowait
                 )
                 self._conn = conn
                 break
-            # anything else pre-handshake: ignore
         self._tasks = [
             asyncio.ensure_future(self._reader_loop()),
             asyncio.ensure_future(self._epoch_loop()),
@@ -163,8 +199,8 @@ class AsyncClient:
                 if addr[:2] != self._peer:
                     continue  # not our server: ignore strays/spoofs
                 msg = _decode(data)
-                if msg is None:
-                    continue
+                if msg is None or msg.conn_id != conn.conn_id:
+                    continue  # another connection's: not a sign of life
                 conn.heard_from_peer()
                 if msg.type == MsgType.DATA:
                     conn.on_data(msg)
@@ -202,6 +238,9 @@ class _ServerConn:
         self.addr = addr
         self.epoch_task: Optional[asyncio.Task] = None
         self.server_initiated_close = False
+        # The client has sent a message carrying this conn's id, so it
+        # knows the id: a later Connect from ``addr`` is a new client.
+        self.heard_id = False
 
 
 class AsyncServer:
@@ -314,9 +353,44 @@ class AsyncServer:
         if sc.epoch_task:
             sc.epoch_task.cancel()
         self._conns.pop(sc.core.conn_id, None)
-        self._by_addr.pop(sc.addr, None)
+        if self._by_addr.get(sc.addr) == sc.core.conn_id:
+            del self._by_addr[sc.addr]
         if self._closing and not self._conns:
             self._drained.set()
+
+    def _lose_conn(self, sc: _ServerConn) -> None:
+        """Declare a connection lost: the application reads
+        ``ConnLostError`` for it, unless it had closed it itself."""
+        sc.core.lost = True
+        if not sc.server_initiated_close:
+            self._read_q.put_nowait(ConnLostError(sc.core.conn_id))
+        self._finish_conn(sc)
+
+    def _on_connect(self, addr: Addr) -> None:
+        """Ack a Connect: a new connection, or the one ``addr`` holds when
+        this Connect is its retry (see the module docstring)."""
+        cid = self._by_addr.get(addr)
+        sc = self._conns[cid] if cid is not None else None
+        if sc is not None and not sc.heard_id:
+            METRICS.inc("lsp.connects_deduped")
+        else:
+            if self._closing:
+                return  # draining: refuse new connections
+            old = sc
+            sc = self._new_conn(addr)
+            if old is not None:
+                # The old client learned its id and went; its connection
+                # would linger until EpochLimit silent epochs.
+                METRICS.inc("lsp.connects_rebound")
+                if trace.enabled():
+                    trace.emit(
+                        None, "lsp", "connect_rebound",
+                        old=old.core.conn_id, new=sc.core.conn_id,
+                        host=addr[0], port=addr[1],
+                    )
+                self._lose_conn(old)
+        sc.core.heard_from_peer()
+        self._endpoint.send(Message.ack(sc.core.conn_id, 0).marshal(), addr)
 
     def _new_conn(self, addr: Addr) -> _ServerConn:
         conn_id = self._next_id
@@ -341,24 +415,12 @@ class AsyncServer:
                 if msg is None:
                     continue
                 if msg.type == MsgType.CONNECT:
-                    # Dedupe retried Connects by remote address: re-ack the
-                    # existing conn instead of minting a duplicate (fixes a
-                    # reference quirk; required for slow-start, lsp3).
-                    cid = self._by_addr.get(addr)
-                    if cid is None:
-                        if self._closing:
-                            continue  # draining: refuse new connections
-                        sc = self._new_conn(addr)
-                    else:
-                        sc = self._conns[cid]
-                    sc.core.heard_from_peer()
-                    self._endpoint.send(
-                        Message.ack(sc.core.conn_id, 0).marshal(), addr
-                    )
+                    self._on_connect(addr)
                     continue
                 sc = self._conns.get(msg.conn_id)
                 if sc is None or sc.addr != addr:
                     continue
+                sc.heard_id = True
                 sc.core.heard_from_peer()
                 if msg.type == MsgType.DATA:
                     sc.core.on_data(msg)
@@ -376,9 +438,7 @@ class AsyncServer:
             while True:
                 await asyncio.sleep(self._params.epoch_seconds)
                 if sc.core.on_epoch():  # lost
-                    if not sc.server_initiated_close:
-                        self._read_q.put_nowait(ConnLostError(sc.core.conn_id))
-                    self._finish_conn(sc)
+                    self._lose_conn(sc)
                     return
         except asyncio.CancelledError:
             pass

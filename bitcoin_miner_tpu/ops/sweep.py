@@ -700,20 +700,33 @@ def _host_min(data: str, lo: int, hi: int) -> Tuple[int, int]:
     return min_hash_range(data, lo, hi)
 
 
-def auto_host_lane_budget(native_ok: bool = True) -> int:
+def auto_host_lane_budget(
+    native_ok: bool = True, dyn_k: Optional[int] = None
+) -> int:
     """Largest digit-class size worth computing on the host instead of
-    compiling a device kernel for: ~0.1 s of host work either way.
-    ``native_ok=False`` (non-default workloads, whose host tier is the
-    hashlib-speed oracle) keeps the budget at the pure-Python level."""
+    compiling a device kernel for.  With the native fold that is 1e7
+    (d <= 7).  ``dyn_k`` is the k of a digit-position-dynamic kernel
+    (the unfactored pallas tier's), which already serves every class
+    d > ``dyn_k`` with no compile: the host then keeps only d <=
+    ``dyn_k`` (1e6 at the pallas tier's k = 6).  On a TPU v5e host a
+    1e7-nonce fold took 0.067-0.085 s of ``sweep.host_fold_s`` (13
+    cores, on the pipeline's dispatch thread), as long as the chip takes
+    for ~1.4e8 nonces, so jobs of a few 1e8 nonces were paced by the
+    host and by how busy its cores were.  ``native_ok=False``
+    (non-default workloads, whose host tier is the hashlib-speed oracle)
+    keeps the budget at the pure-Python level."""
+    budget = 10**5
     if native_ok:
         try:
             from .. import native
 
             if native.available():
-                return 10**7
+                budget = 10**7
         except Exception:
             pass
-    return 10**5
+    if dyn_k is not None:
+        budget = min(budget, 10**dyn_k)
+    return budget
 
 
 def run_sweep_dispatches(
@@ -768,11 +781,19 @@ def run_sweep_dispatches(
         if 10**group.d <= host_lane_budget:
             g_lo = group.chunks[0].base + group.chunks[0].lo_off
             g_hi = group.chunks[-1].base + group.chunks[-1].hi_off - 1
+            t_fold = _time.monotonic()
             h, n = host_min(data, g_lo, g_hi)
+            dt_fold = _time.monotonic() - t_fold
             pending.append((HostFold(h, n), None, None))
             n_host = sum(c.hi_off - c.lo_off for c in group.chunks)
             lanes += n_host
             METRICS.inc("sweep.host_fold_lanes", n_host)
+            METRICS.inc("sweep.host_fold_s", dt_fold)
+            if _trace.enabled():
+                _trace.emit(
+                    None, "miner", "host_fold",
+                    d=group.d, lanes=n_host, seconds=round(dt_fold, 6),
+                )
             continue
         layout = _layout_cache(data_bytes, group.d, sep, family)
         kern = get_kernel(layout, group)
@@ -1086,8 +1107,10 @@ class SweepPipeline:
         self._per_dev_batch = self._batch
         # None = auto: this is the miner's production path, where a tiny
         # digit class must never cost a Mosaic compile (see HostFold).
+        dyn = self._backend == "pallas" and not self._factored
         self._host_lane_budget = (
-            auto_host_lane_budget(native_ok) if host_lane_budget is None
+            auto_host_lane_budget(native_ok, self._max_k if dyn else None)
+            if host_lane_budget is None
             else host_lane_budget
         )
         if mesh is not None:

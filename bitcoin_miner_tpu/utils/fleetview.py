@@ -140,11 +140,12 @@ class FleetView:
             )
             gauges_per = [dict(self._sources[n].gauges) for n in pool_sorted]
             hists_per = [dict(self._sources[n].hist_states) for n in pool]
-        counters: Dict[str, int] = {}
+        counters: Dict[str, float] = {}
         for per in counters_per:
             for k, v in per.items():
                 if isinstance(v, (int, float)):
-                    counters[k] = counters.get(k, 0) + int(v)
+                    # A float counter (seconds) keeps its fraction.
+                    counters[k] = counters.get(k, 0) + v
         gauges: Dict[str, float] = {}
         for per in gauges_per:
             for k, v in per.items():

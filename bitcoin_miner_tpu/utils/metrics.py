@@ -301,6 +301,8 @@ def format_quantiles(h) -> str:
 #:   lsp.delivered         in-order payloads handed to the application
 #:   lsp.dropped_bad_size  datagrams rejected by Size validation
 #:   lsp.dropped_horizon   datagrams beyond the reorder horizon (DoS guard)
+#:   lsp.connects_rebound  Connects from the address of a lingering conn whose client knew its id: a new conn
+#:   lsp.connects_deduped  Connects re-acked with the address's conn (a lost connect-ack's retry)
 #:   sched.chunks_assigned     chunks handed to miners
 #:   sched.chunks_reassigned   chunks returned by dead miners
 #:   sched.chunks_straggler_requeued  chunks reclaimed from hung miners
@@ -369,6 +371,7 @@ def format_quantiles(h) -> str:
 #:   miner.tier_downgrades     kernel tiers abandoned by the sweep watchdog
 #:   sweep.device_lanes        nonces swept by a device kernel dispatch
 #:   sweep.host_fold_lanes     nonces of tiny digit classes min-folded on the host
+#:   sweep.host_fold_s         wall seconds of those host folds (a float counter)
 #:   sweep.kernel_export_hits  pallas dyn kernels loaded from a stored export (no trace)
 #:   sweep.kernel_export_misses  pallas dyn kernels traced, exported and stored
 #:   sweep.kernel_build_s      seconds of a stored kernel's first call (gauge; the latest)

@@ -9,6 +9,7 @@ EpochMillis (lsp/lsp2_test.go:123-127 ``setMaxEpochs`` pattern).
 
 from __future__ import annotations
 
+import asyncio
 import random
 import threading
 from dataclasses import dataclass, field
@@ -125,6 +126,23 @@ class TestSystem:
         except lsp.LspError:
             pass
         assert not self.errors, self.errors
+
+
+def endpoint_on_port(port: Callable[[], int]):
+    """A stand-in for ``lspnet.create_client_endpoint`` that binds the
+    loopback port ``port()`` gives (0: any), as a client's OS may hand a
+    new client the port another has just closed."""
+    from bitcoin_miner_tpu.lspnet.udp import UDPEndpoint, _QueueProtocol
+
+    async def create(host, remote_port, label=None):
+        loop = asyncio.get_running_loop()
+        transport, protocol = await loop.create_datagram_endpoint(
+            _QueueProtocol, local_addr=("127.0.0.1", port())
+        )
+        return UDPEndpoint(transport, protocol, is_server=False,
+                           remote=(host, remote_port), label=label)
+
+    return create
 
 
 def spawn(fn: Callable[[], None]) -> threading.Thread:

@@ -208,6 +208,73 @@ def test_xla_backend_fleet():
         sys_.close()
 
 
+def test_many_one_shot_clients_on_reused_ports(monkeypatch):
+    """The CMU client's many-user shape on the served path: 8 concurrent
+    users, each job on a fresh connection, ranges that cross the miner's
+    host/device split (d = 7 folded on the host, d = 8 on the xla tier),
+    and half the users' second job bound to the local port their first
+    job had just closed, while the server's old connection lingers."""
+    from bitcoin_miner_tpu.ops.sweep import auto_host_lane_budget
+    from lsp_harness import endpoint_on_port
+
+    assert auto_host_lane_budget() == 10**7  # xla: d <= 7 on the host
+    connect_lock = threading.Lock()
+    want_port = [0]
+    monkeypatch.setattr(
+        lspnet, "create_client_endpoint", endpoint_on_port(lambda: want_port[0]))
+    from bitcoin_miner_tpu.utils.metrics import METRICS
+
+    rebound0 = METRICS.get("lsp.connects_rebound")
+    fold0 = METRICS.get("sweep.host_fold_lanes"), METRICS.get("sweep.host_fold_s")
+    sys_ = MiningSystem(n_miners=0, min_chunk=2000)
+    sys_.add_miner(miner_mod.make_async_search("xla"))
+    answers, errors = {}, []
+
+    def job(c, j, port):
+        data = f"u{c}j{j}x"
+        lo = 9_990_000 + 1_000 * c
+        hi = 10_000_000 + 9_000 + 2_000 * c + 500 * j
+        with connect_lock:
+            want_port[0] = port
+            try:
+                client = lsp.Client("127.0.0.1", sys_.port, PARAMS)
+            finally:
+                want_port[0] = 0
+        used = client._c._endpoint.local_addr[1]
+        try:
+            client.write(Message.request(data, lo, hi).marshal())
+            msg = Message.unmarshal(client.read(timeout=90))
+            answers[(data, lo, hi)] = (msg.hash, msg.nonce)
+        finally:
+            client.close()
+        return used
+
+    def user(c):
+        try:
+            port = job(c, 0, 0)
+            job(c, 1, port if c % 2 == 0 else 0)
+        except Exception as e:  # reported below, with the user
+            errors.append((c, repr(e)))
+
+    try:
+        threads = [threading.Thread(target=user, args=(c,)) for c in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=200)
+            assert not t.is_alive(), "a user timed out"
+        assert not errors, errors
+        assert len(answers) == 16
+        # Each reused port met its old connection still lingering.
+        assert METRICS.get("lsp.connects_rebound") - rebound0 == 4
+        assert METRICS.get("sweep.host_fold_lanes") > fold0[0]
+        assert METRICS.get("sweep.host_fold_s") > fold0[1]
+        for (data, lo, hi), got in answers.items():
+            assert got == min_hash_range(data, lo, hi), (data, lo, hi)
+    finally:
+        sys_.close()
+
+
 def test_multichip_mesh_miner_fleet():
     """One miner process spanning the full 8-device virtual mesh via
     --devices (shard_map + pmin cascade), serving a real fleet job — the

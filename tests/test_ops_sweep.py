@@ -406,6 +406,57 @@ class TestHostRouting:
             p.close()
 
 
+    @pytest.mark.parametrize(
+        "dyn_k,budget", [(6, 10**6), (2, 10**2), (8, 10**7), (None, 10**7)]
+    )
+    def test_auto_budget_leaves_dyn_classes_to_the_device(self, dyn_k, budget):
+        # A dyn kernel serves every class d > dyn_k with no compile, so
+        # only d <= dyn_k stays on the host.
+        from bitcoin_miner_tpu import native
+        from bitcoin_miner_tpu.ops.sweep import auto_host_lane_budget
+
+        if not native.available():
+            pytest.skip("native fold not built")
+        assert auto_host_lane_budget(True, dyn_k) == budget
+        assert auto_host_lane_budget(False, dyn_k) == min(budget, 10**5)
+
+    @pytest.mark.parametrize(
+        "kw,budget",
+        [(dict(backend="pallas", interpret=True, max_k=2), 10**2),
+         (dict(backend="pallas", interpret=True, max_k=2, factored=True),
+          10**7),
+         (dict(backend="xla", max_k=2), 10**7)],
+        ids=["pallas-dyn", "pallas-factored", "xla"],
+    )
+    def test_pipeline_auto_budget_by_tier(self, kw, budget):
+        from bitcoin_miner_tpu import native
+        from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+
+        if not native.available():
+            pytest.skip("native fold not built")
+        p = SweepPipeline(batch=2, **kw)
+        try:
+            assert p._host_lane_budget == budget
+        finally:
+            p.close()
+
+    def test_pallas_pipeline_sweeps_d_above_max_k_on_the_device(self):
+        from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+        from bitcoin_miner_tpu.utils.metrics import METRICS
+
+        p = SweepPipeline(backend="pallas", interpret=True, max_k=2, batch=2)
+        try:
+            assert p._host_lane_budget == 10**2
+            host0 = METRICS.get("sweep.host_fold_lanes")
+            dev0 = METRICS.get("sweep.device_lanes")
+            r = p.submit("cmu440", 5, 130).result(timeout=300)
+            assert (r.hash, r.nonce) == min_hash_range("cmu440", 5, 130)
+            assert METRICS.get("sweep.host_fold_lanes") - host0 == 100 - 5
+            assert METRICS.get("sweep.device_lanes") - dev0 == 130 - 100 + 1
+        finally:
+            p.close()
+
+
 class TestDynKernel:
     """The digit-position-dynamic Pallas kernel: one executable serves all
     digit classes of a data length (contributions are runtime inputs)."""

@@ -702,3 +702,23 @@ def test_sync_sweep_leaves_no_pipeline_thread(form):
         if t.name in names and t not in before and t.is_alive()
     ]
     assert not left, left
+
+
+def test_mesh_pallas_pipeline_sweeps_d_above_max_k_on_the_mesh():
+    # The auto host budget on the pallas tier keeps only d <= max_k on
+    # the host; the next class goes through the sharded dyn kernel.
+    from bitcoin_miner_tpu.ops.sweep import SweepPipeline
+    from bitcoin_miner_tpu.utils.metrics import METRICS
+
+    p = SweepPipeline(
+        backend="pallas", interpret=True, mesh=default_mesh(4), max_k=2,
+        batch=2,
+    )
+    try:
+        assert p._host_lane_budget == 10**2
+        dev0 = METRICS.get("sweep.device_lanes")
+        r = p.submit("cmu440", 5, 1234).result(timeout=300)
+    finally:
+        p.close()
+    assert (r.hash, r.nonce) == min_hash_range("cmu440", 5, 1234)
+    assert METRICS.get("sweep.device_lanes") - dev0 == 1234 - 100 + 1
