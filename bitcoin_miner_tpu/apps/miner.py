@@ -1003,7 +1003,9 @@ def main(argv=None) -> int:
             f"{METRICS.get('sweep.host_fold_s'):.3f} s; kernel "
             f"export hits {METRICS.get('sweep.kernel_export_hits')}, "
             f"misses {METRICS.get('sweep.kernel_export_misses')}, build "
-            f"{METRICS.gauge('sweep.kernel_build_s'):.3f} s",
+            f"{METRICS.gauge('sweep.kernel_build_s'):.3f} s; grid groups "
+            f"{METRICS.get('sweep.grid_groups')}, skipped "
+            f"{METRICS.get('sweep.grid_groups_skipped')}",
             file=sys.stderr, flush=True,
         )
     return 0

@@ -126,7 +126,9 @@ def _build_call(
     fraction; steady state pays pass 1 only (see tools/roofline.py for
     the per-pass op accounting).
 
-    Returns ``(call, n_pad)``.
+    Returns ``(call, n_pad, groups)``: ``call(n_groups, *operands)``
+    runs the grid over ``n_groups`` of the ``groups = batch // cpb`` row
+    groups.
     """
     n_lanes = 10**k
     if batch * n_lanes > I32_MAX:
@@ -148,16 +150,7 @@ def _build_call(
     n_words = n_tail_blocks * 16
 
     row_w = n_words + 2  # words per chunk row: template + lo_off + hi_off
-    if cpb is None:
-        # Largest divisor of batch up to the tuned default — so small
-        # batches (tests, probes) still exercise the group-fold path.
-        cpb = next(
-            c for c in range(min(DEFAULT_CPB, batch), 0, -1) if batch % c == 0
-        )
-    elif cpb < 1 or batch % cpb:
-        # An explicitly requested non-divisor would silently measure
-        # something else; refuse (matches the argmin-guard style above).
-        raise ValueError(f"cpb ({cpb}) must divide batch ({batch})")
+    cpb = resolve_cpb(batch, cpb)
     groups = batch // cpb
 
     def kernel(midstate_ref, tailc_ref, *rest):
@@ -200,9 +193,10 @@ def _build_call(
                 th_ref[0] = thresh_ref[0]
 
         # Padding rows of a partial super-batch carry bounds (0, 0): a
-        # fully-padded group skips all vector work with one scalar branch;
-        # a mixed group wastes at most cpb-1 masked compressions, and at
-        # most one group per dispatch is mixed.
+        # fully-padded group (one a grid of ``n_groups`` still reaches)
+        # skips all vector work with one scalar branch; a mixed group
+        # wastes at most cpb-1 masked compressions, and at most one group
+        # per dispatch is mixed.
         any_work = his[0] > los[0]
         for j in range(1, cpb):
             any_work = any_work | (his[j] > los[j])
@@ -339,8 +333,10 @@ def _build_call(
                     th_ref[0] = jnp.minimum(th_ref[0], jnp.min(a0_ref[...]))
 
         # Last program: one cross-lane lexicographic argmin over the
-        # accumulator tile -> the three SMEM output scalars.
-        @pl.when((g == groups - 1) & (t == n_tiles - 1))
+        # accumulator tile -> the three SMEM output scalars.  The row-group
+        # extent may be a runtime operand (see ``call`` below), so the last
+        # group is the grid's, not ``groups - 1``.
+        @pl.when((g == pl.num_programs(0) - 1) & (t == n_tiles - 1))
         def _final():
             v0 = a0_ref[...]
             v1 = a1_ref[...]
@@ -354,7 +350,6 @@ def _build_call(
             h1_ref[0] = m1
             idx_ref[0] = mi
 
-    grid = (groups, n_tiles)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),  # midstate (8,)
         pl.BlockSpec(memory_space=pltpu.SMEM),  # tail_const+bounds, flat (B*(nw+2),)
@@ -378,16 +373,40 @@ def _build_call(
         # grid like the accumulators (SMEM — it is one scalar).
         scratch.append(pltpu.SMEM((1,), jnp.int32))
 
-    call = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )
-    return call, n_pad
+    def call(n_groups, *operands):
+        """Run the grid over the first ``n_groups`` row groups: a static
+        int, or a traced int32 scalar in ``[1, groups]``.  Rows fill the
+        chunk table from slot 0, so the groups past a dispatch's last row
+        hold none, and a grid that stops there skips their per-step cost
+        (~0.33 us a grid step on a v5e, ~81 us an empty group) as well as
+        their vector work."""
+        return pl.pallas_call(
+            kernel,
+            grid=(n_groups, n_tiles),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(*operands)
+
+    return call, n_pad, groups
+
+
+def resolve_cpb(batch: int, cpb: Optional[int] = None) -> int:
+    """Chunk rows per grid program for a ``batch``-slot dispatch: ``cpb``
+    when it divides ``batch``, else a ValueError; None gives the largest
+    divisor of ``batch`` up to ``DEFAULT_CPB``, so small batches (tests,
+    probes) still exercise the group-fold path."""
+    if cpb is None:
+        return next(
+            c for c in range(min(DEFAULT_CPB, batch), 0, -1) if batch % c == 0
+        )
+    if cpb < 1 or batch % cpb:
+        # An explicitly requested non-divisor would silently measure
+        # something else; refuse.
+        raise ValueError(f"cpb ({cpb}) must divide batch ({batch})")
+    return cpb
 
 
 def _unflip(h0b, h1b, idx):
@@ -423,7 +442,7 @@ def make_pallas_minhash(
     means "no lane survived the threshold" — the host keeps its best.
     """
     cwords = _contrib_words(low_pos)
-    call, n_pad = _build_call(
+    call, n_pad, groups = _build_call(
         n_tail_blocks, cwords, k, batch, tile, interpret, cpb, sieve=sieve
     )
 
@@ -435,7 +454,10 @@ def make_pallas_minhash(
                 jnp.asarray(c) for c in _digit_contrib_np(k, low_pos, n_pad)
             )
             return _unflip(
-                *call(midstate, tailc_bounds.reshape(-1), thresh, *contribs)
+                *call(
+                    groups, midstate, tailc_bounds.reshape(-1), thresh,
+                    *contribs,
+                )
             )
 
         return minhash
@@ -445,7 +467,9 @@ def make_pallas_minhash(
         contribs = tuple(
             jnp.asarray(c) for c in _digit_contrib_np(k, low_pos, n_pad)
         )
-        return _unflip(*call(midstate, tailc_bounds.reshape(-1), *contribs))
+        return _unflip(
+            *call(groups, midstate, tailc_bounds.reshape(-1), *contribs)
+        )
 
     return minhash
 
@@ -505,30 +529,32 @@ def make_pallas_minhash_dyn(
     tile) even when the class leaves them constant, so some const-only
     schedule chains move from the scalar unit to the VPU.
 
-    Returned fn: ``(midstate, tailc_bounds, *contribs)`` ->
+    Returned fn: ``(midstate, tailc_bounds, n_groups, *contribs)`` ->
     ``(min_h0, min_h1, flat_idx)``; contribs must have length
     ``w_hi - w_lo + 1`` (see :func:`window_contribs_np`).  With
-    ``sieve=True`` the fn takes ``(midstate, tailc_bounds, thresh,
-    *contribs)`` — the two-stage variant of :func:`_build_call`.
+    ``sieve=True`` the fn takes ``(midstate, tailc_bounds, n_groups,
+    thresh, *contribs)`` — the two-stage variant of :func:`_build_call`.
+
+    ``n_groups`` (an int32 scalar) is the grid's row-group extent: the
+    dispatch's rows, which fill slots from 0, rounded up to whole groups
+    of ``cpb`` (:func:`resolve_cpb`).  A dispatch of 9 rows steps through
+    2 groups instead of ``batch // cpb``; it is clamped to ``[1, batch //
+    cpb]``, so the grid never reads past the chunk table.
     """
     cwords = tuple(range(w_lo, w_hi + 1))
-    call, n_pad = _build_call(
+    call, n_pad, groups = _build_call(
         n_tail_blocks, cwords, k, batch, tile, interpret, cpb, sieve=sieve
     )
 
-    if sieve:
-
-        @jax.jit
-        def minhash(midstate, tailc_bounds, thresh, *contribs):
-            return _unflip(
-                *call(midstate, tailc_bounds.reshape(-1), thresh, *contribs)
-            )
-
-        return minhash, n_pad
-
+    # *rest is (thresh, *contribs) with the sieve, else contribs alone.
     @jax.jit
-    def minhash(midstate, tailc_bounds, *contribs):
-        return _unflip(*call(midstate, tailc_bounds.reshape(-1), *contribs))
+    def minhash(midstate, tailc_bounds, n_groups, *rest):
+        return _unflip(
+            *call(
+                jnp.clip(n_groups, 1, groups), midstate,
+                tailc_bounds.reshape(-1), *rest,
+            )
+        )
 
     return minhash, n_pad
 

@@ -569,8 +569,10 @@ def auto_tune(
     device gets ``ceil(1024 / n_devices)`` rounded up to a multiple of
     ``DEFAULT_CPB``: 1024 on one chip, 256 on four.  A scheduler chunk of
     ~1000 rows then fills ~98% of a mesh dispatch's row groups instead of
-    ~24%; an empty group still pays the grid's per-step cost (on one v5e,
-    250 rows took 136.6 ms a dispatch at 1024 slots, 128.9 ms at 256).  An
+    ~24%; an empty group in a fixed grid paid the per-step cost (on one
+    v5e, 250 rows took 136.6 ms a dispatch at 1024 slots, 128.9 ms at
+    256), and the dyn kernel's grid stops at the fullest device's last
+    row group (:func:`_grid_groups`).  An
     explicit ``batch`` is per device and is kept; so are the xla and
     blake2b defaults.
 
@@ -758,8 +760,10 @@ def run_sweep_dispatches(
     (defaults = the frozen mining workload).
 
     ``get_kernel(layout, group)`` builds/caches the kernel for a shape class;
-    ``run_kernel(kern, midstate, tail_const, bounds)`` queues one dispatch
-    and returns its (not-yet-fetched) output handle;
+    ``run_kernel(kern, midstate, tail_const, bounds, groups)`` queues one
+    dispatch and returns its (not-yet-fetched) output handle, where
+    ``groups`` is the row-group extent of the dispatch's grid
+    (:func:`_grid_groups`; None for a kernel whose grid is fixed);
     ``consume(out, chunk_bases, 10^k)`` fetches and folds one result — it
     must also accept a :class:`HostFold` as ``out`` (with None bases):
     digit classes with ``10^d <= host_lane_budget`` are min-folded on the
@@ -801,12 +805,15 @@ def run_sweep_dispatches(
         for s in range(0, len(group.chunks), batch):
             rows = group.chunks[s : s + batch]
             slots = None
+            fullest = len(rows)
             if n_devices > 1:
                 place = MeshRows(len(rows), n_devices)
                 slots = place.slots(batch // n_devices)
+                fullest = max(place.counts())
                 _count_mesh_dispatch(place, batch // n_devices)
             tail_const, bounds = _fill_templates(layout, group, rows, batch, slots)
-            out = run_kernel(kern, midstate, tail_const, bounds)
+            groups = _grid_groups(kern, fullest, batch // n_devices, n_devices)
+            out = run_kernel(kern, midstate, tail_const, bounds, groups)
             pending.append((out, [c.base for c in rows], 10**group.k))
             n_dev = sum(c.hi_off - c.lo_off for c in rows)
             lanes += n_dev
@@ -816,6 +823,27 @@ def run_sweep_dispatches(
     while pending:
         consume(*pending.popleft())
     return lanes
+
+
+def _grid_groups(
+    kern, rows: int, per_dev_batch: int, n_devices: int = 1
+) -> Optional[int]:
+    """The row groups each device's grid runs for a dispatch whose fullest
+    device holds ``rows`` rows: ``ceil(rows / kern.cpb)`` for a kernel
+    whose row-group extent is a runtime operand (the pallas dyn kernels,
+    which carry their ``cpb``), None for a kernel whose grid is fixed.
+    Counts ``sweep.grid_groups`` and the groups of the full ``per_dev_batch
+    // cpb`` grid it skips (``sweep.grid_groups_skipped``), over the
+    devices."""
+    cpb = getattr(kern, "cpb", None)
+    if cpb is None:
+        return None
+    groups = -(-rows // cpb)
+    METRICS.inc("sweep.grid_groups", n_devices * groups)
+    METRICS.inc(
+        "sweep.grid_groups_skipped", n_devices * (per_dev_batch // cpb - groups)
+    )
+    return groups
 
 
 def _count_mesh_dispatch(place: MeshRows, per_dev_batch: int) -> None:
@@ -923,6 +951,7 @@ def _build_kernel(
             dyn_params,
             make_pallas_minhash,
             make_pallas_minhash_dyn,
+            resolve_cpb,
         )
 
         window = dyn_params(layout, group.k)
@@ -940,6 +969,7 @@ def _build_kernel(
                 sieve=sieve,
             )
         w_lo, w_hi = window
+        cpb = resolve_cpb(batch, cpb)
         params = dict(
             n_tail_blocks=layout.n_tail_blocks,
             w_lo=w_lo,
@@ -961,10 +991,11 @@ def _build_kernel(
 
         # *th is empty (baseline) or the one threshold operand (sieve):
         # one wrapper serves both calling conventions.
-        def kern(midstate, tailc_bounds, *th, _fn=fn, _c=contribs):
-            return _fn(midstate, tailc_bounds, *th, *_c)
+        def kern(midstate, tailc_bounds, n_groups, *th, _fn=fn, _c=contribs):
+            return _fn(midstate, tailc_bounds, n_groups, *th, *_c)
 
         kern.class_key = fn
+        kern.cpb = cpb  # the grid's row-group extent is an operand
         return kern
     return _make_kernel(
         layout.n_tail_blocks, low_pos, group.k, batch, rolled, sieve,
@@ -972,7 +1003,9 @@ def _build_kernel(
     )
 
 
-def _invoke_kernel(backend, kern, midstate, tail_const, bounds, thresh=None):
+def _invoke_kernel(
+    backend, kern, midstate, tail_const, bounds, thresh=None, groups=None
+):
     """One place for the backend-specific calling convention (the pallas
     tier takes the chunk table + bounds as one flattened operand).
 
@@ -980,15 +1013,18 @@ def _invoke_kernel(backend, kern, midstate, tail_const, bounds, thresh=None):
     int in [0, U32_MAX] — U32_MAX (everything survives) until the first
     candidate lands.  The pallas tier wants it pre-sign-flipped int32
     (its comparisons live in that domain); the xla tier compares uint32
-    directly."""
+    directly.  ``groups`` (the pallas dyn kernel only, see
+    :func:`_grid_groups`): the grid's row-group extent, an int32 operand
+    ahead of the threshold."""
     if backend == "pallas":
         tailcb = np.concatenate([tail_const, bounds.astype(np.uint32)], axis=1)
-        if thresh is None:
-            return kern(jnp.asarray(midstate), jnp.asarray(tailcb))
-        tflip = np.array([thresh ^ 0x80000000], dtype=np.uint32).view(np.int32)
-        return kern(
-            jnp.asarray(midstate), jnp.asarray(tailcb), jnp.asarray(tflip)
-        )
+        ops = [jnp.asarray(midstate), jnp.asarray(tailcb)]
+        if groups is not None:
+            ops.append(jnp.asarray(np.int32(groups)))
+        if thresh is not None:
+            tflip = np.array([thresh ^ 0x80000000], dtype=np.uint32)
+            ops.append(jnp.asarray(tflip.view(np.int32)))
+        return kern(*ops)
     if thresh is None:
         return kern(
             jnp.asarray(midstate), jnp.asarray(tail_const), jnp.asarray(bounds)
@@ -1240,6 +1276,10 @@ class SweepPipeline:
                 out = self._invoke(
                     kern, midstate, tail_const, bounds,
                     thresh=U32_MAX if self._sieve else None,
+                    groups=_grid_groups(
+                        kern, len(group.chunks), self._per_dev_batch,
+                        self._n_devices,
+                    ),
                 )
                 for o in out:
                     o.block_until_ready()
@@ -1305,16 +1345,19 @@ class SweepPipeline:
             factored=self._factored,
         )
 
-    def _invoke(self, kern, midstate, tail_const, bounds, thresh=None):
+    def _invoke(
+        self, kern, midstate, tail_const, bounds, thresh=None, groups=None
+    ):
         if self._mesh is not None:
             from ..parallel.sweep import sharded_invoke
 
             return sharded_invoke(
                 kern, midstate, tail_const, bounds,
-                self._mesh, self._axis_name, thresh=thresh,
+                self._mesh, self._axis_name, thresh=thresh, groups=groups,
             )
         return _invoke_kernel(
-            self._backend, kern, midstate, tail_const, bounds, thresh=thresh
+            self._backend, kern, midstate, tail_const, bounds,
+            thresh=thresh, groups=groups,
         )
 
     def _class_lock(self, kern):
@@ -1336,7 +1379,7 @@ class SweepPipeline:
             data, lower, upper, fut = item
             state = {"best": [], "lanes": 0, "fut": fut}
 
-            def run_kernel(kern, midstate, tail_const, bounds):
+            def run_kernel(kern, midstate, tail_const, bounds, groups):
                 # Class lock: a cold class traces inside this call; holding
                 # the lock shares that build with a concurrent prewarm of
                 # the same class.  Warm classes just enqueue (~ms) so the
@@ -1352,7 +1395,8 @@ class SweepPipeline:
                     th = (b[0][0] >> 32) if b else U32_MAX
                 with self._class_lock(kern):
                     out = self._invoke(
-                        kern, midstate, tail_const, bounds, thresh=th
+                        kern, midstate, tail_const, bounds, thresh=th,
+                        groups=groups,
                     )
                     self._warm_keys.add(getattr(kern, "class_key", kern))
                     return (out, _time.monotonic())

@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -235,10 +236,13 @@ def _make_sharded_kernel_dyn(
     `_build_kernel`) — a multi-chip sweep crossing a decimal digit
     boundary never re-traces or re-loads.
 
-    Returned jitted fn: ``(midstate, tail_const, bounds, [thresh,]
-    *contribs)`` with contribs replicated (one (n_pad/128, 128) u32 tile
-    per window word); ``sieve=True`` adds the replicated uint32 thresh
-    scalar of the per-shard sieve (see :func:`_make_sharded_kernel`).
+    Returned jitted fn: ``(midstate, tail_const, bounds, n_groups,
+    [thresh,] *contribs)`` with contribs replicated (one (n_pad/128, 128)
+    u32 tile per window word); ``sieve=True`` adds the replicated uint32
+    thresh scalar of the per-shard sieve (see :func:`_make_sharded_kernel`).
+    ``n_groups`` is the replicated int32 row-group extent of every shard's
+    grid: the fullest device's rows in whole groups of ``cpb``, so every
+    device steps through as many groups as that one.
     """
     from ..ops.pallas_sha256 import make_pallas_minhash_dyn
 
@@ -248,22 +252,22 @@ def _make_sharded_kernel_dyn(
     )
     n_window = w_hi - w_lo + 1
 
-    def shard_fn(midstate, tail_const, bounds, *rest):
+    def shard_fn(midstate, tail_const, bounds, n_groups, *rest):
         tailcb = jnp.concatenate(
             [tail_const, bounds.astype(jnp.uint32)], axis=1
         )
         if sieve:
             h0, h1, flat = pallas_fn(
-                midstate, tailcb, _flip_thresh(rest[0]), *rest[1:]
+                midstate, tailcb, n_groups, _flip_thresh(rest[0]), *rest[1:]
             )
         else:
-            h0, h1, flat = pallas_fn(midstate, tailcb, *rest)
+            h0, h1, flat = pallas_fn(midstate, tailcb, n_groups, *rest)
         return _collective_min(h0, h1, flat, axis_name)
 
     mapped = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        in_specs=(P(), P(axis_name, None), P(axis_name, None))
+        in_specs=(P(), P(axis_name, None), P(axis_name, None), P())
         + ((P(),) if sieve else ())
         + (P(None, None),) * n_window,
         out_specs=(P(), P(), P(), P()),
@@ -323,7 +327,7 @@ def sharded_kernel_for(
             ),
         )
     if backend == "pallas":
-        from ..ops.pallas_sha256 import dyn_params
+        from ..ops.pallas_sha256 import dyn_params, resolve_cpb
 
         window = dyn_params(layout, group.k)
         if window is not None:
@@ -368,10 +372,14 @@ def sharded_kernel_for(
                 group.k, low_pos, w_lo, w_hi, n_pad, mesh
             )
 
-            def kern(midstate, tail_const, bounds, *th, _fn=fn, _c=contribs):
-                return _fn(midstate, tail_const, bounds, *th, *_c)
+            def kern(
+                midstate, tail_const, bounds, n_groups, *th, _fn=fn,
+                _c=contribs,
+            ):
+                return _fn(midstate, tail_const, bounds, n_groups, *th, *_c)
 
             kern.class_key = fn
+            kern.cpb = resolve_cpb(batch_per_device)  # the factory's cpb
             return kern
         # d == k (the d=1 class): outside the dyn window domain; one
         # class, so per-class compilation costs nothing extra.
@@ -409,18 +417,21 @@ def shard_operands(midstate, tail_const, bounds, mesh: Mesh, axis_name: str):
 
 def sharded_invoke(
     kern, midstate, tail_const, bounds, mesh: Mesh, axis_name: str,
-    thresh=None,
+    thresh=None, groups=None,
 ):
     """Queue one sharded dispatch (see :func:`shard_operands`).
     ``thresh`` (per-shard sieve kernels only): the host's running-min h0
-    as a plain int — replicated to every shard as a uint32 scalar."""
-    th = ()
+    as a plain int — replicated to every shard as a uint32 scalar.
+    ``groups`` (the sharded dyn kernel only): the row-group extent of
+    every shard's grid, replicated as an int32 scalar ahead of it."""
+    rep = NamedSharding(mesh, P())
+    extra = []
+    if groups is not None:
+        extra.append(jax.device_put(np.int32(groups), rep))
     if thresh is not None:
-        import numpy as _np
-
-        th = (jax.device_put(_np.uint32(thresh), NamedSharding(mesh, P())),)
+        extra.append(jax.device_put(np.uint32(thresh), rep))
     ops = shard_operands(midstate, tail_const, bounds, mesh, axis_name)
-    return kern(*ops, *th)
+    return kern(*ops, *extra)
 
 
 def sweep_min_hash_sharded(

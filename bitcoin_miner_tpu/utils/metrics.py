@@ -379,6 +379,8 @@ def format_quantiles(h) -> str:
 #:   sweep.mesh_row_slots      n_devices x the fullest device's rows, per mesh dispatch
 #:   sweep.mesh_dispatch_slots  n_devices x the per-device batch, per mesh dispatch
 #:   sweep.mesh_dispatches     mesh (sharded) dispatches enqueued
+#:   sweep.grid_groups         row groups the pallas dyn kernel's grids ran, over the devices
+#:   sweep.grid_groups_skipped  row groups of the full batch // cpb grid those grids skipped
 #:   client.resubmits          jobs resubmitted after a lost client conn
 #:   chaos.dropped             packets dropped by the network simulator
 #:   chaos.partitioned         packets blackholed by a directional partition

@@ -92,6 +92,7 @@ def _single_chip_dyn(topo):
     specs = [
         s((8,), jnp.uint32),
         s((batch, nw + 2), jnp.uint32),
+        s((), jnp.int32),  # the grid's row-group extent
         s((1,), jnp.int32),
         *(s((n_pad // 128, 128), jnp.uint32) for _ in range(w_hi - w_lo + 1)),
     ]
@@ -163,6 +164,7 @@ def _sharded_dyn(topo):
         jax.ShapeDtypeStruct((8,), jnp.uint32, sharding=rep),
         jax.ShapeDtypeStruct((rows, nw), jnp.uint32, sharding=row),
         jax.ShapeDtypeStruct((rows, 2), jnp.int32, sharding=row),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep),  # row groups
         *((jax.ShapeDtypeStruct((), jnp.uint32, sharding=rep),) if sieve else ()),
         *(
             jax.ShapeDtypeStruct((n_pad // 128, 128), jnp.uint32, sharding=rep2)

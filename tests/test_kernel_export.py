@@ -34,6 +34,7 @@ from bitcoin_miner_tpu.ops.pallas_sha256 import (
     DEFAULT_TILE,
     dyn_params,
     make_pallas_minhash_dyn,
+    resolve_cpb,
     window_contribs_np,
 )
 from bitcoin_miner_tpu.ops.sha256 import build_layout
@@ -54,15 +55,16 @@ DATA = "cmu440"
 LO, HI = 1000, 1399
 
 
-def _kernel(batch=2, sieve=True):
+def _kernel(batch=2, sieve=True, groups=1, cpb=None):
     """A small interpret-mode dyn kernel (d=4, k=2), its factory's
-    parameters, and one dispatch's operands."""
+    parameters, and one dispatch's operands: ``batch`` rows, the grid over
+    ``groups`` row groups of ``cpb``."""
     group = next(decompose_range(LO, LO + 100 * batch - 1, max_k=2))
     layout = build_layout(DATA.encode(), group.d)
     w_lo, w_hi = dyn_params(layout, group.k)
     params = dict(
         n_tail_blocks=layout.n_tail_blocks, w_lo=w_lo, w_hi=w_hi, k=group.k,
-        batch=batch, tile=DEFAULT_TILE, cpb=None, sieve=sieve,
+        batch=batch, tile=DEFAULT_TILE, cpb=cpb, sieve=sieve,
     )
     fn, n_pad = make_pallas_minhash_dyn(**params, interpret=True)
     tail_const, bounds = _fill_templates(layout, group, group.chunks, batch)
@@ -71,7 +73,8 @@ def _kernel(batch=2, sieve=True):
     th = (np.array([0x7FFFFFFF], dtype=np.int32),) if sieve else ()
     low_pos = layout.digit_pos[layout.digit_count - group.k :]
     args = [
-        np.array(layout.midstate, dtype=np.uint32), tailcb, *th,
+        np.array(layout.midstate, dtype=np.uint32), tailcb, np.int32(groups),
+        *th,
         *window_contribs_np(group.k, low_pos, w_lo, w_hi, n_pad),
     ]
     return fn, params, [jnp.asarray(a) for a in args]
@@ -215,6 +218,44 @@ def test_source_digest_follows_each_traced_module(module, tmp_path, monkeypatch)
     assert digest(extra) != kernel_store.source_digest(extra)
 
 
+def test_one_export_serves_every_group_count(tmp_path):
+    """The grid's row-group extent is an operand of the stored kernel, not
+    part of its key: exported once, and loaded where no kernel can be
+    traced, it answers at one group and at two as the jitted kernel does."""
+    runs = {g: _kernel(batch=4, cpb=2, groups=g) for g in (1, 2)}
+    fn, params, _ = runs[1]
+    traced = {g: _ints(fn(*args)) for g, (_f, _p, args) in runs.items()}
+    h0, h1, idx = traced[2]
+    assert ((h0 << 32) | h1, LO + idx) == min_hash_range(DATA, LO, LO + 399)
+
+    before = _counts()
+    stored = StoredKernel(fn, params, tmp_path)
+    assert {g: _ints(stored(*a)) for g, (_f, _p, a) in runs.items()} == traced
+    assert _delta(before) == (0, 1)
+    before = _counts()
+    loaded = StoredKernel(None, params, tmp_path)
+    assert {g: _ints(loaded(*a)) for g, (_f, _p, a) in runs.items()} == traced
+    assert _delta(before) == (1, 0)
+    assert len(_exports(tmp_path)) == 1
+
+
+def test_grid_group_counters_add_up_to_the_full_grid(production_store):
+    """Each dispatch of the stored dyn kernel counts the row groups its
+    grid ran and those of the full ``batch // cpb`` grid it skipped: 9
+    rows at batch 6, cpb 2 are a full dispatch (3 groups) and one of 3
+    rows (2 groups, 1 skipped)."""
+    names = ("sweep.grid_groups", "sweep.grid_groups_skipped")
+    before = [METRICS.get(n) for n in names]
+    r = sweep_min_hash(
+        DATA, LO, LO + 899, backend="pallas", interpret=False, batch=6,
+        cpb=2, max_k=2,
+    )
+    assert (r.hash, r.nonce) == min_hash_range(DATA, LO, LO + 899)
+    ran, skipped = (METRICS.get(n) - b for n, b in zip(names, before))
+    assert (ran, skipped) == (5, 1)
+    assert ran + skipped == 2 * (6 // 2)  # two dispatches of the full grid
+
+
 def test_store_sits_beside_the_compile_cache(tmp_path, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert kernel_store.store_dir() == tmp_path / "kernel_exports"
@@ -332,7 +373,11 @@ def _sharded_kernel(mesh, axis="miners", batch=2):
         np.array(layout.midstate, dtype=np.uint32), tail_const, bounds,
         mesh, axis,
     )
-    thresh = jax.device_put(np.uint32(U32_MAX), NamedSharding(mesh, P()))
+    rep = NamedSharding(mesh, P())
+    groups = jax.device_put(
+        np.int32(-(-max(rows.counts()) // resolve_cpb(batch))), rep
+    )
+    thresh = jax.device_put(np.uint32(U32_MAX), rep)
     low_pos = layout.digit_pos[layout.digit_count - group.k :]
     contribs = psweep._mesh_contribs(group.k, low_pos, w_lo, w_hi, n_pad, mesh)
     params = dict(
@@ -346,7 +391,7 @@ def _sharded_kernel(mesh, axis="miners", batch=2):
         local, lane = divmod(flat, 10**group.k)
         return group.chunks[rows.row(dev, local)].base + lane
 
-    return fn, params, [*ops, thresh, *contribs], nonce
+    return fn, params, [*ops, groups, thresh, *contribs], nonce
 
 
 def _answer(out, nonce):

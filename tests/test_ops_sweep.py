@@ -508,6 +508,76 @@ class TestDynKernel:
         )
         assert (r.hash, r.nonce) == min_hash_range("cmu440", 5, 15)
 
+    @staticmethod
+    def _window_ending_in_min(data, n_rows):
+        """Start of the first window of ``n_rows`` d=4 chunks (k=2, 100
+        nonces each) whose minimum lies in its last chunk, so a grid that
+        stopped a group short would miss it."""
+        mins = [min_hash_range(data, b, b + 99) for b in range(1000, 10000, 100)]
+        for s in range(len(mins) - n_rows + 1):
+            if min(mins[s : s + n_rows]) == mins[s + n_rows - 1]:
+                return 1000 + 100 * s
+        raise AssertionError(f"no {n_rows}-chunk window ends in its minimum")
+
+    @pytest.mark.parametrize("sieve", [True, False], ids=["sieve", "full_fold"])
+    @pytest.mark.parametrize(
+        "case", ["1", "cpb-1", "cpb", "cpb+1", "batch", "tie"]
+    )
+    def test_runtime_group_count_bit_exact(self, case, sieve):
+        """A grid over ceil(rows / cpb) row groups, the count the drivers
+        pass, answers as the hashlib oracle and as the full batch // cpb
+        grid.  "tie" repeats the minimum's row (the last, alone in the
+        second group) in slot 0: the lower slot, the lower flat index,
+        must win."""
+        import numpy as np
+
+        from bitcoin_miner_tpu.ops.pallas_sha256 import (
+            DEFAULT_TILE, dyn_params, make_pallas_minhash_dyn,
+            window_contribs_np,
+        )
+        from bitcoin_miner_tpu.ops.sweep import _fill_templates
+
+        data, batch, cpb = "cmu440", 8, 4
+        n_rows = {
+            "1": 1, "cpb-1": cpb - 1, "cpb": cpb, "cpb+1": cpb + 1,
+            "batch": batch, "tie": cpb + 1,
+        }[case]
+        lo = self._window_ending_in_min(data, n_rows)
+        hi = lo + 100 * n_rows - 1
+        group = next(decompose_range(lo, hi, max_k=2))
+        chunks = list(group.chunks)
+        if case == "tie":
+            chunks[0] = chunks[-1]
+        layout = build_layout(data.encode(), group.d)
+        w_lo, w_hi = dyn_params(layout, group.k)
+        fn, n_pad = make_pallas_minhash_dyn(
+            layout.n_tail_blocks, w_lo, w_hi, group.k, batch, DEFAULT_TILE,
+            interpret=True, cpb=cpb, sieve=sieve,
+        )
+        tail_const, bounds = _fill_templates(layout, group, chunks, batch)
+        tailcb = np.concatenate([tail_const, bounds.astype(np.uint32)], axis=1)
+        th = [np.array([0x7FFFFFFF], dtype=np.int32)] if sieve else []
+        low_pos = layout.digit_pos[layout.digit_count - group.k :]
+        contribs = window_contribs_np(group.k, low_pos, w_lo, w_hi, n_pad)
+        midstate = np.array(layout.midstate, dtype=np.uint32)
+
+        def run(n_groups):
+            out = fn(midstate, tailcb, np.int32(n_groups), *th, *contribs)
+            return tuple(int(x) for x in out)
+
+        n_groups = -(-n_rows // cpb)
+        got = run(n_groups)
+        assert got == run(batch // cpb)
+        h0, h1, idx = got
+        nonce = chunks[idx // 100].base + idx % 100
+        assert ((h0 << 32) | h1, nonce) == min_hash_range(data, lo, hi)
+        if case == "tie":
+            assert idx // 100 == 0
+        elif n_groups > 1:
+            # The extent is honoured: a group short, the minimum's row is
+            # never hashed.
+            assert run(n_groups - 1) != got
+
     def test_zero_tiles_shared_across_classes(self):
         from bitcoin_miner_tpu.ops.pallas_sha256 import (
             dyn_window, window_contribs_np, zero_tile_np,

@@ -399,6 +399,37 @@ def test_mesh_pipeline_pallas_default_dispatch_holds_1024_slots(monkeypatch):
     assert shipped == [(1024, 2)]
 
 
+def test_mesh_dyn_grid_runs_the_fullest_devices_groups():
+    """Every shard's grid runs the fullest device's rows in whole groups of
+    cpb.  35 rows over 4 devices of 24 slots (cpb 8) place 9, 9, 9 and 8,
+    so each grid runs 2 of its 3 groups and the short device's second
+    group is empty.  The window's minimum sits in a ninth row, which a
+    grid one group short would miss."""
+    from bitcoin_miner_tpu.ops.sweep import MeshRows
+    from bitcoin_miner_tpu.utils.metrics import METRICS
+
+    data, n_dev, per_dev, n_rows = "cmu440", 4, 24, 35
+    place = MeshRows(n_rows, n_dev)
+    assert place.counts() == (9, 9, 9, 8)
+    ninth = {place.row(dev, 8) for dev in range(3)}
+    mins = [min_hash_range(data, b, b + 99) for b in range(10000, 30000, 100)]
+    start = next(
+        s for s in range(len(mins) - n_rows)
+        if mins.index(min(mins[s : s + n_rows]), s) - s in ninth
+    )
+    lo = 10000 + 100 * start
+    hi = lo + 100 * n_rows - 1
+    names = ("sweep.grid_groups", "sweep.grid_groups_skipped")
+    before = [METRICS.get(n) for n in names]
+    r = sweep_min_hash_sharded(
+        data, lo, hi, mesh=default_mesh(n_dev), backend="pallas",
+        interpret=True, max_k=2, batch_per_device=per_dev,
+    )
+    assert (r.hash, r.nonce) == min_hash_range(data, lo, hi)
+    ran, skipped = (METRICS.get(n) - b for n, b in zip(names, before))
+    assert (ran, skipped) == (n_dev * 2, n_dev * 1)
+
+
 def test_mesh_rows_slot_map_is_nonce_ordered():
     from bitcoin_miner_tpu.ops.sweep import MeshRows
 
